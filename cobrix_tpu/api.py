@@ -36,6 +36,7 @@ from .reader.diagnostics import (
     ShardErrorPolicy,
     ShardFailureInfo,
 )
+from .reader.columnar import validate_backend
 from .reader.fixed_len_reader import FixedLenReader
 from .reader.json_out import rows_to_json
 from .reader.parameters import (
@@ -906,7 +907,9 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
                                 records=result.n_rows)
         return result
 
-    if len(shards) == 1 or parallelism <= 1:
+    # <= 1: zone-map skipping can leave no shard at all, and a pool of
+    # zero workers is a ValueError
+    if len(shards) <= 1 or parallelism <= 1:
         return [run_shard(s) for s in enumerate(shards)]
     from concurrent.futures import ThreadPoolExecutor
 
@@ -998,6 +1001,7 @@ def read_cobol(path=None,
     if path is None:
         raise ValueError("'path' must be specified for read_cobol.")
 
+    validate_backend(backend)
     params, opts = parse_options(options)
     if params.filter and backend == "host":
         raise ValueError(
@@ -1182,7 +1186,8 @@ def _build_obs_context(params: ReaderParameters, metrics: ReadMetrics,
                       cache_scope=metrics.cache_scope,
                       io_stats=metrics.io_stats,
                       field_costs=metrics.field_costs_acc,
-                      pass_counts=metrics.pass_counts)
+                      pass_counts=metrics.pass_counts,
+                      device_stats=metrics.device_stats)
 
 
 def _finish_obs(obs_ctx, params: ReaderParameters, data) -> None:

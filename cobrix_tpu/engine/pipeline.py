@@ -771,6 +771,13 @@ def _finalizers(count: int, output_schema, ex: PipelineExecutor,
     (a dead client must cancel its scan), partial ledgers the chunk."""
     if not assemble:
         return [None] * count
+    # on the caller's thread, before a stage thread can: pyarrow's
+    # allocator (mimalloc, pyarrow 25) takes the thread that first loads
+    # it for the process's main one, and once that thread has exited —
+    # as every stage thread does when its scan ends — an allocation on a
+    # later thread dereferences a null heap
+    import pyarrow  # noqa: F401
+
     # parallel assembly: heavy table builds overlap freely, but the
     # batch tap keeps its documented one-call-at-a-time contract
     tap_lock = threading.Lock() if ex.parallel_finalize else None

@@ -27,11 +27,11 @@ class ObsContext:
     """The read's observability bundle (any member may be None)."""
 
     __slots__ = ("tracer", "metrics", "progress", "cache_scope",
-                 "io_stats", "field_costs", "pass_counts")
+                 "io_stats", "field_costs", "pass_counts", "device_stats")
 
     def __init__(self, tracer=None, metrics: Optional[dict] = None,
                  progress=None, cache_scope=None, io_stats=None,
-                 field_costs=None, pass_counts=None):
+                 field_costs=None, pass_counts=None, device_stats=None):
         self.tracer = tracer
         self.metrics = metrics      # obs.metrics.scan_metrics() dict
         self.progress = progress    # obs.progress.ProgressTracker
@@ -44,6 +44,9 @@ class ObsContext:
         # profiling.PassCounters — fused-native-pass engagement counts
         # for the read (lands in ReadMetrics.as_dict()["native_passes"])
         self.pass_counts = pass_counts
+        # profiling.DeviceStats — launches/bytes/compiles of the device
+        # decode plane (lands in ReadMetrics.as_dict()["device"])
+        self.device_stats = device_stats
 
 
 def current() -> Optional[ObsContext]:

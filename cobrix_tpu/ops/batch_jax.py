@@ -102,6 +102,52 @@ def decode_bcd(data: jnp.ndarray,
 # zoned decimal (DISPLAY)
 # ---------------------------------------------------------------------------
 
+# Running counts along a field's bytes are built from one elementwise step
+# per byte, not from jnp.cumsum: inside the exp1 program on a TPU v5e
+# (libtpu 0.0.34, PR 21's chip run) the scan XLA emits for a cumsum over
+# the 28-byte DISPLAY fields miscounts from the 17th byte on — every
+# other width came out right, and so did the same field compiled alone —
+# which silently misplaces digits. Field widths are static and small, so
+# the unrolled form costs a few dozen vector ops and nothing else.
+
+def _digits_to_right(is_digit):
+    """int32 [..., W]: how many digits follow each byte."""
+    idig = is_digit.astype(jnp.int32)
+    running = jnp.zeros(idig.shape[:-1], dtype=jnp.int32)
+    counts = [None] * idig.shape[-1]
+    for j in range(idig.shape[-1] - 1, -1, -1):
+        counts[j] = running
+        running = running + idig[..., j]
+    return jnp.stack(counts, axis=-1)
+
+
+def _digits_after_dot(is_digit, is_dot):
+    """int32 [...]: digits to the right of the first decimal point."""
+    seen_dot = jnp.zeros(is_dot.shape[:-1], dtype=jnp.bool_)
+    total = jnp.zeros(is_dot.shape[:-1], dtype=jnp.int32)
+    for j in range(is_dot.shape[-1]):
+        seen_dot = seen_dot | is_dot[..., j]
+        total = total + (seen_dot & is_digit[..., j]).astype(jnp.int32)
+    return total
+
+
+def _any_before_and_after(mask):
+    """bool [..., W] pair: is any `mask` byte strictly left / strictly
+    right of each position."""
+    w = mask.shape[-1]
+    left = [None] * w
+    right = [None] * w
+    seen = jnp.zeros(mask.shape[:-1], dtype=jnp.bool_)
+    for j in range(w):
+        left[j] = seen
+        seen = seen | mask[..., j]
+    seen = jnp.zeros(mask.shape[:-1], dtype=jnp.bool_)
+    for j in range(w - 1, -1, -1):
+        right[j] = seen
+        seen = seen | mask[..., j]
+    return jnp.stack(left, axis=-1), jnp.stack(right, axis=-1)
+
+
 def _classify_display_ebcdic(b):
     """Shared classification of the reference zoned-decimal state machine
     (mirror of batch_np._classify_display_ebcdic). Returns
@@ -125,11 +171,7 @@ def _classify_display_ebcdic(b):
         jnp.where(is_c_digit, b - 0xC0,
                   jnp.where(is_d_digit, b - 0xD0, 0)))
     negative = (is_d_digit | is_minus).any(axis=-1)
-    idig = is_digit.astype(jnp.int32)
-    dot_right = jnp.where(
-        n_dots > 0,
-        jnp.sum(jnp.where(jnp.cumsum(is_dot, axis=-1) > 0, idig, 0), axis=-1),
-        0)
+    dot_right = _digits_after_dot(is_digit, is_dot)
     valid_base = jnp.all(known, axis=-1) & (n_signs <= 1)
     return is_digit, digit_val, negative, dot_right, n_dots, n_digits, \
         valid_base
@@ -146,18 +188,11 @@ def _classify_display_ascii(b):
     n_signs = (is_minus | is_plus).sum(axis=-1)
     n_dots = is_dot.sum(axis=-1)
     n_digits = is_digit.sum(axis=-1)
-    meaningful = (is_digit | is_dot).astype(jnp.int32)
-    left_has = jnp.cumsum(meaningful, axis=-1) - meaningful > 0
-    right_has = (jnp.cumsum(meaningful[..., ::-1], axis=-1)[..., ::-1]
-                 - meaningful) > 0
+    left_has, right_has = _any_before_and_after(is_digit | is_dot)
     interior_space = (is_space & left_has & right_has).any(axis=-1)
     digit_val = jnp.where(is_digit, b - 0x30, 0)
     negative = is_minus.any(axis=-1)
-    idig = is_digit.astype(jnp.int32)
-    dot_right = jnp.where(
-        n_dots > 0,
-        jnp.sum(jnp.where(jnp.cumsum(is_dot, axis=-1) > 0, idig, 0), axis=-1),
-        0)
+    dot_right = _digits_after_dot(is_digit, is_dot)
     valid_base = jnp.all(known, axis=-1) & (n_signs <= 1) & ~interior_space
     return is_digit, digit_val, negative, dot_right, n_dots, n_digits, \
         valid_base
@@ -178,8 +213,7 @@ def _decode_display(classify, data, signed, allow_dot, require_digits,
                     out_dtype, dyn_sf):
     (is_digit, digit_val, negative, dot_right, n_dots, n_digits,
      valid_base) = classify(data)
-    idig = is_digit.astype(jnp.int32)
-    digits_right = (jnp.cumsum(idig[..., ::-1], axis=-1)[..., ::-1] - idig)
+    digits_right = _digits_to_right(is_digit)
     mantissa = jnp.sum(digit_val.astype(out_dtype)
                        * _pow10(digits_right, out_dtype), axis=-1)
     mantissa = jnp.where(negative, -mantissa, mantissa)
@@ -313,9 +347,7 @@ def _decode_display_wide(classify, data, signed, allow_dot, require_digits,
                          dyn_sf: int = 0):
     (is_digit, digit_val, negative, dot_right, n_dots, n_digits,
      valid_base) = classify(data)
-    idig = is_digit.astype(jnp.int32)
-    digits_right = (jnp.cumsum(idig[..., ::-1], axis=-1)[..., ::-1]
-                    - idig).astype(jnp.int64)
+    digits_right = _digits_to_right(is_digit).astype(jnp.int64)
     hi, lo = _chunks_to_u128(
         _digit_chunks(digit_val.astype(jnp.int64), digits_right,
                       data.shape[-1]))

@@ -29,7 +29,9 @@ _run_group_jax` contracts). String groups keep the XLA LUT-gather path
 columns are the only other non-fused planes.
 
 Parity is pinned by tests/test_pallas_kernels.py against the numpy
-blueprint kernels, on both the interpreter and real TPU.
+blueprint kernels through the interpreter, tests/test_tpu_compile.py
+compiles the Mosaic kernel for a described v5e, and chip_smoke.py runs
+it on the chip against the host kernels and the scalar oracle.
 """
 from __future__ import annotations
 
@@ -448,6 +450,11 @@ def build_fused_decode(groups: Sequence[StridedGroup], record_len: int,
     jit-traceable; pads the batch to the tile size, extracts the byte
     planes in XLA, runs the single fused pallas_call over batch tiles,
     and assembles limb outputs into int64 / uint64-pair planes.
+
+    `interpret=None` runs the kernel through the Pallas interpreter
+    everywhere but on a TPU (the CPU tests' parity tool). The choice is
+    recorded on the returned function as `fn.interpret`, so a caller that
+    needs the Mosaic kernel can refuse anything else.
     """
     from jax.experimental import pallas as pl
 
@@ -520,4 +527,5 @@ def build_fused_decode(groups: Sequence[StridedGroup], record_len: int,
             results.append(tuple(_assemble_group(bufs, g)))
         return results
 
+    fn.interpret = interpret
     return fn

@@ -34,35 +34,18 @@ from typing import Dict, Optional
 @contextlib.contextmanager
 def profile_trace(output_dir: str):
     """Capture a JAX profiler trace of everything inside the block into
-    `output_dir` (TensorBoard-loadable). Falls back to a no-op if the
-    profiler is unavailable (e.g. numpy-only environments)."""
-    try:
-        import jax
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        yield
-        return
+    `output_dir` (TensorBoard-loadable)."""
+    import jax
+
     with jax.profiler.trace(output_dir):
         yield
 
 
-_TRACE_ANNOTATION = None
-
-
 def annotate(name: str):
-    """Named span on the profiler timeline; no-op outside a trace. The
-    TraceAnnotation class resolves once — this sits on per-block decode
-    hot paths."""
-    global _TRACE_ANNOTATION
-    if _TRACE_ANNOTATION is None:
-        try:
-            import jax
+    """Named span on the profiler timeline; ~free outside a trace."""
+    import jax
 
-            _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
-        except Exception:  # pragma: no cover
-            _TRACE_ANNOTATION = False
-    if _TRACE_ANNOTATION is False:  # pragma: no cover
-        return contextlib.nullcontext()
-    return _TRACE_ANNOTATION(name)
+    return jax.profiler.TraceAnnotation(name)
 
 
 class StageTimes:
@@ -142,6 +125,60 @@ class PassCounters:
             return dict(self._counts)
 
 
+class DeviceStats:
+    """What the device decode plane did for one read: program launches by
+    padded batch shape, bytes over the link each way, the seconds spent
+    compiling, the devices the outputs lived on, and what kind of program
+    ran (does it hold the fused kernel; was that kernel interpreted).
+    The record a caller needs to tell a read that used the chip from one
+    that only says so. Shared like PassCounters: scan threads reach it
+    through the ObsContext."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.launches: Dict[tuple, int] = {}
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.compile_s = 0.0
+        self.compiles = 0
+        self.devices: set = set()
+        self.has_kernel: Optional[bool] = None
+        self.interpreted: Optional[bool] = None
+
+    def note_launch(self, shape: tuple, h2d_bytes: int, d2h_bytes: int,
+                    devices, program, built, interpreted) -> None:
+        """One program launch. `program` is the ops.device.CompiledShape
+        that ran, `built` whether this launch had to compile it."""
+        with self._lock:
+            self.launches[shape] = self.launches.get(shape, 0) + 1
+            self.h2d_bytes += h2d_bytes
+            self.d2h_bytes += d2h_bytes
+            self.devices.update(str(d) for d in devices)
+            if built:
+                self.compiles += 1
+                self.compile_s += program.compile_s
+            # every launch of the read must agree before the read may
+            # claim the kernel: one launch without it turns the flag off
+            self.has_kernel = (program.has_kernel if self.has_kernel is None
+                               else self.has_kernel and program.has_kernel)
+            if interpreted is not None:
+                self.interpreted = bool(self.interpreted) or interpreted
+
+    def as_dict(self) -> dict:
+        with self._lock:
+            return {
+                "launches": {f"{b}x{e}": n for (b, e), n
+                             in sorted(self.launches.items())},
+                "h2d_bytes": self.h2d_bytes,
+                "d2h_bytes": self.d2h_bytes,
+                "compiles": self.compiles,
+                "compile_s": round(self.compile_s, 3),
+                "devices": sorted(self.devices),
+                "has_kernel": self.has_kernel,
+                "interpreted": self.interpreted,
+            }
+
+
 @dataclass
 class ReadMetrics:
     """Structured per-read metrics (the IndexBuilder/CobolScanners log
@@ -206,6 +243,9 @@ class ReadMetrics:
         # fused-native-pass engagement counters (always on — one locked
         # dict increment per kernel launch, nowhere near hot-loop cost)
         self.pass_counts = PassCounters()
+        # what the device decode plane did (backend jax/pallas); stays
+        # empty on host-kernel reads
+        self.device_stats = DeviceStats()
         # root-span args dict + trace destination, kept so lazy
         # post-read assembly can fold its costs back into an already
         # written trace artifact (refresh_trace_field_costs)
@@ -387,6 +427,8 @@ class ReadMetrics:
         passes = self.pass_counts.as_dict()
         if passes:
             out["native_passes"] = passes
+        if self.device_stats.launches:
+            out["device"] = self.device_stats.as_dict()
         roof = self.roofline()
         if roof is not None:
             out["roofline"] = roof

@@ -311,8 +311,10 @@ def dataset(path, copybook: Optional[str] = None,
                        parse_options)
     from ..plan.cache import copybook_for_params
     from ..reader.arrow_out import arrow_schema
+    from ..reader.columnar import validate_backend
     from ..reader.schema import output_schema_for
 
+    validate_backend(backend)
     contents = load_copybook_contents(copybook, copybook_contents)
     files = list_input_files(path)
     if not files:

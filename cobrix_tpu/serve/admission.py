@@ -345,10 +345,14 @@ class AdmissionController:
     # -- the in-flight byte gate ----------------------------------------
 
     def acquire_bytes(self, tenant: str, n: int,
-                      timeout_s: Optional[float] = None) -> None:
+                      timeout_s: Optional[float] = None,
+                      own_bytes: int = 0) -> None:
         """Block until `n` more in-flight bytes fit the tenant's budget
         (backpressure on the assembly stage). A single batch larger
-        than the whole budget is admitted alone rather than deadlocking.
+        than the whole budget is admitted alone rather than deadlocking,
+        and so is a batch whose caller already holds (`own_bytes`)
+        everything the tenant has in flight: only that caller can drain
+        those bytes, so making it wait for them is waiting for itself.
         Raises TimeoutError after `timeout_s` (default
         `byte_wait_timeout_s`) without drain — callers that can create
         drain themselves (OrderedBatchEmitter flushing past a
@@ -363,7 +367,7 @@ class AdmissionController:
         with self._cond:
             while True:
                 held = self._inflight_bytes.get(tenant, 0)
-                if held + n <= budget or held == 0:
+                if held + n <= budget or held <= own_bytes:
                     self._inflight_bytes[tenant] = held + n
                     return
                 if last_held is not None and held < last_held:

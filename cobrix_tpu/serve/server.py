@@ -408,6 +408,12 @@ class ScanServer(socketserver.ThreadingTCPServer):
                 "fleet mode needs a shared cache_dir in "
                 "server_options (the replica registry lives under "
                 "<cache_dir>/fleet)")
+        # every answer is Arrow IPC, so load pyarrow here, on the thread
+        # that builds the server, and not on the first connection's
+        # handler thread: pyarrow 25's allocator crashes later threads
+        # once its first loader has exited (engine/pipeline._finalizers)
+        import pyarrow  # noqa: F401
+
         super().__init__((host, port), _Handler)
         # max seconds ONE frame write may block on a non-reading peer
         # before the scan is cancelled as ClientGone (0 = unbounded)

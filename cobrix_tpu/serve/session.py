@@ -289,7 +289,15 @@ class OrderedBatchEmitter:
         flush anything a newly-signalled failed chunk unblocked (that
         releases held bytes). Gives up only after the controller's full
         `byte_wait_timeout_s` passes with zero progress — drained bytes
-        or an advanced gap both re-arm the clock."""
+        or an advanced gap both re-arm the clock.
+
+        The wait is for bytes OTHER scans of the tenant hold. This
+        buffer's own tables drain only when the chunk it waits for
+        arrives, and that chunk comes through this same serialized tap:
+        a chunk table can outweigh its input several times (exp3's wide
+        records), so the tables that finish ahead of the next one can
+        exceed the whole budget, and blocking on them would hold the
+        next one out until the timeout failed a healthy scan."""
         window = self.controller.byte_wait_timeout_s
         t0 = time.monotonic()
         last_next = self._next
@@ -304,7 +312,8 @@ class OrderedBatchEmitter:
                 self.controller.acquire_bytes(
                     self.tenant, nbytes,
                     timeout_s=min(self._GATE_SLICE_S,
-                                  max(0.0, budget_left)))
+                                  max(0.0, budget_left)),
+                    own_bytes=sum(self._held_bytes.values()))
                 return
             except TimeoutError as exc:
                 held = self.controller.inflight_bytes(self.tenant)
@@ -543,6 +552,12 @@ class ScanSession:
                 # filtered scan from a tiny file in /debug and fleet
                 # rollups
                 "pushdown": m.pushdown,
+                # what the device decode plane did (backend jax/pallas):
+                # launches by shape, bytes over the link, compiles, the
+                # devices that held the outputs — a client's only way
+                # to see that the chip answered; None on host reads
+                "device": (m.device_stats.as_dict()
+                           if m.device_stats.launches else None),
             }
         if req.want_trace and self.tracer is not None:
             # the client asked for the server-side spans: ship them with

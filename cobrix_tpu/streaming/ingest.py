@@ -47,6 +47,7 @@ from ..api import (
     parse_options,
 )
 from ..obs.metrics import stream_metrics
+from ..reader.columnar import validate_backend
 from ..reader.fixed_len_reader import FixedLenReader
 from ..reader.index import IncrementalIndexer
 from ..reader.parameters import ReaderParameters
@@ -209,7 +210,7 @@ class ContinuousIngestor:
                 f"truncation_policy must be 'error' or 'restart', "
                 f"got {truncation_policy!r}")
         self.path = path
-        self.backend = backend
+        self.backend = validate_backend(backend)
         self.poll_interval_s = max(0.01, float(poll_interval_s))
         self.idle_timeout_s = idle_timeout_s
         self.max_batches = max_batches

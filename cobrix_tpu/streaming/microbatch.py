@@ -22,6 +22,7 @@ import time
 from typing import Iterable, Iterator, Optional
 
 from ..api import CobolData, list_input_files, parse_options
+from ..reader.columnar import validate_backend
 from ..reader.fixed_len_reader import FixedLenReader
 from ..reader.schema import CobolOutputSchema
 
@@ -54,7 +55,7 @@ class CobolStreamer:
             raise ValueError(
                 "Streaming supports fixed-length records only "
                 "(like the reference's CobolStreamer)")
-        self.backend = backend
+        self.backend = validate_backend(backend)
         self.reader = FixedLenReader(copybook_contents, params)
         self.params = params
         self._schema = CobolOutputSchema(

@@ -750,78 +750,3 @@ def test_fleetcheck_three_replica_smoke():
     fleetcheck = _load_tool("fleetcheck")
     with hard_timeout(420, "fleetcheck"):
         assert fleetcheck.check_fleet(sweep=False)
-
-
-# ---------------------------------------------------------------------------
-# bench satellite: the bounded, cached device probe
-# ---------------------------------------------------------------------------
-
-def test_bench_probe_hard_deadline_cache_and_skip_reason(
-        tmp_path, monkeypatch):
-    monkeypatch.setenv("COBRIX_JAX_PROBE_CACHE",
-                       str(tmp_path / "probe.json"))
-    monkeypatch.delenv("BENCH_FORCE_CPU", raising=False)
-    import bench
-
-    calls = []
-
-    def timeout_run(cmd, timeout=None, **kw):
-        calls.append(timeout)
-        raise subprocess.TimeoutExpired(cmd, timeout)
-
-    monkeypatch.setattr(bench.subprocess, "run", timeout_run)
-    platform, probe = bench._probe_jax(deadline_s=3)
-    assert platform is None
-    assert probe["skip_reason"] == "init_timeout"
-    assert probe["deadline_s"] == 3 and probe["cached"] is False
-    assert len(calls) == 1  # ONE bounded attempt, no escalation ladder
-    # failure cached: the next run skips the wait, reason preserved
-    platform2, probe2 = bench._probe_jax(deadline_s=3)
-    assert len(calls) == 1
-    assert probe2["skip_reason"] == "cached_failure"
-    assert "init_timeout" in probe2["error"]
-    # use_cache=False forces a fresh probe (the end-of-run retry)
-    bench._probe_jax(deadline_s=3, use_cache=False)
-    assert len(calls) == 2
-
-    def ok_run(cmd, timeout=None, **kw):
-        calls.append(timeout)
-
-        class R:
-            returncode = 0
-            stdout = "tpu\n"
-            stderr = ""
-
-        return R()
-
-    monkeypatch.setattr(bench.subprocess, "run", ok_run)
-    platform3, probe3 = bench._probe_jax(deadline_s=3, use_cache=False)
-    assert platform3 == "tpu" and probe3 is None
-    # success cached across runs: detection without a subprocess
-    monkeypatch.setattr(bench.subprocess, "run", timeout_run)
-    n = len(calls)
-    platform4, probe4 = bench._probe_jax(deadline_s=3)
-    assert platform4 == "tpu" and probe4 is None and len(calls) == n
-    doc = json.loads((tmp_path / "probe.json").read_text())
-    assert list(doc.values())[0]["platform"] == "tpu"
-
-
-def test_bench_probe_init_error_skip_reason(tmp_path, monkeypatch):
-    monkeypatch.setenv("COBRIX_JAX_PROBE_CACHE",
-                       str(tmp_path / "probe.json"))
-    monkeypatch.delenv("BENCH_FORCE_CPU", raising=False)
-    import bench
-
-    def fail_run(cmd, timeout=None, **kw):
-        class R:
-            returncode = 1
-            stdout = ""
-            stderr = "RuntimeError: no backend"
-
-        return R()
-
-    monkeypatch.setattr(bench.subprocess, "run", fail_run)
-    platform, probe = bench._probe_jax(deadline_s=3, use_cache=False)
-    assert platform is None
-    assert probe["skip_reason"] == "init_error"
-    assert "no backend" in probe["error"]

@@ -1,8 +1,8 @@
 """Fused Pallas decode kernel parity vs the numpy blueprint kernels.
 
 Runs in Pallas interpret mode on CPU (conftest pins JAX to the virtual CPU
-mesh); the same code path compiles with Mosaic on a real TPU (validated by
-the bench's pallas calibration and the device-parity sweep in round 3).
+mesh). tests/test_tpu_compile.py compiles the same kernels with Mosaic for
+a described v5e; chip_smoke.py runs them on the chip.
 """
 import numpy as np
 import pytest
@@ -13,9 +13,7 @@ from cobrix_tpu.reader.columnar import ColumnarDecoder, _pallas_group_spec
 from cobrix_tpu.testing.generators import (EXP1_COPYBOOK, EXP3_COPYBOOK,
                                            generate_exp1, generate_exp3)
 
-from conftest import jax_usable
-
-pytestmark = pytest.mark.skipif(not jax_usable(), reason="jax backend unusable")
+pytestmark = pytest.mark.jax
 
 
 def test_offsets_progression():

@@ -564,6 +564,9 @@ def test_gap_blocked_emitter_drains_on_failed_chunk_signal():
         em.emit(0, t)             # flushes straight through
         em.emit(2, t)             # gap at 1: buffered + charged
         em.emit(3, t)             # buffered + charged (budget now full)
+        # another scan of the tenant holds a byte too: without it the
+        # buffer would be waiting for itself and is let through
+        ctl.acquire_bytes("t", 1)
 
         blocked_done = threading.Event()
 
@@ -582,7 +585,35 @@ def test_gap_blocked_emitter_drains_on_failed_chunk_signal():
         assert time.monotonic() - t0 < 10  # not the 20s no-drain window
         em.finish()
         assert len(written) == 4  # 0,2,3,4 in order; 1 skipped
+        ctl.release_bytes("t", 1)
         assert ctl.inflight_bytes("t") == 0
+
+
+def test_reorder_buffer_never_waits_for_itself():
+    """Tables that finish ahead of the next chunk can outweigh the whole
+    byte budget (a chunk's table is several times its input on wide
+    records). The next chunk comes through the same serialized tap, so
+    an emit that waited for this buffer's own bytes would hold it out
+    until the no-drain timeout failed a healthy scan."""
+    import pyarrow as pa
+
+    from cobrix_tpu.serve.session import OrderedBatchEmitter
+
+    with hard_timeout(60, "self wait"):
+        t = pa.table({"v": list(range(1000))})
+        ctl = AdmissionController(
+            default_quota=TenantQuota(
+                max_inflight_bytes=int(t.nbytes * 2.5)),
+            byte_wait_timeout_s=20.0)
+        written = []
+        em = OrderedBatchEmitter(written.append, "t", controller=ctl)
+        t0 = time.monotonic()
+        for index in (1, 2, 3, 4):  # 4 tables against a 2.5-table budget
+            em.emit(index, t)
+        assert not written and ctl.inflight_bytes("t") == 4 * t.nbytes
+        em.emit(0, t)               # the one they all waited for
+        assert time.monotonic() - t0 < 5
+        assert len(written) == 5 and ctl.inflight_bytes("t") == 0
 
 
 def test_batch_callback_delivers_none_for_failed_chunks(fixed_file):

@@ -1,0 +1,204 @@
+"""The device programs of the main path compile for a TPU v5e.
+
+The TPU's compiler is installed with JAX and compiles for a chip that is
+described, not attached: these tests hand it the real programs at the
+widths chip_smoke.py runs them at, with the Pallas kernel forced out of
+interpret mode, and check what interpret-mode parity tests cannot — that
+Mosaic accepts the kernels (tiling, fast-memory use), that the program
+fits the device, and which collectives a mesh puts in. Nothing runs:
+a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, so a test file that
+loaded it while being collected would take it from every other xdist
+worker. For the same reason everything compiles in this process, in
+this one file.
+"""
+import os
+
+import numpy as np
+import pytest
+
+from cobrix_tpu import parse_copybook
+from cobrix_tpu.copybook.datatypes import Encoding
+from cobrix_tpu.ops import pallas_tpu
+from cobrix_tpu.reader.columnar import ColumnarDecoder, _pallas_group_spec
+from cobrix_tpu.testing.generators import EXP1_COPYBOOK, EXP3_COPYBOOK
+
+pytestmark = pytest.mark.jax
+
+KERNEL = "tpu_custom_call"
+EXP3_EXTENT = 16064
+HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Force the fused kernel out of interpret mode for code that picks
+    the mode from jax.default_backend() (the CPU, here)."""
+    build = pallas_tpu.build_fused_decode
+
+    def forced(groups, record_len, interpret=None):
+        return build(groups, record_len, interpret=False)
+
+    monkeypatch.setattr(pallas_tpu, "build_fused_decode", forced)
+
+
+def exp3_copybook():
+    return parse_copybook(EXP3_COPYBOOK,
+                          segment_redefines=["STATIC_DETAILS", "CONTACTS"])
+
+
+def compile_on(sharding, fn, batch: int, extent: int):
+    import jax
+
+    x = jax.ShapeDtypeStruct((batch, extent), np.uint8, sharding=sharding)
+    compiled = jax.jit(fn).lower(x).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+    return compiled
+
+
+def full_block(decoder) -> int:
+    """The batch a big read launches (ColumnarDecoder._device_block)."""
+    return decoder._device_block(10 ** 9, decoder.plan.max_extent)
+
+
+@pytest.mark.parametrize("batch", [2048, "full_block"])
+def test_exp3_decode_pallas(one_chip, mosaic, batch):
+    decoder = ColumnarDecoder(exp3_copybook(), backend="pallas")
+    assert decoder.plan.max_extent == EXP3_EXTENT
+    fn = decoder.build_jax_decode_fn()
+    assert fn.interpret is False
+    if batch == "full_block":
+        batch = full_block(decoder)
+        assert batch == 8192
+    compiled = compile_on(one_chip, fn, batch, EXP3_EXTENT)
+    assert KERNEL in compiled.as_text()
+
+
+def test_exp3_decode_xla_gather(one_chip):
+    decoder = ColumnarDecoder(exp3_copybook(), backend="jax")
+    fn = decoder.build_jax_decode_fn()
+    assert fn.interpret is None
+    compiled = compile_on(one_chip, fn, 2048, EXP3_EXTENT)
+    assert KERNEL not in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_chips", [1, 4])
+def test_device_aggregator(topo, mosaic, n_chips):
+    """NUM1+NUM2 over the exp3 'C' records: the kernel on every mesh, and
+    the cross-chip reduction only on the four-chip one."""
+    import jax
+    from jax.sharding import Mesh
+
+    from cobrix_tpu.parallel import DeviceAggregator
+
+    mesh = Mesh(np.asarray(topo.devices[:n_chips]), axis_names=("data",))
+    agg = DeviceAggregator(exp3_copybook(), columns=["NUM1", "NUM2"],
+                           active_segment="STATIC_DETAILS", mesh=mesh,
+                           backend="pallas")
+    program = agg.device_program()
+    assert program.interpreted is False
+    compiled, built = program.compiled_for(
+        jax.ShapeDtypeStruct((2048, agg.record_extent), np.uint8),
+        jax.ShapeDtypeStruct((), np.int32))
+    assert built and compiled.has_kernel
+    text = compiled.executable.as_text()
+    assert ("all-reduce" in text) == (n_chips > 1)
+
+
+# one group of every fused kind x output width (ops/pallas_tpu.py
+# StridedGroup); the display kind follows the copybook's encoding
+KINDS_COPYBOOK = """
+       01 R.
+          05 BIN-I32   PIC S9(6)  COMP.
+          05 BIN-I64   PIC S9(12) COMP.
+          05 BIN-WIDE  PIC S9(25) COMP.
+          05 BCD-I32   PIC S9(7)  COMP-3.
+          05 BCD-I64   PIC S9(15) COMP-3.
+          05 BCD-WIDE  PIC S9(25) COMP-3.
+          05 DSP-I32   PIC S9(5).
+          05 DSP-I64   PIC S9(15).
+          05 DSP-WIDE  PIC S9(25).
+"""
+
+
+@pytest.mark.parametrize("batch", [256, 4096])
+def test_kinds_matrix(one_chip, batch):
+    covered = set()
+    for encoding in (Encoding.EBCDIC, Encoding.ASCII):
+        decoder = ColumnarDecoder(
+            parse_copybook(KINDS_COPYBOOK, data_encoding=encoding),
+            backend="pallas")
+        groups = [_pallas_group_spec(g) for g in decoder.kernel_groups]
+        assert all(groups)
+        covered.update((g.kind, g.out) for g in groups)
+        fused = pallas_tpu.build_fused_decode(
+            groups, decoder.plan.max_extent, interpret=False)
+        assert fused.interpret is False
+        compiled = compile_on(one_chip, fused, batch,
+                              decoder.plan.max_extent)
+        assert KERNEL in compiled.as_text()
+    assert covered == {
+        (kind, out)
+        for kind in ("binary", "bcd", "display_ebcdic", "display_ascii")
+        for out in ("i32", "i64", "wide")}
+
+
+def test_exp1_decode_pallas_whole_program(one_chip, mosaic):
+    """Every kernel kind at irregular offsets, strings and floats beside
+    them, at the batch a big exp1 read launches. The slow one (about a
+    minute and a half): exp1's kernel unrolls 59 groups."""
+    decoder = ColumnarDecoder(parse_copybook(EXP1_COPYBOOK),
+                              backend="pallas")
+    batch = full_block(decoder)
+    assert batch == 65536
+    compiled = compile_on(one_chip, decoder.build_jax_decode_fn(), batch,
+                          decoder.plan.max_extent)
+    assert KERNEL in compiled.as_text()
+
+
+def test_device_framing_scan(one_chip):
+    """ops/device_framing's pointer-doubling RDW scan over a 32 MiB image
+    compiles and fits (it keeps several per-byte arrays live)."""
+    import jax
+
+    from cobrix_tpu.ops import device_framing
+
+    image = jax.ShapeDtypeStruct((32 * 1024 * 1024,), np.uint8,
+                                 sharding=one_chip)
+    compiled = device_framing._build_scan(False, 0).lower(image).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
