@@ -44,7 +44,7 @@ from .reader.parameters import (
     MultisegmentParameters,
     ReaderParameters,
 )
-from .profiling import ReadMetrics, stage
+from .profiling import PoolWait, ReadMetrics, Stage, stage, timed_stage
 from .reader.result import FileResult, rows_file_result
 from .reader.schema import CobolOutputSchema, StructType
 from .reader.stream import RetryPolicy, open_stream, path_scheme
@@ -710,12 +710,24 @@ class CobolData:
                 sink.close()
         return sink.getvalue() if path is None else None
 
+    @property
+    def _stage_stats(self):
+        """The read's DeviceStats: where the stages of work done after
+        the read (its obs context is gone) are counted."""
+        return self.metrics.device_stats if self.metrics is not None \
+            else None
+
     def to_arrow(self):
         """pyarrow Table with schema-declared types, built from the kernel
         outputs without row materialization (the reference must feed Spark
         rows, SparkCobolRowType.scala:24; a columnar framework emits
-        columns)."""
-        table = self._to_arrow_impl()
+        columns). The seconds of every call accumulate in
+        `metrics.timings_s["to_arrow"]`; what `_to_arrow_impl` does beyond
+        the per-batch builds (concatenation, the record-order take) is
+        the caller's thread's `assemble.table` stage."""
+        with stage(self.metrics, "to_arrow"), Stage("assemble.table",
+                                                    self._stage_stats):
+            table = self._to_arrow_impl()
         if (self.metrics is not None
                 and self.metrics.field_costs_acc is not None):
             # sequential assembly ran after the trace was written; fold
@@ -744,9 +756,10 @@ class CobolData:
             with ThreadPoolExecutor(
                     max_workers=min(self.parallelism,
                                     len(self._results))) as ex:
-                tables = list(ex.map(
-                    lambda r: r.to_arrow(self.output_schema),
-                    self._results))
+                with PoolWait(self._stage_stats):
+                    tables = list(ex.map(
+                        lambda r: r.to_arrow(self.output_schema),
+                        self._results))
         else:
             tables = [r.to_arrow(self.output_schema) for r in self._results]
         if not tables:
@@ -914,7 +927,8 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
     from concurrent.futures import ThreadPoolExecutor
 
     with ThreadPoolExecutor(max_workers=min(parallelism, len(shards))) as ex:
-        return list(ex.map(run_shard, enumerate(shards)))
+        with PoolWait():
+            return list(ex.map(run_shard, enumerate(shards)))
 
 
 def read_cobol(path=None,
@@ -1466,9 +1480,10 @@ def _read_fixed_len_chunked(reader, file_path: str, params, backend: str,
             or not compressed_chunkable(file_path, io):
         if skipper is not None and skipper.should_skip(file_path, 0, -1):
             return []
+        with timed_stage(stage_times, "read"):
+            whole = _read_file_bytes(file_path, retry, on_retry, io)
         return [track(reader.read_result(
-            _read_file_bytes(file_path, retry, on_retry, io),
-            backend=backend,
+            whole, backend=backend,
             file_id=file_order, first_record_id=base_record_id,
             input_file_name=file_path, ignore_file_size=ignore_file_size,
             stage_times=stage_times),
@@ -1488,7 +1503,8 @@ def _read_fixed_len_chunked(reader, file_path: str, params, backend: str,
             with open_stream(file_path, start_offset=done,
                              maximum_bytes=nbytes, retry=retry,
                              on_retry=on_retry, io=io) as stream:
-                data = stream.next_view(nbytes)
+                with timed_stage(stage_times, "read"):
+                    data = stream.next_view(nbytes)
                 if not data:
                     break
                 if len(data) % rs and done + len(data) < size:
@@ -1506,7 +1522,8 @@ def _read_fixed_len_chunked(reader, file_path: str, params, backend: str,
     with open_stream(file_path, retry=retry, on_retry=on_retry,
                      io=io) as stream:
         while done < size:
-            data = stream.next_view(min(chunk_bytes, size - done))
+            with timed_stage(stage_times, "read"):
+                data = stream.next_view(min(chunk_bytes, size - done))
             if not data:
                 break
             if len(data) % rs and done + len(data) < size:

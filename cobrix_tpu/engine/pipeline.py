@@ -49,7 +49,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 from ..obs.context import activate as obs_activate
 from ..obs.context import current as obs_current
 from ..obs.trace import maybe_parent
-from ..profiling import ReadMetrics, StageTimes
+from ..profiling import PoolWait, ReadMetrics, StageTimes
 from ..reader.diagnostics import ShardErrorPolicy, ShardFailureInfo
 from ..reader.stream import RetryPolicy, open_stream
 from .chunks import FixedChunk, plan_fixed_chunks
@@ -154,6 +154,13 @@ class PipelineExecutor:
 
     def run(self, tasks: Sequence[tuple],
             chunk_meta: Optional[Sequence[dict]] = None) -> List[object]:
+        # the caller only starts, supervises and joins the stage threads:
+        # they split the scan's wall among themselves
+        with PoolWait():
+            return self._run(tasks, chunk_meta)
+
+    def _run(self, tasks: Sequence[tuple],
+             chunk_meta: Optional[Sequence[dict]]) -> List[object]:
         n = len(tasks)
         results: List[object] = [None] * n
         if n == 0:

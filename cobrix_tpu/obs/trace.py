@@ -29,9 +29,17 @@ The export target is the Chrome trace-event JSON format
 (``chrome://tracing`` / https://ui.perfetto.dev): "X" complete events
 for spans, "i" instants for supervisor events, with process/thread
 metadata so each worker process and pipeline thread gets its own lane.
-This complements (not replaces) the `jax.profiler` device traces from
-profiling.profile_trace — the device kernels appear there, the host scan
-topology here.
+Which clock: every span of this module is on `time.perf_counter`, and
+exists only when a Tracer is attached (`trace_file=`, a served request
+with `trace=True`). The stages of a read (read/frame/decode/assemble and
+the finer ones of the device path) are ALSO emitted, always, as
+``cobrix.<stage>`` `jax.profiler.TraceAnnotation`s by `profiling.Stage`,
+the one primitive that feeds both: those lie on the PROFILER's clock
+beside the device's operations in an `.xplane.pb` (profiling.
+profile_trace). What only this module has — scan/shard/chunk parents,
+`queue_wait`, supervisor instants, cross-process merge — is on the
+host's clock alone; the device kernels appear only in the profiler's
+trace.
 """
 from __future__ import annotations
 

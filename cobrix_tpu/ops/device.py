@@ -7,8 +7,9 @@ decode and its statistics, the device aggregate) is a `DeviceProgram`.
 It compiles ahead of time, once per argument shape and under a lock —
 scan threads that share one decoder wait for the first compile instead
 of each repeating it — and keeps for every shape what a caller cannot
-get from a bare `jax.jit`: the seconds the compile took and whether the
-compiled program holds the fused Pallas kernel.
+get from a bare `jax.jit`: the seconds the compile took, how many of them
+went to tracing and lowering, and whether the compiled program holds the
+fused Pallas kernel.
 """
 from __future__ import annotations
 
@@ -21,6 +22,8 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 import jax
+
+from ..profiling import Stage
 
 # the Mosaic kernel's custom-call target in compiled HLO; absent when the
 # Pallas kernel ran through the interpreter or no group was fused
@@ -52,7 +55,10 @@ class CompiledShape:
     """One compiled executable with what was learned building it."""
 
     executable: object
+    # trace + lower + compile (or the load from the persistent cache),
+    # and the part of it spent tracing and lowering, which no cache keeps
     compile_s: float
+    lower_s: float
     has_kernel: bool
 
 
@@ -81,17 +87,20 @@ class DeviceProgram:
         entry = self._compiled.get(key)
         if entry is not None:
             return entry, False
-        with self._lock:
+        # threads that find the lock taken wait for the same compile: the
+        # stage covers the wait too
+        with Stage("compile"), self._lock:
             entry = self._compiled.get(key)
             if entry is not None:
                 return entry, False
             ensure_compile_cache()
             t0 = time.perf_counter()
-            executable = self._jit.lower(*[
-                jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in key
-            ]).compile()
+            lowered = self._jit.lower(*[
+                jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in key])
+            t1 = time.perf_counter()
+            executable = lowered.compile()
             entry = CompiledShape(
-                executable, time.perf_counter() - t0,
+                executable, time.perf_counter() - t0, t1 - t0,
                 KERNEL_CALL_TARGET in executable.as_text())
             self._compiled[key] = entry
             return entry, True

@@ -501,30 +501,36 @@ def build_fused_decode(groups: Sequence[StridedGroup], record_len: int,
             # and Mosaic rejects the (i32, i64) index tuple
             return (i, jnp.int32(0))
 
-        planes = []
-        for g in groups:
-            planes.extend(_byte_planes(data, g))
-        packed = (jnp.concatenate(planes, axis=1) if planes
-                  else data[:, :1])
-        o32, obool = pl.pallas_call(
-            functools.partial(_fused_kernel, layout),
-            grid=(n_tiles,),
-            in_specs=[pl.BlockSpec((BATCH_TILE, total_in), batch_row)],
-            out_specs=[pl.BlockSpec((BATCH_TILE, total_i32), batch_row),
-                       pl.BlockSpec((BATCH_TILE, total_bool), batch_row)],
-            out_shape=[jax.ShapeDtypeStruct((b + bpad, total_i32),
-                                            jnp.int32),
-                       jax.ShapeDtypeStruct((b + bpad, total_bool),
-                                            jnp.bool_)],
-            interpret=interpret,
-        )(packed)
+        # scopes named by the plan, not by the order of fusion: they land
+        # in every operation's `op_name`, so a trace names the step
+        with jax.named_scope("cobrix.planes"):
+            planes = []
+            for g in groups:
+                planes.extend(_byte_planes(data, g))
+            packed = (jnp.concatenate(planes, axis=1) if planes
+                      else data[:, :1])
+        with jax.named_scope("cobrix.kernel"):
+            o32, obool = pl.pallas_call(
+                functools.partial(_fused_kernel, layout),
+                grid=(n_tiles,),
+                in_specs=[pl.BlockSpec((BATCH_TILE, total_in), batch_row)],
+                out_specs=[pl.BlockSpec((BATCH_TILE, total_i32), batch_row),
+                           pl.BlockSpec((BATCH_TILE, total_bool),
+                                        batch_row)],
+                out_shape=[jax.ShapeDtypeStruct((b + bpad, total_i32),
+                                                jnp.int32),
+                           jax.ShapeDtypeStruct((b + bpad, total_bool),
+                                                jnp.bool_)],
+                interpret=interpret,
+            )(packed)
         results = []
-        for g, _, slots in layout:
-            bufs = []
-            for space, start in slots:
-                src = o32 if space == "i32" else obool
-                bufs.append(src[:b, start:start + g.count])
-            results.append(tuple(_assemble_group(bufs, g)))
+        with jax.named_scope("cobrix.outputs"):
+            for g, _, slots in layout:
+                bufs = []
+                for space, start in slots:
+                    src = o32 if space == "i32" else obool
+                    bufs.append(src[:b, start:start + g.count])
+                results.append(tuple(_assemble_group(bufs, g)))
         return results
 
     fn.interpret = interpret

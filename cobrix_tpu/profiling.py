@@ -1,15 +1,35 @@
-"""Profiling hooks: JAX profiler traces + structured read metrics.
+"""Profiling hooks: one stage primitive, JAX profiler traces, structured
+read metrics.
 
 The reference's observability is SLF4J logging around the scan
 (CobolScanners.scala:51, IndexBuilder.scala:216 — per-partition offsets
-and index counts). The TPU-native equivalents here:
+and index counts). What stands in for it here, and on which clock:
 
+- `Stage` (through `timed_stage`, `stage`, `StageTimes.timed`): the one
+  way to time a block. A single `with` feeds three sinks:
+  (a) a `jax.profiler.TraceAnnotation` named ``cobrix.<stage>`` — on the
+  PROFILER's clock, beside the device's operations in an `.xplane.pb`
+  (only in a process that has already imported JAX; a host-kernel read
+  never imports it for this);
+  (b) the read's `DeviceStats.stage_s` / `stage_n` — always on, on
+  `time.perf_counter`, SELF time: a thread-local stack pauses the
+  parent while a child stage runs, so the stages of one thread never
+  overlap. Where several threads of one read are inside stages at once
+  (a shard pool, pooled table builds, the pipeline's stage threads) each
+  instant is split evenly among them (`DeviceStats.stage_clock`), and
+  the thread that only waits for them (`PoolWait`) takes none: the
+  stages of a read add up to the part of its wall that some stage
+  covered, never to more;
+  (c) the `StageTimes` busy sums, `ReadMetrics.timings_s` and the
+  `obs.Tracer` span where those are attached — on `perf_counter`, whole
+  (inclusive) durations, as before.
+- `annotate(name)`: a bare span on the profiler's clock under exactly
+  `name` (``cobrix_decode`` round the launch loop: the benchmark's trace
+  reduction reads it); ~free when no trace is on.
 - `profile_trace(dir)`: a context manager wrapping any read/decode in a
   `jax.profiler.trace` session — the artifact opens in TensorBoard/XProf
-  and shows the fused kernel, transfers, and collectives on the device
-  timeline. The bench writes one such artifact per run.
-- `annotate(name)`: named TraceAnnotation spans used inside the decode
-  paths (visible on the profiler timeline; ~free when no trace is on).
+  and shows the fused kernel, transfers, and the spans above on one
+  timeline.
 - `ReadMetrics`: per-read structured counters (files, shards, records,
   bytes, per-stage timings) attached to every CobolData as `.metrics`.
 - `StageTimes`: thread-safe per-stage BUSY time accumulation for the
@@ -25,10 +45,22 @@ scope) and publishes read totals into the default registry.
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Dict, Optional
+
+from .obs import context as obs_context
+
+# the prefix of every stage's span on the profiler's timeline
+SPAN_PREFIX = "cobrix."
+
+# the clock of sinks (b) and (c); a module attribute so the self-time
+# arithmetic can be tested against a fake one
+_clock = time.perf_counter
+# per thread: the stages open on it, innermost last
+_open = threading.local()
 
 
 @contextlib.contextmanager
@@ -42,10 +74,105 @@ def profile_trace(output_dir: str):
 
 
 def annotate(name: str):
-    """Named span on the profiler timeline; ~free outside a trace."""
-    import jax
+    """Named span on the profiler timeline; ~free outside a trace. A
+    no-op in a process that has not imported JAX: nothing can be
+    profiling it, and a span is no reason to pay for the import."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(name)
 
-    return jax.profiler.TraceAnnotation(name)
+
+class Stage:
+    """One timed block of a read, fed to every sink that is attached
+    (module docstring). `stats` is the read's DeviceStats: given by the
+    caller for work after the read (the reference a DecodedBatch
+    captured), else taken from the thread's obs context."""
+
+    __slots__ = ("name", "stats", "stage_times", "metrics", "_t0", "_at0",
+                 "_children", "_joined", "_span")
+
+    def __init__(self, name: str, stats=None, stage_times=None,
+                 metrics=None):
+        self.name = name
+        self.stats = stats
+        self.stage_times = stage_times
+        self.metrics = metrics
+
+    def __enter__(self):
+        stats = self.stats
+        if stats is None:
+            ctx = obs_context.current()
+            if ctx is not None:
+                stats = self.stats = ctx.device_stats
+        self._span = annotate(SPAN_PREFIX + self.name)
+        self._span.__enter__()
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        self._joined = self._joins(
+            any(frame.stats is stats for frame in stack))
+        stack.append(self)
+        self._children = 0.0
+        self._t0 = now = _clock()
+        self._at0 = (now if stats is None
+                     else stats.stage_clock(now, self._joined))
+        return self
+
+    def __exit__(self, *exc):
+        t0, t1 = self._t0, _clock()
+        stats = self.stats
+        shared = (t1 if stats is None
+                  else stats.stage_clock(t1, -self._joined)) - self._at0
+        stack = _open.stack
+        stack.pop()
+        if stack:
+            stack[-1]._children += shared
+        self._record(t0, t1, shared - self._children)
+        self._span.__exit__(*exc)
+        return False
+
+    def _joins(self, inside: bool) -> int:
+        """+1 where this stage makes its thread one of those that share
+        the read's wall (`inside`: an enclosing stage already did)."""
+        return 0 if inside else 1
+
+    def _record(self, t0: float, t1: float, self_s: float) -> None:
+        if self.stats is not None:
+            self.stats.add_stage(self.name, self_s)
+        stage_times, metrics = self.stage_times, self.metrics
+        if stage_times is not None:
+            stage_times.add(self.name, t1 - t0)
+            if stage_times.tracer is not None:
+                stage_times.tracer.record_span(self.name, "stage", t0, t1)
+        if metrics is not None:
+            # locked accumulation: the pipelined executor runs stages of
+            # the same read on multiple threads
+            metrics.add_timing(self.name, t1 - t0)
+            if metrics.tracer is not None:
+                metrics.tracer.record_span(self.name, "phase", t0, t1)
+
+
+class PoolWait(Stage):
+    """The block in which a thread only waits for the read's other
+    threads (a shard pool, the pooled table builds, the pipeline's stage
+    threads). It pauses the stage it sits in and takes no share of the
+    wall, so that the workers' stages split it among themselves; counted
+    nowhere, but a `cobrix.pool_wait` span on the profiler's clock, which
+    `benchmark/stage_gaps.py` reads the same way."""
+
+    __slots__ = ()
+
+    def __init__(self, stats=None):
+        super().__init__("pool_wait", stats)
+
+    def _joins(self, inside: bool) -> int:
+        return -1 if inside else 0
+
+    def _record(self, t0, t1, self_s) -> None:
+        pass
 
 
 class StageTimes:
@@ -71,28 +198,19 @@ class StageTimes:
         with self._lock:
             self.busy_s[name] = self.busy_s.get(name, 0.0) + seconds
 
-    @contextlib.contextmanager
-    def timed(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self.add(name, t1 - t0)
-            if self.tracer is not None:
-                self.tracer.record_span(name, "stage", t0, t1)
+    def timed(self, name: str) -> Stage:
+        return Stage(name, stage_times=self)
 
     def as_dict(self) -> Dict[str, float]:
         with self._lock:
             return {k: round(v, 6) for k, v in self.busy_s.items()}
 
 
-def timed_stage(stage_times: Optional[StageTimes], name: str):
-    """`stage_times.timed(name)` or a no-op when no accumulator is wired
-    (sequential reads pass None through the reader hot paths)."""
-    if stage_times is None:
-        return contextlib.nullcontext()
-    return stage_times.timed(name)
+def timed_stage(stage_times: Optional[StageTimes], name: str) -> Stage:
+    """The stage `name` of the read this thread works for. `stage_times`
+    is the pipelined engine's accumulator; None on sequential reads,
+    where the read's DeviceStats and the profiler still get the stage."""
+    return Stage(name, stage_times=stage_times)
 
 
 class PassCounters:
@@ -128,8 +246,11 @@ class PassCounters:
 class DeviceStats:
     """What the device decode plane did for one read: program launches by
     padded batch shape, bytes over the link each way, the seconds spent
-    compiling, the devices the outputs lived on, and what kind of program
-    ran (does it hold the fused kernel; was that kernel interpreted).
+    compiling (`compile_s`, of which `lower_s` tracing and lowering), the
+    devices the outputs lived on, what kind of program ran (does it hold
+    the fused kernel; was that kernel interpreted), and the read's
+    seconds and entries by stage (`stage_s`, `stage_n`: self time, each
+    instant split among the threads inside stages; profiling.Stage).
     The record a caller needs to tell a read that used the chip from one
     that only says so. Shared like PassCounters: scan threads reach it
     through the ObsContext."""
@@ -140,10 +261,39 @@ class DeviceStats:
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.compile_s = 0.0
+        self.lower_s = 0.0
         self.compiles = 0
         self.devices: set = set()
         self.has_kernel: Optional[bool] = None
         self.interpreted: Optional[bool] = None
+        # seconds and entries by stage (profiling.Stage)
+        self.stage_s: Dict[str, float] = {}
+        self.stage_n: Dict[str, int] = {}
+        # the stage clock: seconds of the read's wall in which some
+        # thread was inside a stage, advancing by 1/threads of a second
+        # per second, so that the threads of a pool split each instant
+        self._stage_threads = 0
+        self._stage_clock_s = 0.0
+        self._stage_clock_at = 0.0
+
+    def stage_clock(self, now: float, joined: int) -> float:
+        """The stage clock's reading at the instant `now`, as a thread
+        joins (+1) or leaves (-1) the threads that are inside a stage of
+        this read, or does neither (0: a nested stage)."""
+        with self._lock:
+            if self._stage_threads > 0:
+                self._stage_clock_s += ((now - self._stage_clock_at)
+                                        / self._stage_threads)
+            self._stage_clock_at = now
+            self._stage_threads += joined
+            return self._stage_clock_s
+
+    def add_stage(self, name: str, self_s: float) -> None:
+        """One pass through the stage `name`, `self_s` of it (on the stage
+        clock) outside any child stage."""
+        with self._lock:
+            self.stage_s[name] = self.stage_s.get(name, 0.0) + self_s
+            self.stage_n[name] = self.stage_n.get(name, 0) + 1
 
     def note_launch(self, shape: tuple, h2d_bytes: int, d2h_bytes: int,
                     devices, program, built, interpreted) -> None:
@@ -157,6 +307,7 @@ class DeviceStats:
             if built:
                 self.compiles += 1
                 self.compile_s += program.compile_s
+                self.lower_s += program.lower_s
             # every launch of the read must agree before the read may
             # claim the kernel: one launch without it turns the flag off
             self.has_kernel = (program.has_kernel if self.has_kernel is None
@@ -173,9 +324,13 @@ class DeviceStats:
                 "d2h_bytes": self.d2h_bytes,
                 "compiles": self.compiles,
                 "compile_s": round(self.compile_s, 3),
+                "lower_s": round(self.lower_s, 3),
                 "devices": sorted(self.devices),
                 "has_kernel": self.has_kernel,
                 "interpreted": self.interpreted,
+                "stage_s": {k: round(v, 6) for k, v
+                            in sorted(self.stage_s.items())},
+                "stage_n": dict(sorted(self.stage_n.items())),
             }
 
 
@@ -437,28 +592,10 @@ class ReadMetrics:
         return out
 
 
-class _Stage:
-    def __init__(self, metrics: ReadMetrics, name: str):
-        self.metrics = metrics
-        self.name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        # locked accumulation: the pipelined executor runs stages of the
-        # same read on multiple threads, and a bare dict read-modify-write
-        # here loses increments under that interleaving
-        self.metrics.add_timing(self.name, t1 - self._t0)
-        tracer = self.metrics.tracer
-        if tracer is not None:
-            tracer.record_span(self.name, "phase", self._t0, t1)
-
-
-def stage(metrics: Optional[ReadMetrics], name: str):
-    """Accumulating wall-clock timer for one pipeline stage."""
-    if metrics is None:
-        return contextlib.nullcontext()
-    return _Stage(metrics, name)
+def stage(metrics: Optional[ReadMetrics], name: str) -> Stage:
+    """Accumulating wall-clock timer for one phase of a read
+    (`timings_s[name]`, whole durations), counted as a stage too."""
+    return Stage(name,
+                 stats=metrics.device_stats if metrics is not None
+                 else None,
+                 metrics=metrics)

@@ -27,6 +27,7 @@ from ..copybook.ast import Group, Primitive, Statement
 from ..copybook.datatypes import Integral
 from ..obs import fieldcost
 from ..plan.compiler import Codec
+from ..profiling import Stage
 from ..copybook.datatypes import SchemaRetentionPolicy, TrimPolicy
 from .columnar import (
     _FLOAT_CODECS,
@@ -297,6 +298,9 @@ class ArrowBatchBuilder:
         self.fc = batch.field_costs
         if self.fc is not None:
             _warm_pa_lazy_imports()
+        # the read's DeviceStats, captured by the batch the same way: the
+        # `assemble.*` stages below count on it (None outside a read)
+        self.stats = batch.stage_stats
 
     # -- leaves ------------------------------------------------------------
 
@@ -632,6 +636,17 @@ class ArrowBatchBuilder:
         if col is None:
             return pa.nulls(self.n, type=pa_type)
         spec = self.decoder.plan.columns[col]
+        if slot_path:
+            # a slot of an OCCURS element: the enclosing `assemble.list`
+            # stage covers it (exp3's per-slot route comes here 4,000
+            # times a batch)
+            return self._timed_leaf(st, col, spec, pa_type)
+        name = ("assemble.string" if spec.codec in _STRING_CODECS
+                else "assemble.scalar")
+        with Stage(name, self.stats):
+            return self._timed_leaf(st, col, spec, pa_type)
+
+    def _timed_leaf(self, st: Primitive, col: int, spec, pa_type):
         fc = self.fc
         if fc is None:
             return self._leaf_array_impl(st, col, spec, pa_type)
@@ -767,15 +782,16 @@ class ArrowBatchBuilder:
         """{col index -> pa.Array | None} for every decimal-typed column
         of one kernel group, via one decimal128_batch call."""
         fc = self.fc
-        if fc is None:
-            return self._build_decimal_group_impl(g)
-        tok = fc.begin()
-        entry = self._build_decimal_group_impl(g)
-        plan = self.decoder.plan
-        names = tuple(plan.cost_name(c) for c in g.columns
-                      if c.index in entry) or g.names
-        fc.commit(tok, names, fieldcost.PLANE_ASSEMBLE, 0, 0, g.label)
-        return entry
+        with Stage("assemble.decimal", self.stats):
+            if fc is None:
+                return self._build_decimal_group_impl(g)
+            tok = fc.begin()
+            entry = self._build_decimal_group_impl(g)
+            plan = self.decoder.plan
+            names = tuple(plan.cost_name(c) for c in g.columns
+                          if c.index in entry) or g.names
+            fc.commit(tok, names, fieldcost.PLANE_ASSEMBLE, 0, 0, g.label)
+            return entry
 
     def _build_decimal_group_impl(self, g) -> dict:
         from .. import native
@@ -1025,18 +1041,20 @@ class ArrowBatchBuilder:
     def _list_array(self, st: Statement, slot_path):
         """OCCURS -> ListArray: element slots interleaved via one take."""
         fc = self.fc
-        if fc is None:
-            return self._list_array_impl(st, slot_path)
-        tok = fc.begin()
-        arr = self._list_array_impl(st, slot_path)
-        # list glue (offsets, interleave take) charged to the array
-        # field itself; element builds are nested regions with their own
-        # charges — the OCCURS slots share the statement name, so the
-        # whole array still reads as one cost row
-        cols = self.decoder.plan.columns_for(st)
-        name = self.decoder.plan.cost_name(cols[0]) if cols else st.name
-        fc.commit(tok, (name,), fieldcost.PLANE_ASSEMBLE, 0, 0)
-        return arr
+        with Stage("assemble.list", self.stats):
+            if fc is None:
+                return self._list_array_impl(st, slot_path)
+            tok = fc.begin()
+            arr = self._list_array_impl(st, slot_path)
+            # list glue (offsets, interleave take) charged to the array
+            # field itself; element builds are nested regions with their
+            # own charges — the OCCURS slots share the statement name, so
+            # the whole array still reads as one cost row
+            cols = self.decoder.plan.columns_for(st)
+            name = (self.decoder.plan.cost_name(cols[0]) if cols
+                    else st.name)
+            fc.commit(tok, (name,), fieldcost.PLANE_ASSEMBLE, 0, 0)
+            return arr
 
     def _subtree_planned(self, st: Statement) -> bool:
         """True when any leaf under `st` has a compiled column. False

@@ -41,7 +41,7 @@ from .. import native
 from ..obs import context as obs_context
 from ..obs import fieldcost
 from ..ops import batch_np
-from ..profiling import annotate
+from ..profiling import Stage, annotate
 from ..plan.cache import cached_code_page_lut, cached_compile_plan
 from ..plan.compiler import Codec, ColumnSpec, FieldPlan
 from .extractors import DecodeOptions
@@ -344,6 +344,9 @@ class DecodedBatch:
         # obs context died (profiling.PassCounters; None outside a read)
         ctx = obs_context.current()
         self.pass_counts = ctx.pass_counts if ctx is not None else None
+        # the read's DeviceStats, captured the same way: the assembly
+        # stages (arrow_out) count on it after the read returned
+        self.stage_stats = ctx.device_stats if ctx is not None else None
 
     # -- vectorized access -------------------------------------------------
 
@@ -1071,8 +1074,10 @@ class ColumnarDecoder:
             if arr.shape[1] < extent:
                 # pad to the plan's byte extent; columns past a record's
                 # true end are nulled via `lengths`
-                padded = np.zeros((arr.shape[0], extent), dtype=np.uint8)
-                padded[:, :arr.shape[1]] = arr
+                with Stage("pack"):
+                    padded = np.zeros((arr.shape[0], extent),
+                                      dtype=np.uint8)
+                    padded[:, :arr.shape[1]] = arr
                 arr = padded
         if self.backend in DEVICE_BACKENDS:
             outputs = self._decode_jax(arr)
@@ -1110,9 +1115,10 @@ class ColumnarDecoder:
         lengths = np.minimum(rec_lengths - start_offset, extent_full)
 
         def packed_fallback():
-            batch = native.pack_records(data, rec_offsets, rec_lengths,
-                                        extent_full,
-                                        start_offset=start_offset)
+            with Stage("pack"):
+                batch = native.pack_records(data, rec_offsets, rec_lengths,
+                                            extent_full,
+                                            start_offset=start_offset)
             return self.decode(batch, lengths=lengths)
 
         if self.backend != "numpy" or not native.available():
@@ -1559,6 +1565,7 @@ class ColumnarDecoder:
         cannot partition a custom call — an unwrapped kernel would force
         an all-gather of the whole batch onto every chip); the non-fused
         XLA groups stay in the outer GSPMD context."""
+        import jax
         import jax.numpy as jnp
         from ..ops import batch_jax
 
@@ -1583,7 +1590,6 @@ class ColumnarDecoder:
                     strided, self.plan.max_extent)
                 interpret = fused.interpret
                 if mesh is not None and mesh.devices.size > 1:
-                    import jax
                     from jax.sharding import PartitionSpec
 
                     # decode is embarrassingly parallel: each device runs
@@ -1605,9 +1611,16 @@ class ColumnarDecoder:
                 if g.codec is Codec.HOST_FALLBACK:
                     outs[gi] = ()
                     continue
-                offs = jnp.asarray(g.offsets)
-                slab = data[:, offs[:, None] + jnp.arange(g.width)[None, :]]
-                outs[gi] = self._run_group_jax(g, slab, jnp, batch_jax, lut)
+                # a scope named by the plan (codec and width), not by
+                # the order of fusion: it lands in the `op_name` of every
+                # operation of the group
+                with jax.named_scope(
+                        "cobrix.group." + g.label.replace("/", "_")):
+                    offs = jnp.asarray(g.offsets)
+                    slab = data[:, offs[:, None]
+                                + jnp.arange(g.width)[None, :]]
+                    outs[gi] = self._run_group_jax(g, slab, jnp,
+                                                   batch_jax, lut)
             return outs
 
         # whether the fused kernel goes through the Pallas interpreter;
@@ -1655,13 +1668,17 @@ class ColumnarDecoder:
                 rows = arr[start:start + block]
                 m = rows.shape[0]
                 if m != block:
-                    padded = np.zeros((block, extent), dtype=np.uint8)
-                    padded[:m] = rows
+                    with Stage("pack"):
+                        padded = np.zeros((block, extent), dtype=np.uint8)
+                        padded[:m] = rows
                     rows = padded
-                x = jax.device_put(rows)
+                with Stage("h2d"):
+                    x = jax.device_put(rows)
                 compiled, built = program.compiled_for(x)
-                device_outs = compiled.executable(x)
-                host_outs = jax.device_get(device_outs)
+                with Stage("launch"):
+                    device_outs = compiled.executable(x)
+                with Stage("d2h_wait"):
+                    host_outs = jax.device_get(device_outs)
                 if stats is not None:
                     leaves = jax.tree_util.tree_leaves(device_outs)
                     stats.note_launch(
@@ -1673,11 +1690,13 @@ class ColumnarDecoder:
         if len(parts) == 1:
             merged = parts[0][0]
         else:
-            merged = [tuple(np.concatenate([outs[gi][k][:m]
-                                            for outs, m in parts])
-                            for k in range(len(group_outs)))
-                      for gi, group_outs in enumerate(parts[0][0])]
-        outputs = self.collect_outputs(merged, n)
+            with Stage("merge"):
+                merged = [tuple(np.concatenate([outs[gi][k][:m]
+                                                for outs, m in parts])
+                                for k in range(len(group_outs)))
+                          for gi, group_outs in enumerate(parts[0][0])]
+        with Stage("collect"):
+            outputs = self.collect_outputs(merged, n)
         if tok is not None:
             # one jitted program decodes every group: split its wall
             # (incl. transfers) across groups by bytes touched — coarser

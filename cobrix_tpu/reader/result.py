@@ -19,6 +19,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ..copybook.datatypes import SchemaRetentionPolicy
+from ..profiling import Stage
 from .columnar import DecodedBatch
 
 
@@ -255,14 +256,17 @@ class FileResult:
             seg_reasons = None
             if reasons:
                 seg_reasons = [reasons.get(int(p)) for p in seg.positions]
-            tables.append(segment_table(
-                seg.batch, seg.active, output_schema,
-                file_id=self.file_id,
-                record_ids=np.asarray(record_ids, dtype=np.int64),
-                seg_level_ids=seg.seg_level_ids,
-                input_file_name=self.input_file_name,
-                redefine_masks=seg.redefine_masks,
-                corrupt_reasons=seg_reasons))
+            # counted on the read's DeviceStats through the reference the
+            # batch captured: the read's obs context is gone by now
+            with Stage("assemble.table", seg.batch.stage_stats):
+                tables.append(segment_table(
+                    seg.batch, seg.active, output_schema,
+                    file_id=self.file_id,
+                    record_ids=np.asarray(record_ids, dtype=np.int64),
+                    seg_level_ids=seg.seg_level_ids,
+                    input_file_name=self.input_file_name,
+                    redefine_masks=seg.redefine_masks,
+                    corrupt_reasons=seg_reasons))
             order.append(np.asarray(seg.positions, dtype=np.int64))
         if len(tables) == 1:
             table = tables[0]

@@ -537,6 +537,12 @@ class ScanSession:
                 "pipeline": ({"chunks": m.pipeline.get("chunks"),
                               "overlap": m.pipeline.get("overlap")}
                              if m.pipeline else None),
+                # busy seconds by pipeline stage, summed over the stage
+                # threads (read/frame/decode/assemble; whole durations,
+                # so they exceed scan_s where stages overlapped). The
+                # self-time split is `device.stage_s`
+                "stage_busy_s": (m.stage_busy.as_dict()
+                                 if m.stage_busy is not None else None),
                 # per-field cost attribution + roofline anchoring: the
                 # streaming happened via batch_callback DURING the scan,
                 # so the table is complete here — serving clients get
@@ -553,9 +559,11 @@ class ScanSession:
                 # rollups
                 "pushdown": m.pushdown,
                 # what the device decode plane did (backend jax/pallas):
-                # launches by shape, bytes over the link, compiles, the
-                # devices that held the outputs — a client's only way
-                # to see that the chip answered; None on host reads
+                # launches by shape, bytes over the link, compiles (and
+                # how much of them was lowering), the devices that held
+                # the outputs, self seconds and entries by stage — a
+                # client's only way to see that the chip answered and
+                # where the request's time went; None on host reads
                 "device": (m.device_stats.as_dict()
                            if m.device_stats.launches else None),
             }

@@ -101,3 +101,18 @@ def read_golden_lines(name: str):
     require_reference_data()
     with open(os.path.join(REAL_REFERENCE_DATA, name), encoding="iso-8859-1") as f:
         return f.read().splitlines()
+
+
+def check_stage_record(device: dict, wall_s: float, stages: set) -> None:
+    stage_s, stage_n = device["stage_s"], device["stage_n"]
+    assert stages <= set(stage_s), sorted(stages - set(stage_s))
+    assert set(stage_s) == set(stage_n)
+    assert all(s >= 0.0 for s in stage_s.values())
+    assert all(n >= 1 for n in stage_n.values())
+    launches = sum(device["launches"].values())
+    assert stage_n["launch"] == launches
+    assert stage_n["h2d"] == launches and stage_n["d2h_wait"] == launches
+    # self time on one thread, each instant split among the threads of a
+    # pool: the stages add up to at most the wall
+    assert sum(stage_s.values()) <= wall_s
+    assert 0.0 <= device["lower_s"] <= device["compile_s"]
