@@ -104,6 +104,20 @@ def _validity_buffer(valid: np.ndarray):
     return pa.py_buffer(np.packbits(valid, bitorder="little"))
 
 
+def _packed_validity(valid: np.ndarray):
+    """(Arrow validity bitmap, null count) of a contiguous bool plane:
+    no bitmap where nothing is null (the usual case, and `all` reads a
+    byte plane several times faster than packing it), else packed once."""
+    if valid.all():
+        return None, 0
+    packed = native.pack_validity(valid.view(np.uint8))
+    if packed is None:  # no native library
+        return (_validity_buffer(valid),
+                valid.size - int(np.count_nonzero(valid)))
+    bitmap, nulls = packed
+    return _pa().py_buffer(bitmap), nulls
+
+
 def _decimal128_from_mantissa(mantissa: np.ndarray, valid: np.ndarray,
                               pa_type):
     """decimal128 array with the int64 mantissa as the unscaled value."""
@@ -465,7 +479,7 @@ class ArrowBatchBuilder:
                 continue
             out = batch._out.get(c.index)
             if out is None or "lazy_numeric" not in out:
-                continue  # planes already exist: existing routes serve them
+                continue  # planes already exist: _leaf_array_impl reads them
             pa_type = to_arrow_type(primitive_data_type(c.statement))
             desc = _asm_descriptor(c, pa_type)
             if desc is None:
@@ -572,7 +586,7 @@ class ArrowBatchBuilder:
         for c in cols:
             o = outm.get(c)
             if o is None or "lazy_numeric" not in o:
-                return None  # planes exist: the stack path serves them
+                return None  # planes exist: _plane_flat_values serves them
         key = (id(st), cols[0], compact_rows is None)
         cached = batch._asm_flat_cache.get(key)
         if cached is not None:
@@ -953,11 +967,18 @@ class ArrowBatchBuilder:
         """One record-major flat array covering every OCCURS slot of a
         numeric leaf (the slots live in one kernel group; per-slot
         pa.array calls would dominate wide-OCCURS materialization —
-        exp3's 2000-element plane is 4000 such calls otherwise).
+        exp3's 2000-element plane is 4000 such calls otherwise). One
+        algorithm fed from two sources: bytes not yet decoded go through
+        the fused native kernel (`_native_flat_values`, the host
+        backends), decoded planes give their group matrix's rows
+        (`_plane_flat_values`, the device backends); both serve the
+        compact and the positional shape.
         `compact_mask`/`compact_rows` (decode-once): build values for
         ONLY the visible rows — the caller verified hidden rows are
         nulled at an enclosing struct, where child buffers are invisible.
-        None -> caller uses the per-slot path."""
+        None -> caller uses the per-slot path (strings, wide or
+        dynamic-scale decimals, a truncated visible row, a leaf of
+        another segment arm, slots without a shared plane)."""
         pa = _pa()
         pa_type = to_arrow_type(primitive_data_type(st))
         is_decimal = pa.types.is_decimal(pa_type)
@@ -983,37 +1004,86 @@ class ArrowBatchBuilder:
                 trunc = trunc & relevant
             if bool(trunc.any()):
                 return None  # truncated tails own the partial-field rules
-        if compact_rows is not None:
-            return self._native_flat_values(st, cols, spec0, pa_type,
-                                            max_size,
-                                            compact_rows=compact_rows)
-        arr = self._native_flat_values(st, cols, spec0, pa_type, max_size,
-                                       row_mask=relevant)
-        if arr is not None:
-            return arr
-        if relevant is not None:
-            # no native pass: hidden rows would need Python-side blanking
-            # — keep the existing masked per-slot route
+        # bytes not yet decoded (the host backends defer): one fused
+        # native pass from the record image into the flat buffers
+        arr = self._native_flat_values(
+            st, cols, spec0, pa_type, max_size,
+            row_mask=None if compact_rows is not None else relevant,
+            compact_rows=compact_rows)
+        if arr is None:
+            # already decoded (a device backend's planes, or a deferred
+            # group the fused pass declined): the group matrix's own rows
+            arr = self._plane_flat_values(cols, spec0, pa_type, relevant,
+                                          compact_rows)
+        return arr
+
+    def _slot_plane(self, cols):
+        """(values matrix, valid matrix, column slice) when the slot
+        columns `cols` of one OCCURS leaf, in slot order, are evenly
+        spaced columns of ONE decoded group matrix (`plane` of
+        columnar._store_numeric: consecutive where the leaf has the
+        group's columns to itself within an element, strided where
+        sibling leaves of the same kernel group sit between its slots).
+        None where a slot has no such plane: wide limbs, host-fallback
+        values, strings."""
+        planes = [self.batch.column_arrays(c).get("plane") for c in cols]
+        if any(p is None for p in planes):
             return None
+        values, valid, p0 = planes[0]
+        step = planes[1][2] - p0 if len(planes) > 1 else 1
+        stop = p0 + len(planes) * step
+        if (step < 1 or any(p[0] is not values for p in planes)
+                or [p[2] for p in planes] != list(range(p0, stop, step))):
+            return None
+        return values, valid, slice(p0, stop, step)
+
+    def _plane_flat_values(self, cols, spec0, pa_type, relevant,
+                           compact_rows):
+        """Record-major flat values array for all slots of one OCCURS
+        numeric leaf whose columns are ALREADY decoded into one group
+        matrix [n, ncols] (every device-backend batch): the matrix's rows
+        are the record-major order a list's values want, so the slots'
+        columns are sliced out of it in one piece — no per-slot arrays,
+        no stack, no interleaving take. `compact_rows`: one ascending
+        row gather keeps only the visible rows (the caller gives hidden
+        rows empty lists). Otherwise positional, and `relevant` (rows
+        that cannot be dropped) nulls the hidden rows' slots, as the
+        fused native pass does. The matrix may have any strides: the
+        host kernels' and XLA:CPU's are row-major, a TPU hands its
+        planes back column-major (the program's output layout), where
+        the one copy below is a transposing one. None -> the per-slot
+        path (no shared plane, or a decimal wider than an exact int64
+        mantissa)."""
+        pa = _pa()
+        is_decimal = pa.types.is_decimal(pa_type)
         if is_decimal and pa_type.precision > 18:
-            return None  # the stack path below is exact-int64 only
-        outs = [self.batch.column_arrays(c) for c in cols]
-        if any("values" not in o or "values_hi" in o for o in outs):
             return None
-        vals = np.stack([o["values"] for o in outs], axis=1)
-        valid = np.stack([o["valid"] for o in outs], axis=1)
-        flat = vals.reshape(-1)
-        fvalid = valid.reshape(-1)
-        mask = None if fvalid.all() else ~fvalid
+        shift = _static_decimal_shift(spec0, pa_type) if is_decimal else 0
+        if shift is None:
+            return None
+        plane = self._slot_plane(cols)
+        if plane is None:
+            return None
+        values, valid, slots = plane
+        values, valid = values[:, slots], valid[:, slots]
+        if compact_rows is not None:
+            values, valid = values[compact_rows], valid[compact_rows]
+        elif relevant is not None:
+            valid = valid & relevant[:, None]
+        # one copy into record-major order; none where every row stays
+        # and the leaf's slots are the whole of a row-major matrix
+        flat = np.ascontiguousarray(values).reshape(-1)
+        fvalid = np.ascontiguousarray(valid).reshape(-1)
+        if self.batch.pass_counts is not None:
+            self.batch.pass_counts.incr("plane_list")
         if is_decimal:
-            shift = _static_decimal_shift(spec0, pa_type)
-            if shift is None:
-                return None
             mantissa = flat.astype(np.int64, copy=False) * 10 ** shift
-            return _decimal128_from_mantissa(
-                mantissa, fvalid, pa_type)
-        return pa.array(
-            flat.astype(_numpy_dtype_for(pa_type), copy=False), mask=mask)
+            return _decimal128_from_mantissa(mantissa, fvalid, pa_type)
+        flat = flat.astype(_numpy_dtype_for(pa_type), copy=False)
+        vbuf, nulls = _packed_validity(fvalid)
+        return pa.Array.from_buffers(
+            pa_type, len(flat), [vbuf, pa.py_buffer(flat)],
+            null_count=nulls)
 
     def _flat_struct_values(self, group: Group, slot_path, max_size: int,
                             compact_mask=None, compact_rows=None):
@@ -1039,7 +1109,7 @@ class ArrowBatchBuilder:
         return pa.StructArray.from_arrays(children, names=names)
 
     def _list_array(self, st: Statement, slot_path):
-        """OCCURS -> ListArray: element slots interleaved via one take."""
+        """OCCURS -> ListArray, one per batch under `assemble.list`."""
         fc = self.fc
         with Stage("assemble.list", self.stats):
             if fc is None:
@@ -1130,10 +1200,11 @@ class ArrowBatchBuilder:
         counts_probe = self._occurs_counts(st)
         if n and max_size and n * max_size < 2**31 - 1:
             # position-addressed assembly: ONE flat record-major values
-            # array (slot s of record i at i*S+s), built natively when
-            # the fused kernel applies and by the numpy stack path
-            # otherwise — never the slot-major concat + random-access
-            # take interleave below
+            # array (slot s of record i at i*S+s), built by the fused
+            # native kernel from bytes not yet decoded, and from the
+            # decoded group matrix's own rows otherwise (a device
+            # backend's planes) — never the slot-major concat +
+            # random-access take interleave below
             flat = None
             if not self._subtree_planned(st):
                 # projection pruned the whole plane: zero assembly —
@@ -1190,9 +1261,19 @@ class ArrowBatchBuilder:
                 offsets = np.zeros(n + 1, dtype=np.int32)
                 np.cumsum(counts, out=offsets[1:])
                 return pa.ListArray.from_arrays(pa.array(offsets), values)
+        # what only the slots can do: strings, nested groups or arrays in
+        # the element, wide or dynamic-scale decimals, truncated tails,
+        # host-fallback columns
+        with Stage("assemble.list.slots", self.stats):
+            return self._slot_major_list(st, slot_path, counts_probe)
+
+    def _slot_major_list(self, st: Statement, slot_path, counts):
+        """One array per slot, concatenated slot-major and interleaved
+        back into record order by one take."""
+        pa = _pa()
+        n, max_size = self.n, st.array_max_size
         elems = [self._statement_array(st, slot_path + (k,), as_element=True)
                  for k in range(max_size)]
-        counts = counts_probe
         if n == 0 or max_size == 0:
             value_type = (elems[0].type if elems
                           else to_arrow_type(self._element_schema_type(st)))

@@ -173,13 +173,35 @@ def _scatter_rows(arr: np.ndarray, mask: np.ndarray, n: int) -> np.ndarray:
     return out
 
 
-def _scatter_output(out: dict, mask: np.ndarray, n: int) -> dict:
-    """Scatter one column's output dict from subset rows to full length.
-    Only plain array planes reach here: string codecs defer before the
-    masked routing and HOST_FALLBACK groups are excluded from it
-    explicitly in decode_raw."""
-    return {k: _scatter_rows(np.asarray(v), mask, n)
-            for k, v in out.items()}
+def _scatter_outputs(outputs: Dict[int, dict], mask: np.ndarray,
+                     n: int) -> Dict[int, dict]:
+    """Scatter the columns' output dicts of one subset decode from subset
+    rows to full length. Only plain array planes reach here: string
+    codecs defer before the masked routing and HOST_FALLBACK groups are
+    excluded from it explicitly in decode_raw. A group matrix that
+    `_store_numeric` handed out column by column is scattered once, and
+    the columns stay views of it (their `plane` stays true)."""
+    whole: Dict[int, np.ndarray] = {}
+
+    def matrix(m):
+        full = whole.get(id(m))
+        if full is None:
+            full = whole[id(m)] = _scatter_rows(m, mask, n)
+        return full
+
+    result = {}
+    for col, out in outputs.items():
+        plane = out.get("plane")
+        of_plane = () if plane is None else ("plane", "values", "valid")
+        full = {k: _scatter_rows(np.asarray(v), mask, n)
+                for k, v in out.items() if k not in of_plane}
+        if plane is not None:
+            values, valid, pos = plane
+            values, valid = matrix(values), matrix(valid)
+            full.update(values=values[:, pos], valid=valid[:, pos],
+                        plane=(values, valid, pos))
+        result[col] = full
+    return result
 
 
 def _masks_equal(a, b) -> bool:
@@ -315,7 +337,8 @@ class DecodedBatch:
         self.decoder = decoder
         self.data = data
         self.n_records = data.shape[0]
-        self._out = outputs  # col index -> {"values","valid","dot_scale","bytes"}
+        # col index -> {"values","valid","plane","dot_scale","bytes"}
+        self._out = outputs
         self._str_cache: Dict[int, List[str]] = {}
         self._col_cache: Dict[int, list] = {}
         self._maker_cache: Dict[tuple, object] = {}
@@ -1218,8 +1241,7 @@ class ColumnarDecoder:
                                       ext)
             sub_out: Dict[int, dict] = {}
             self._run_groups(gs, sub, sub_out)
-            for col, out in sub_out.items():
-                outputs[col] = _scatter_output(out, mask, n)
+            outputs.update(_scatter_outputs(sub_out, mask, n))
 
         batch = native.pack_records(buf, offs, rec_lengths, narrow_extent)
         self._run_groups(narrow_groups, batch, outputs)
@@ -1514,11 +1536,18 @@ class ColumnarDecoder:
 
     def _store_numeric(self, g: _KernelGroup, outputs: Dict[int, dict],
                        values, valid, dot_scale=None) -> None:
+        """One output dict per column of a decoded group. `values` and
+        `valid` are column views of the group's [n, ncols] matrices;
+        `plane` names those matrices and the column's position in them,
+        so that a consumer of many columns of one group (the Arrow list
+        builder, over the slots of an OCCURS) can take the matrix's own
+        rows instead of stacking the views back together."""
         values = np.asarray(values)
         valid = np.asarray(valid)
         dots = None if dot_scale is None else np.asarray(dot_scale)
         for pos, c in enumerate(g.columns):
-            out = {"values": values[:, pos], "valid": valid[:, pos]}
+            out = {"values": values[:, pos], "valid": valid[:, pos],
+                   "plane": (values, valid, pos)}
             if dots is not None:
                 out["dot_scale"] = dots[:, pos]
             elif c.params.scale_factor < 0 and g.codec is Codec.BINARY:

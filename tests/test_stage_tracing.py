@@ -232,6 +232,13 @@ def test_exp3_read_counts_every_stage(exp3_file, small_blocks):
     assert again["device"]["stage_n"]["to_arrow"] == 2
     # one slot leaf of the OCCURS is not a stage of its own
     assert device["stage_n"]["assemble.scalar"] < 100
+    # the one batch's list came from the decoded planes (NUM1 and NUM2),
+    # not slot by slot
+    assert device["stage_n"]["assemble.list"] == 1
+    assert metrics["native_passes"]["plane_list"] == 2
+    assert again["device"]["stage_n"]["assemble.list"] == 2
+    assert again["native_passes"]["plane_list"] == 4
+    assert "assemble.list.slots" not in again["device"]["stage_s"]
 
 
 def test_a_sharded_read_splits_the_wall_among_its_threads(exp3_file):
@@ -249,9 +256,28 @@ def test_a_sharded_read_splits_the_wall_among_its_threads(exp3_file):
     check_stage_record(device, wall_s, EXP3_STAGES - {"merge"})
     assert device["stage_n"]["decode"] == 4
     assert device["stage_n"]["assemble.list"] == 4
+    assert metrics["native_passes"]["plane_list"] == 2 * 4
+    assert "assemble.list.slots" not in device["stage_s"]
     # whole durations, summed over the pool's threads, may pass the wall;
     # the shared clock may not
     assert "pool_wait" not in device["stage_s"]
+
+
+def test_a_list_of_strings_is_built_slot_by_slot(tmp_path):
+    """What the plane route cannot serve runs under a stage of its own,
+    beneath `assemble.list`."""
+    path = tmp_path / "strings.bin"
+    path.write_bytes(b"\xc1\xc2\xc3\xc4\xc5\xc6" * 20)
+    data = read_cobol(str(path), backend="jax", copybook_contents="""
+       01 R.
+          05 S OCCURS 3 PIC X(2).
+    """)
+    assert data.to_arrow().column("R")[0].as_py() == {"S": ["AB", "CD", "EF"]}
+    metrics = data.metrics.as_dict()
+    device = metrics["device"]
+    assert device["stage_n"]["assemble.list.slots"] == 1
+    assert device["stage_n"]["assemble.list"] == 1
+    assert "plane_list" not in metrics["native_passes"]
 
 
 def test_a_pipelined_read_counts_on_its_stage_threads(exp3_file):
