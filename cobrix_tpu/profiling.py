@@ -245,7 +245,8 @@ class PassCounters:
 
 class DeviceStats:
     """What the device decode plane did for one read: program launches by
-    padded batch shape, bytes over the link each way, the seconds spent
+    padded batch shape, the records they decoded (padding excluded),
+    bytes over the link each way, the seconds spent
     compiling (`compile_s`, of which `lower_s` tracing and lowering), the
     devices the outputs lived on, what kind of program ran (does it hold
     the fused kernel; was that kernel interpreted), and the read's
@@ -258,6 +259,7 @@ class DeviceStats:
     def __init__(self):
         self._lock = threading.Lock()
         self.launches: Dict[tuple, int] = {}
+        self.records = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.compile_s = 0.0
@@ -295,12 +297,15 @@ class DeviceStats:
             self.stage_s[name] = self.stage_s.get(name, 0.0) + self_s
             self.stage_n[name] = self.stage_n.get(name, 0) + 1
 
-    def note_launch(self, shape: tuple, h2d_bytes: int, d2h_bytes: int,
-                    devices, program, built, interpreted) -> None:
-        """One program launch. `program` is the ops.device.CompiledShape
-        that ran, `built` whether this launch had to compile it."""
+    def note_launch(self, shape: tuple, records: int, h2d_bytes: int,
+                    d2h_bytes: int, devices, program, built,
+                    interpreted) -> None:
+        """One program launch of `records` rows padded to `shape`.
+        `program` is the ops.device.CompiledShape that ran, `built`
+        whether this launch had to compile it."""
         with self._lock:
             self.launches[shape] = self.launches.get(shape, 0) + 1
+            self.records += records
             self.h2d_bytes += h2d_bytes
             self.d2h_bytes += d2h_bytes
             self.devices.update(str(d) for d in devices)
@@ -320,6 +325,7 @@ class DeviceStats:
             return {
                 "launches": {f"{b}x{e}": n for (b, e), n
                              in sorted(self.launches.items())},
+                "records": self.records,
                 "h2d_bytes": self.h2d_bytes,
                 "d2h_bytes": self.d2h_bytes,
                 "compiles": self.compiles,

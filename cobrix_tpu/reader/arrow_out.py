@@ -1396,32 +1396,36 @@ def segment_table(batch: DecodedBatch,
     def seg_arrays():
         from .result import SegLevelColumns
 
-        out = []
-        for lvl in range(output_schema.generate_seg_id_field_count):
-            if isinstance(seg_level_ids, SegLevelColumns):
-                ab = seg_level_ids.arrow_level(lvl)
-                if ab is not None:
-                    # native int-formatted Seg_Id buffers — no Python
-                    # strings at all
-                    offsets, data, valid = ab
-                    vbuf = (None if valid.all()
-                            else _validity_buffer(valid))
-                    out.append(pa.Array.from_buffers(
-                        pa.string(), n,
-                        [vbuf, pa.py_buffer(offsets),
-                         pa.py_buffer(data)]))
-                    continue
-                # per-level object column straight into Arrow (no
-                # per-row list materialization)
-                vals = (seg_level_ids.levels[lvl]
-                        if lvl < len(seg_level_ids.levels)
-                        else [None] * n)
-            elif seg_level_ids is not None:
-                vals = [row[lvl] if row is not None and lvl < len(row)
-                        else None for row in seg_level_ids]
-            else:
-                vals = [None] * n
-            out.append(pa.array(vals, type=pa.string()))
+        levels = output_schema.generate_seg_id_field_count
+        if not levels:
+            return []
+        with Stage("assemble.seg_id", batch.stage_stats):
+            out = []
+            for lvl in range(levels):
+                if isinstance(seg_level_ids, SegLevelColumns):
+                    ab = seg_level_ids.arrow_level(lvl)
+                    if ab is not None:
+                        # native int-formatted Seg_Id buffers — no Python
+                        # strings at all
+                        offsets, data, valid = ab
+                        vbuf = (None if valid.all()
+                                else _validity_buffer(valid))
+                        out.append(pa.Array.from_buffers(
+                            pa.string(), n,
+                            [vbuf, pa.py_buffer(offsets),
+                             pa.py_buffer(data)]))
+                        continue
+                    # per-level object column straight into Arrow (no
+                    # per-row list materialization)
+                    vals = (seg_level_ids.levels[lvl]
+                            if lvl < len(seg_level_ids.levels)
+                            else [None] * n)
+                elif seg_level_ids is not None:
+                    vals = [row[lvl] if row is not None and lvl < len(row)
+                            else None for row in seg_level_ids]
+                else:
+                    vals = [None] * n
+                out.append(pa.array(vals, type=pa.string()))
         return out
 
     # Generated columns in ROW order (extractors._apply_post_processing /

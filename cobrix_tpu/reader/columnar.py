@@ -1711,7 +1711,7 @@ class ColumnarDecoder:
                 if stats is not None:
                     leaves = jax.tree_util.tree_leaves(device_outs)
                     stats.note_launch(
-                        (block, extent), x.nbytes,
+                        (block, extent), m, x.nbytes,
                         sum(leaf.nbytes for leaf in leaves),
                         {d for leaf in leaves for d in leaf.devices()},
                         compiled, built, program.interpreted)

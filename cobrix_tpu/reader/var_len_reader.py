@@ -24,7 +24,7 @@ from ..copybook.ast import Group, Primitive
 from ..copybook.copybook import Copybook
 from ..plan.cache import copybook_for_params, decoder_cache_for
 from ..obs.context import count_pass, current as obs_current
-from ..profiling import timed_stage
+from ..profiling import Stage, timed_stage
 from .columnar import ColumnarDecoder, decoder_for_segment
 from .extractors import (
     DecodeOptions,
@@ -920,9 +920,10 @@ class VarLenReader:
         keep = np.ones(n, dtype=bool)
         level_ids_per_record: Optional[List[List[Optional[str]]]] = None
         if level_count and segment_ids is not None:
-            level_ids_per_record, no_root = _segment_level_ids_vectorized(
-                segment_ids, seg.segment_level_ids, prefix, file_id,
-                start_record_id)
+            with Stage("seg_id"):
+                level_ids_per_record, no_root = _segment_level_ids_vectorized(
+                    segment_ids, seg.segment_level_ids, prefix, file_id,
+                    start_record_id)
             keep[no_root] = False  # before the first matched segment
         if segment_filter is not None and segment_ids is not None:
             keep &= segment_ids.mask_of(segment_filter)

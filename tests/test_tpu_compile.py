@@ -23,7 +23,8 @@ from cobrix_tpu import parse_copybook
 from cobrix_tpu.copybook.datatypes import Encoding
 from cobrix_tpu.ops import pallas_tpu
 from cobrix_tpu.reader.columnar import ColumnarDecoder, _pallas_group_spec
-from cobrix_tpu.testing.generators import EXP1_COPYBOOK, EXP3_COPYBOOK
+from cobrix_tpu.testing.generators import (EXP1_COPYBOOK, EXP2_COPYBOOK,
+                                           EXP3_COPYBOOK)
 
 pytestmark = pytest.mark.jax
 
@@ -105,6 +106,24 @@ def test_exp3_decode_pallas(one_chip, mosaic, batch):
         assert batch == 8192
     compiled = compile_on(one_chip, fn, batch, EXP3_EXTENT)
     assert KERNEL in compiled.as_text()
+
+
+def test_exp2_decode_pallas_full_block(one_chip, mosaic):
+    """64 B records of strings, half a vreg's lanes, at the largest batch
+    the decoder launches (2,097,152 rows when this was written: a 100 MiB
+    shard of an exp2 read is 1.6 million records)."""
+    decoder = ColumnarDecoder(
+        parse_copybook(EXP2_COPYBOOK,
+                       segment_redefines=["STATIC_DETAILS", "CONTACTS"]),
+        backend="pallas")
+    assert decoder.plan.max_extent == 64
+    batch = full_block(decoder)
+    compiled = compile_on(one_chip, decoder.build_jax_decode_fn(), batch, 64)
+    assert KERNEL in compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(f"exp2 {batch}x64: {mem.argument_size_in_bytes} B in, "
+          f"{mem.output_size_in_bytes} B out, "
+          f"{mem.temp_size_in_bytes} B of temporaries")
 
 
 def test_exp3_decode_xla_gather(one_chip):
