@@ -113,8 +113,8 @@ def run_device_query(mb_target: float, platform: str) -> dict:
             segment_id_redefine_map={"C": "STATIC_DETAILS",
                                      "P": "CONTACTS"}))
     reader = VarLenReader(EXP3_COPYBOOK, params)
-    # backend resolves per platform: fused Pallas kernel on TPU, the XLA
-    # gather path elsewhere (parallel/sharded.resolve_device_backend)
+    # backend resolves per platform: fused Pallas kernel on TPU, the plain
+    # XLA program elsewhere (parallel/sharded.resolve_device_backend)
     agg = DeviceAggregator(reader.copybook, columns=["NUM1", "NUM2"],
                            active_segment="STATIC_DETAILS")
     _log(f"device query decode backend: {agg.decoder.backend}")

@@ -71,11 +71,15 @@ def _shape_key(args) -> Tuple:
 class DeviceProgram:
     """`jax.jit(fn, **jit_options)` compiled ahead of time per argument
     shape. `interpreted` says whether the program's Pallas kernel runs
-    through the interpreter (None: it has no Pallas kernel)."""
+    through the interpreter (None: it has no Pallas kernel);
+    `device_groups` how many of the decode's kernel groups took which
+    route ({"fused", "sliced", "gathered"}: build_jax_decode_fn)."""
 
     def __init__(self, fn, interpreted: Optional[bool] = None,
+                 device_groups: Optional[Dict[str, int]] = None,
                  **jit_options):
         self.interpreted = interpreted
+        self.device_groups = device_groups
         self._jit = jax.jit(fn, **jit_options)
         self._lock = threading.Lock()
         self._compiled: Dict[Tuple, CompiledShape] = {}

@@ -23,10 +23,10 @@ count]` tiles. Values wider than 32 bits (10-18 digit fields, and the
 19-38 digit BigDecimal plane) are accumulated in base-2^16 limbs held in
 int32 lanes — TPUs have no native int64 — and assembled into int64 /
 uint64-pair outputs by XLA after the kernel, so every fused group returns
-exactly the tuples the XLA gather path produces (`columnar.
-_run_group_jax` contracts). String groups keep the XLA LUT-gather path
-(a 256-entry transcode XLA already lowers well); floats and host-fallback
-columns are the only other non-fused planes.
+exactly the tuples the plain XLA route produces (`columnar.
+_run_group_jax` contracts). String groups stay on that route (static
+slices and an element-wise lookup, `batch_jax.transcode_ebcdic`); floats
+and host-fallback columns are the only other non-fused planes.
 
 Parity is pinned by tests/test_pallas_kernels.py against the numpy
 blueprint kernels through the interpreter, tests/test_tpu_compile.py
@@ -401,7 +401,7 @@ def _assemble_u128(limbs):
 
 
 def _assemble_group(outs, g: StridedGroup):
-    """Kernel buffers -> the exact tuple the XLA gather path returns for
+    """Kernel buffers -> the exact tuple the plain XLA route returns for
     this group (int64 values via x64, uint64 limb pairs for wide)."""
     if g.out == "i32":
         return tuple(outs)

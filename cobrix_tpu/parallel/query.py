@@ -214,6 +214,7 @@ class DeviceAggregator:
 
         sharding = batch_sharding(self.mesh)
         return DeviceProgram(agg, interpreted=decode_all.interpret,
+                             device_groups=decode_all.device_groups,
                              in_shardings=(sharding, None))
 
     def device_program(self):

@@ -24,15 +24,15 @@ from .mesh import batch_sharding, data_mesh, pad_batch_to_multiple
 
 def resolve_device_backend(backend: Optional[str]) -> str:
     """Map the default ("auto") device backend to the platform: the fused
-    Pallas kernel on real TPU (the production decode plane), the XLA
-    gather path elsewhere (interpret-mode pallas on CPU is a parity tool,
+    Pallas kernel on real TPU (the production decode plane), the plain
+    XLA program elsewhere (interpret-mode pallas on CPU is a parity tool,
     not a fast path). An explicit "jax"/"pallas" wins."""
     if backend not in (None, "auto"):
         return backend
     import jax
 
-    # a backend that cannot initialise raises here: picking the gather
-    # path for it would only move the failure to the first decode
+    # a backend that cannot initialise raises here: picking the plain
+    # program for it would only move the failure to the first decode
     return "pallas" if jax.default_backend() == "tpu" else "jax"
 
 
@@ -80,6 +80,7 @@ class ShardedColumnarDecoder(ColumnarDecoder):
                     fn = self.build_jax_decode_fn(mesh=self.mesh)
                     self._jax_fn = DeviceProgram(
                         fn, interpreted=fn.interpret,
+                        device_groups=fn.device_groups,
                         in_shardings=sharding,
                         # every output's leading axis is the record axis;
                         # keep the results distributed — transfers gather
@@ -143,6 +144,7 @@ class ShardedColumnarDecoder(ColumnarDecoder):
             sharding = batch_sharding(self.mesh)
             self._stats_fn = DeviceProgram(
                 stats, interpreted=decode_all.interpret,
+                device_groups=decode_all.device_groups,
                 in_shardings=(sharding, None))
 
         if n is None:

@@ -249,7 +249,9 @@ class DeviceStats:
     bytes over the link each way, the seconds spent
     compiling (`compile_s`, of which `lower_s` tracing and lowering), the
     devices the outputs lived on, what kind of program ran (does it hold
-    the fused kernel; was that kernel interpreted), and the read's
+    the fused kernel; was that kernel interpreted; how many kernel groups
+    of the decoders it launched took which route: `device_groups`), and
+    the read's
     seconds and entries by stage (`stage_s`, `stage_n`: self time, each
     instant split among the threads inside stages; profiling.Stage).
     The record a caller needs to tell a read that used the chip from one
@@ -268,6 +270,9 @@ class DeviceStats:
         self.devices: set = set()
         self.has_kernel: Optional[bool] = None
         self.interpreted: Optional[bool] = None
+        # route counts of every decode program launched, by identity: a
+        # read launches one decoder's program many times
+        self._program_groups: Dict[int, Dict[str, int]] = {}
         # seconds and entries by stage (profiling.Stage)
         self.stage_s: Dict[str, float] = {}
         self.stage_n: Dict[str, int] = {}
@@ -299,11 +304,14 @@ class DeviceStats:
 
     def note_launch(self, shape: tuple, records: int, h2d_bytes: int,
                     d2h_bytes: int, devices, program, built,
-                    interpreted) -> None:
+                    interpreted, device_groups=None) -> None:
         """One program launch of `records` rows padded to `shape`.
         `program` is the ops.device.CompiledShape that ran, `built`
-        whether this launch had to compile it."""
+        whether this launch had to compile it, `device_groups` the route
+        counts of the DeviceProgram it belongs to."""
         with self._lock:
+            if device_groups is not None:
+                self._program_groups[id(device_groups)] = device_groups
             self.launches[shape] = self.launches.get(shape, 0) + 1
             self.records += records
             self.h2d_bytes += h2d_bytes
@@ -320,9 +328,21 @@ class DeviceStats:
             if interpreted is not None:
                 self.interpreted = bool(self.interpreted) or interpreted
 
+    @property
+    def device_groups(self) -> Dict[str, int]:
+        """Kernel groups by the route they took on the device (fused
+        Pallas kernel, static slices, XLA gather), summed over the
+        decoders whose programs this read launched."""
+        with self._lock:
+            counted = list(self._program_groups.values())
+        return {route: sum(groups.get(route, 0) for groups in counted)
+                for route in ("fused", "sliced", "gathered")}
+
     def as_dict(self) -> dict:
+        device_groups = self.device_groups
         with self._lock:
             return {
+                "device_groups": device_groups,
                 "launches": {f"{b}x{e}": n for (b, e), n
                              in sorted(self.launches.items())},
                 "records": self.records,
@@ -589,6 +609,7 @@ class ReadMetrics:
         if passes:
             out["native_passes"] = passes
         if self.device_stats.launches:
+            out["device_groups"] = self.device_stats.device_groups
             out["device"] = self.device_stats.as_dict()
         roof = self.roofline()
         if roof is not None:

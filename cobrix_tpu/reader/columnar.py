@@ -2,7 +2,7 @@
 
 The production decode path. A `FieldPlan` (plan/compiler.py) is bound to
 kernel launches: columns sharing (codec, width, kernel variant) are decoded
-together — one byte-slab gather + one vectorized kernel per group — instead
+together — one byte slab + one vectorized kernel per group — instead
 of the reference's per-record, per-field closure walk
 (RecordExtractors.scala:49).
 
@@ -299,7 +299,8 @@ def _resolve_occurs(st: Statement, dep_value) -> int:
 
 def _pallas_group_spec(g: _KernelGroup):
     """StridedGroup for the fused Pallas kernel, or None if the group stays
-    on the XLA path (strings via the LUT gather, floats, host fallback).
+    on the XLA route of build_jax_decode_fn (strings, floats) or on the
+    host (host fallback).
     Every numeric plane is fused: int32 lanes natively, 10-18-digit and
     wide (BigDecimal) fields via base-2^16 limb arithmetic in int32
     lanes; irregular offsets feed the kernel through XLA gathers."""
@@ -325,6 +326,49 @@ def _pallas_group_spec(g: _KernelGroup):
             allow_dot=allow_dot, require_digits=require_digits,
             dyn_sf=min(sf, 0))
     return None
+
+
+# a group on the XLA route reads its bytes by static slices, up to this
+# many (one per run of adjacent or evenly spaced columns; `width` for a run
+# with other bytes between its columns); past it the group keeps XLA's
+# gather, one operation whatever the columns, which a TPU runs element by
+# element. The slices always run faster; the limit bounds what they add to
+# lowering and compiling. exp1 with every group on this route
+# (backend="jax") needs at most 6 a group; for a described v5e a group of
+# 32 slices compiles as fast as its gather, one of 128 in 30 s against 4,
+# one of 256 in 66 s against 5 (PERF.md section 6, PR 27)
+SLICE_PIECES_MAX = 64
+
+
+def _slice_pieces(offsets, width: int) -> List[Tuple[int, int, int]]:
+    """A group's columns, in their order, as runs in arithmetic
+    progression: [(first offset, columns, stride)], stride >= width (a
+    lone column is dense: stride == width)."""
+    pieces: List[Tuple[int, int, int]] = []
+    for off in (int(o) for o in offsets):
+        if pieces:
+            start, count, stride = pieces[-1]
+            step = off - (start + (count - 1) * stride)
+            if step >= width and (count == 1 or step == stride):
+                pieces[-1] = (start, count + 1, step)
+                continue
+        pieces.append((off, 1, width))
+    return pieces
+
+
+def _dense(piece: Tuple[int, int, int], width: int) -> bool:
+    return piece[1] == 1 or piece[2] == width
+
+
+def _merged_spans(intervals) -> List[Tuple[int, int]]:
+    """Byte intervals [lo, hi) merged where they overlap or touch."""
+    spans: List[Tuple[int, int]] = []
+    for lo, hi in sorted(intervals):
+        if spans and lo <= spans[-1][1]:
+            spans[-1] = (spans[-1][0], max(spans[-1][1], hi))
+        else:
+            spans.append((lo, hi))
+    return spans
 
 
 class DecodedBatch:
@@ -973,7 +1017,7 @@ _decoder_build_lock = threading.Lock()
 
 # every decode backend there is: the native/numpy host kernels, the scalar
 # oracle (row-wise; the readers walk it, the columnar decoder never runs
-# under that name), the XLA gather program, the fused Pallas program
+# under that name), the plain XLA program, the fused Pallas program
 BACKENDS = ("numpy", "host", "jax", "pallas")
 DEVICE_BACKENDS = ("jax", "pallas")
 
@@ -1588,8 +1632,16 @@ class ColumnarDecoder:
         backend "pallas": numeric groups whose offsets form an arithmetic
         progression (OCCURS-array layouts) decode through the single fused
         Pallas kernel — one VMEM pass of each batch tile for the whole
-        numeric plane (ops/pallas_tpu.py); remaining groups use the XLA
-        gather path below. `mesh`: with a multi-device mesh the fused
+        numeric plane (ops/pallas_tpu.py). Every other group, and with
+        backend "jax" every group, takes the XLA route: its [n, columns,
+        width] bytes are static slices chosen from its offsets (one for a
+        run of adjacent columns, `width` strided ones for a run with other
+        bytes between; XLA's gather only past SLICE_PIECES_MAX slices),
+        and EBCDIC strings become code points in one element-wise lookup
+        a program (batch_jax.transcode_ebcdic) over the bytes their
+        fields cover, each byte once however many redefines read it.
+        `decode_all.device_groups` counts the groups by route. `mesh`:
+        with a multi-device mesh the fused
         pallas_call is wrapped in shard_map over the ``data`` axis (GSPMD
         cannot partition a custom call — an unwrapped kernel would force
         an all-gather of the whole batch onto every chip); the non-fused
@@ -1629,29 +1681,125 @@ class ColumnarDecoder:
                         out_specs=PartitionSpec("data"),
                         check_vma=False)
 
+        # the route of every group the kernel does not take, from what
+        # the plan knows: its columns as runs in arithmetic progression,
+        # each one static slice (None: too many runs, the gather stays)
+        pieces_of: Dict[int, Optional[list]] = {}
+        for gi, g in enumerate(kernel_groups):
+            if gi in fused_indices or g.codec is Codec.HOST_FALLBACK:
+                continue
+            pieces = _slice_pieces(g.offsets, g.width)
+            slices = sum(1 if _dense(piece, g.width) else g.width
+                         for piece in pieces)
+            pieces_of[gi] = pieces if slices <= SLICE_PIECES_MAX else None
+        device_groups = {
+            "fused": len(fused_indices),
+            "sliced": sum(p is not None for p in pieces_of.values()),
+            "gathered": sum(p is None for p in pieces_of.values())}
+        # EBCDIC bytes become code points in one lookup a program: first
+        # the maximal byte ranges that sliced string columns cover without
+        # a gap, each once however many fields (redefines) read it (the
+        # union is never more than their sum), then what single groups
+        # bring (runs of columns with other bytes between, gathered slabs)
+        spans = _merged_spans(
+            (piece[0], piece[0] + piece[1] * g.width)
+            for gi, g in enumerate(kernel_groups)
+            if g.codec is Codec.EBCDIC_STRING and pieces_of.get(gi)
+            for piece in pieces_of[gi] if _dense(piece, g.width))
+        span_at = np.cumsum([0] + [hi - lo for lo, hi in spans])
+
+        def span_position(offset: int) -> int:
+            k = next(k for k, (lo, hi) in enumerate(spans)
+                     if lo <= offset < hi)
+            return int(span_at[k]) + offset - spans[k][0]
+
+        def piece_bytes(data, piece, width):
+            """[n, columns, width] bytes of one run of columns, by one
+            static slice."""
+            start, count, stride = piece
+            n = data.shape[0]
+            if _dense(piece, width):
+                return jax.lax.slice_in_dim(
+                    data, start, start + count * width,
+                    axis=1).reshape(n, count, width)
+            # other bytes between the columns: byte j of every column is
+            # one strided slice (as pallas_tpu._byte_planes reads numerics)
+            last = start + (count - 1) * stride
+            return jnp.stack(
+                [jax.lax.slice_in_dim(data, start + j, last + j + 1,
+                                      stride=stride, axis=1)
+                 for j in range(width)], axis=2)
+
+        def group_bytes(data, g, pieces):
+            if pieces is None:
+                offs = jnp.asarray(g.offsets)
+                return data[:, offs[:, None] + jnp.arange(g.width)[None, :]]
+            parts = [piece_bytes(data, piece, g.width) for piece in pieces]
+            return (parts[0] if len(parts) == 1
+                    else jnp.concatenate(parts, axis=1))
+
+        def group_scope(g):
+            # named by the plan (codec and width), not by the order of
+            # fusion: it lands in the `op_name` of every operation of the
+            # group
+            return jax.named_scope(
+                "cobrix.group." + g.label.replace("/", "_"))
+
         def decode_all(data):
-            outs: List[tuple] = [None] * len(kernel_groups)
+            n = data.shape[0]
+            outs: List[tuple] = [
+                () if g.codec is Codec.HOST_FALLBACK else None
+                for g in kernel_groups]
             if fused is not None:
                 for gi, pair in zip(fused_indices, fused(data)):
                     outs[gi] = pair
-            for gi, g in enumerate(kernel_groups):
-                if outs[gi] is not None:
-                    continue
-                if g.codec is Codec.HOST_FALLBACK:
-                    outs[gi] = ()
-                    continue
-                # a scope named by the plan (codec and width), not by
-                # the order of fusion: it lands in the `op_name` of every
-                # operation of the group
-                with jax.named_scope(
-                        "cobrix.group." + g.label.replace("/", "_")):
-                    offs = jnp.asarray(g.offsets)
-                    slab = data[:, offs[:, None]
-                                + jnp.arange(g.width)[None, :]]
-                    outs[gi] = self._run_group_jax(g, slab, jnp,
-                                                   batch_jax, lut)
+            slabs = {}
+            # the lookup's input, [n, k] blocks side by side, and where
+            # each EBCDIC group reads its columns in it: (position, columns)
+            blocks = [jax.lax.slice_in_dim(data, lo, hi, axis=1)
+                      for lo, hi in spans]
+            cursor = int(span_at[-1])
+            reads: Dict[int, list] = {}
+            for gi, pieces in pieces_of.items():
+                g = kernel_groups[gi]
+                with group_scope(g):
+                    if g.codec is not Codec.EBCDIC_STRING:
+                        slabs[gi] = group_bytes(data, g, pieces)
+                        continue
+                    reads[gi] = []
+                    for piece in pieces or [None]:
+                        if piece is not None and _dense(piece, g.width):
+                            reads[gi].append(
+                                (span_position(piece[0]), piece[1]))
+                            continue
+                        block = (group_bytes(data, g, None) if piece is None
+                                 else piece_bytes(data, piece, g.width))
+                        count = block.shape[1]
+                        blocks.append(block.reshape(n, count * g.width))
+                        reads[gi].append((cursor, count))
+                        cursor += count * g.width
+            if blocks:
+                # the lookup's own scope: its operations are no one group's
+                with jax.named_scope("cobrix.lookup.ebcdic"):
+                    points = batch_jax.transcode_ebcdic(
+                        blocks[0] if len(blocks) == 1
+                        else jnp.concatenate(blocks, axis=1), lut)
+            for gi in pieces_of:
+                g = kernel_groups[gi]
+                with group_scope(g):
+                    if gi in reads:
+                        parts = [jax.lax.slice_in_dim(
+                            points, at, at + count * g.width,
+                            axis=1).reshape(n, count, g.width)
+                            for at, count in reads[gi]]
+                        slabs[gi] = (parts[0] if len(parts) == 1
+                                     else jnp.concatenate(parts, axis=1))
+                    outs[gi] = self._run_group_jax(g, slabs[gi], jnp,
+                                                   batch_jax)
             return outs
 
+        # which route each group took, known when the program is built
+        decode_all.device_groups = device_groups
         # whether the fused kernel goes through the Pallas interpreter;
         # None when the program holds no Pallas kernel at all
         decode_all.interpret = interpret
@@ -1668,7 +1816,8 @@ class ColumnarDecoder:
 
                     fn = self.build_jax_decode_fn()
                     self._jax_fn = DeviceProgram(
-                        fn, interpreted=fn.interpret)
+                        fn, interpreted=fn.interpret,
+                        device_groups=fn.device_groups)
         return self._jax_fn
 
     def _device_block(self, n: int, extent: int) -> int:
@@ -1714,7 +1863,8 @@ class ColumnarDecoder:
                         (block, extent), m, x.nbytes,
                         sum(leaf.nbytes for leaf in leaves),
                         {d for leaf in leaves for d in leaf.devices()},
-                        compiled, built, program.interpreted)
+                        compiled, built, program.interpreted,
+                        program.device_groups)
                 parts.append((host_outs, m))
         if len(parts) == 1:
             merged = parts[0][0]
@@ -1765,7 +1915,9 @@ class ColumnarDecoder:
                 self._store_numeric(g, outputs, values, valid)
         return outputs
 
-    def _run_group_jax(self, g: _KernelGroup, slab, jnp, batch_jax, lut):
+    def _run_group_jax(self, g: _KernelGroup, slab, jnp, batch_jax):
+        """One group's outputs from its [n, ncols, width] slab: bytes, or
+        for an EBCDIC string group the code points already looked up."""
         if g.codec is Codec.BINARY:
             signed, big_endian, fits32, wide = g.variant
             if wide:
@@ -1804,8 +1956,6 @@ class ColumnarDecoder:
         if g.codec is Codec.DOUBLE_IEEE:
             return batch_jax.decode_ieee_float(
                 slab, g.columns[0].params.big_endian, double=True)
-        if g.codec is Codec.EBCDIC_STRING:
-            return (batch_jax.transcode_ebcdic(slab, jnp.asarray(lut)),)
         if g.codec is Codec.ASCII_STRING and not self.non_standard_ascii_charset:
             return (batch_jax.mask_ascii(slab),)
         return (slab,)

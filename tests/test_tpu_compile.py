@@ -29,8 +29,13 @@ from cobrix_tpu.testing.generators import (EXP1_COPYBOOK, EXP2_COPYBOOK,
 pytestmark = pytest.mark.jax
 
 KERNEL = "tpu_custom_call"
+GATHER = " gather("
 EXP3_EXTENT = 16064
 HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+# temporaries of PR 26's programs (the parent of the change that took the
+# string groups off XLA's gathers), compiled here the same way
+PARENT_EXP2_TEMP_BYTES = 2_650_646_528
+PARENT_EXP3_TEMP_BYTES = 400_620_544
 
 
 @pytest.fixture(scope="module")
@@ -106,21 +111,35 @@ def test_exp3_decode_pallas(one_chip, mosaic, batch):
         assert batch == 8192
     compiled = compile_on(one_chip, fn, batch, EXP3_EXTENT)
     assert KERNEL in compiled.as_text()
+    # the eight string groups are static slices of one looked-up plane
+    assert GATHER not in compiled.as_text()
+    assert fn.device_groups == {"fused": 2, "sliced": 8, "gathered": 0}
+    if batch == 8192:
+        # the kernel's planes are all but 1 MB of it, as in the parent
+        # (the string gathers' temporaries were small at 8,192 rows); the
+        # code points of the covered 64 bytes are the 64 KB more
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < PARENT_EXP3_TEMP_BYTES + 2 * batch * 64
 
 
 def test_exp2_decode_pallas_full_block(one_chip, mosaic):
     """64 B records of strings, half a vreg's lanes, at the largest batch
     the decoder launches (2,097,152 rows when this was written: a 100 MiB
-    shard of an exp2 read is 1.6 million records)."""
+    shard of an exp2 read is 1.6 million records). No gather is left (the
+    parent's program held 456), and fewer temporaries than the parent's."""
     decoder = ColumnarDecoder(
         parse_copybook(EXP2_COPYBOOK,
                        segment_redefines=["STATIC_DETAILS", "CONTACTS"]),
         backend="pallas")
     assert decoder.plan.max_extent == 64
     batch = full_block(decoder)
-    compiled = compile_on(one_chip, decoder.build_jax_decode_fn(), batch, 64)
+    fn = decoder.build_jax_decode_fn()
+    assert fn.device_groups == {"fused": 1, "sliced": 8, "gathered": 0}
+    compiled = compile_on(one_chip, fn, batch, 64)
     assert KERNEL in compiled.as_text()
+    assert GATHER not in compiled.as_text()
     mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < PARENT_EXP2_TEMP_BYTES
     print(f"exp2 {batch}x64: {mem.argument_size_in_bytes} B in, "
           f"{mem.output_size_in_bytes} B out, "
           f"{mem.temp_size_in_bytes} B of temporaries")
@@ -132,6 +151,9 @@ def test_exp3_decode_xla_gather(one_chip):
     assert fn.interpret is None
     compiled = compile_on(one_chip, fn, 2048, EXP3_EXTENT)
     assert KERNEL not in compiled.as_text()
+    # the route keeps its old name; its groups are strided slices now
+    assert fn.device_groups == {"fused": 0, "sliced": 10, "gathered": 0}
+    assert GATHER not in compiled.as_text()
 
 
 @pytest.mark.parametrize("n_chips", [1, 4])
@@ -198,14 +220,24 @@ def test_kinds_matrix(one_chip, batch):
 def test_exp1_decode_pallas_whole_program(one_chip, mosaic):
     """Every kernel kind at irregular offsets, strings and floats beside
     them, at the batch a big exp1 read launches. The slow one (about a
-    minute and a half): exp1's kernel unrolls 59 groups."""
+    minute and a half): exp1's kernel unrolls 59 groups. No group on the
+    XLA route keeps a gather by the slice limit (two string groups and
+    two float groups, one or two adjacent columns each); the gathers that
+    are left feed the kernel its irregular numerics (`cobrix.planes`,
+    pallas_tpu._byte_planes) and carry no group's scope."""
     decoder = ColumnarDecoder(parse_copybook(EXP1_COPYBOOK),
                               backend="pallas")
     batch = full_block(decoder)
     assert batch == 65536
-    compiled = compile_on(one_chip, decoder.build_jax_decode_fn(), batch,
-                          decoder.plan.max_extent)
-    assert KERNEL in compiled.as_text()
+    fn = decoder.build_jax_decode_fn()
+    assert fn.device_groups == {"fused": 61, "sliced": 4, "gathered": 0}
+    compiled = compile_on(one_chip, fn, batch, decoder.plan.max_extent)
+    text = compiled.as_text()
+    assert KERNEL in text
+    for line in text.splitlines():
+        if GATHER in line:
+            assert "cobrix.group." not in line, line
+            assert "cobrix.lookup." not in line, line
 
 
 def test_device_framing_scan(one_chip):
