@@ -239,7 +239,7 @@ class ScanReport:
                          f" = {roof['fraction'] * 100:.1f}% of bandwidth")
             lines.append(line)
         else:
-            lines.append("roofline: uncalibrated (run bench.py or "
+            lines.append("roofline: uncalibrated (run "
                          "obs.roofline.measured_bandwidth())")
         costs = self.field_costs
         if costs:

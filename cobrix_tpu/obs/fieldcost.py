@@ -242,7 +242,7 @@ def current() -> Optional[FieldCostAccumulator]:
 
 def top_fields(table: Dict[str, dict], n: int = 5) -> List[dict]:
     """The N most expensive rows of an `as_dict()` table as a list of
-    {field, **costs} records (the shape bench.py embeds)."""
+    {field, **costs} records (the shape `ScanReport.top_fields` returns)."""
     out = []
     for name, row in table.items():  # as_dict() is busy-sorted already
         out.append({"field": name, **row})

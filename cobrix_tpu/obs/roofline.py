@@ -7,16 +7,16 @@ reported as a FRACTION of the measured bandwidth. This module measures
 that bandwidth once per machine with a numpy STREAM triad
 (``a = b + s * c`` over arrays far larger than cache; 24 bytes move
 per element under the STREAM counting convention) and caches the result
-on disk so every consumer — `ReadMetrics`, `bench.py`, the
-``cobrix_roofline_fraction`` Prometheus gauge, `ScanReport` — anchors
-against the same number.
+on disk so every consumer — `ReadMetrics`, the serve tier's
+`roofline_min` SLO and audit record, the ``cobrix_roofline_fraction``
+Prometheus gauge, `ScanReport` — anchors against the same number.
 
 Cache location: ``$COBRIX_ROOFLINE_CACHE`` when set, else
 ``~/.cache/cobrix_tpu/roofline.json`` (one JSON object; written with
 temp + atomic rename like io/blockcache.py). Reads NEVER trigger a
 calibration implicitly — `cached_bandwidth()` only reads; a scan on an
-uncalibrated machine simply reports no roofline. `bench.py` (and
-`explain(..., calibrate=True)`) call `measured_bandwidth()` which
+uncalibrated machine simply reports no roofline.
+`explain(..., calibrate=True)` calls `measured_bandwidth()`, which
 calibrates on a cold cache, paying the ~1s once.
 """
 from __future__ import annotations
@@ -84,8 +84,8 @@ def calibrate(size_mb: float = 128.0, repeats: int = 3) -> dict:
 def _read_cache() -> Optional[dict]:
     """The on-disk calibration, verified (io/integrity.py): a corrupted
     record would silently re-anchor every roofline fraction on this
-    machine to a wrong basis — benchgate comparisons, SLO roofline_min
-    objectives, the Prometheus gauge. A record failing its checksum is
+    machine to a wrong basis — SLO roofline_min objectives, audit
+    records, the Prometheus gauge. A record failing its checksum is
     quarantined (next to the cache file), counted on
     ``cobrix_cache_corruption_total{plane="roofline"}``, and treated as
     uncalibrated, so the next `measured_bandwidth()` rebuilds it."""
@@ -159,7 +159,7 @@ def cached_bandwidth() -> Optional[float]:
 def measured_bandwidth(force: bool = False,
                        size_mb: float = 128.0) -> float:
     """The calibrated bandwidth, calibrating (and caching) when the
-    cache is cold or `force` is set — the bench / explain entry point."""
+    cache is cold or `force` is set — the explain entry point."""
     global _memo
     if not force:
         bw = cached_bandwidth()

@@ -5,7 +5,7 @@ builds corpora *through the encoder* (cobrix_tpu.encode.BatchEncoder),
 so every generated file is also a round-trip witness: the bytes are
 produced by the same tables the readers decode with, and re-encoding
 the decoded rows must reproduce them exactly (tools/rtcheck.py gates
-that; tools/benchgate.py holds the bench corpus to it).
+that).
 
 Two profiles, both chunked so multi-GB corpora stream to disk without
 materializing:

@@ -5,8 +5,7 @@ interpret mode, the four-chip phase on four of conftest's virtual CPU
 devices); its main() is held to its contract — no TPU, or a phase that
 raises, means no `"ok": true` and a non-zero exit; and the launch code
 around it is pinned: unknown backends raise, the compile cache goes
-where JAX_COMPILATION_CACHE_DIR says or else under the checkout, and
-bench.py neither runs without a chip nor survives a failed device leg.
+where JAX_COMPILATION_CACHE_DIR says or else under the checkout.
 """
 import ast
 import json
@@ -193,25 +192,3 @@ def test_compile_cache_defaults_to_the_checkout():
     assert before is None
     assert helper == after == os.path.join(REPO, ".jax_cache")
 
-
-# ------------------------------------------------------------- bench.py
-
-def test_bench_refuses_to_run_without_a_chip(monkeypatch):
-    import bench
-
-    monkeypatch.delenv("BENCH_FORCE_CPU", raising=False)
-    with pytest.raises(SystemExit, match="measures a TPU"):
-        bench._init_device()
-    monkeypatch.setenv("BENCH_FORCE_CPU", "1")
-    assert bench._init_device()["platform"] == "cpu"
-
-
-def test_bench_device_leg_that_raises_fails_the_run(monkeypatch):
-    import bench
-
-    def broken(mb, platform):
-        raise RuntimeError("device leg broke")
-
-    monkeypatch.setattr(bench, "run_device_query", broken)
-    with pytest.raises(RuntimeError, match="device leg broke"):
-        bench._device_metrics(1.0, "cpu")

@@ -133,8 +133,8 @@ def test_aggregate_projects_to_selected_columns(copybook, dataset):
 
 
 def test_streamed_blocks_merge_to_single_shot(copybook, dataset):
-    """The bench's streaming loop: fixed-size padded blocks H2D, partial
-    aggregates merged host-side — must equal the one-shot aggregate."""
+    """chip_smoke.py's streaming loop: fixed-size padded blocks H2D,
+    partial aggregates merged host-side — must equal the one-shot aggregate."""
     data, _ = dataset
     agg = DeviceAggregator(copybook)
     one = agg.aggregate(data)

@@ -1,7 +1,8 @@
 """Smoke tests: the runnable example apps (examples/ — the equivalents of
 the reference's spark-cobol-app programs) must stay green. The device
-query example is TPU-targeted (minutes of XLA compile on CPU) and is
-exercised by the bench instead."""
+query example is TPU-targeted (minutes of XLA compile on CPU); the
+aggregate it shows is held by tests/test_device_query.py at a small
+size and by chip_smoke.py's aggregate phase on the chip."""
 import importlib.util
 import os
 import sys

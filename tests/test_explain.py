@@ -1,6 +1,5 @@
 """Scan explain, per-field cost attribution, roofline, and the perf
-observability satellites (benchgate, atomic trace export, traceview
---fields).
+observability satellites (atomic trace export, traceview --fields).
 
 The attribution guarantees under test:
 
@@ -281,7 +280,7 @@ class TestAttributionParity:
         doc = rep.as_dict()
         assert doc["top_fields"] == top
         assert "field costs" in rep.render()
-        # the serving trailer / bench.py read the same live table
+        # the serving trailer reads the same live table
         assert rep.data.metrics.as_dict()["field_costs"] == \
             rep.field_costs
 
@@ -395,32 +394,8 @@ class TestRoofline:
 
 
 # ---------------------------------------------------------------------------
-# satellites: benchgate, traceview --fields, atomic trace export
+# satellites: traceview --fields, atomic trace export
 # ---------------------------------------------------------------------------
-
-class TestBenchgate:
-    def test_smoke(self):
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools", "benchgate.py"),
-             "--smoke"], capture_output=True, text=True, timeout=60)
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-
-    def test_gate_against_real_history(self, tmp_path):
-        """A fresh doc far below the repo's own BENCH history must exit
-        nonzero; a generous one passes."""
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        import benchgate
-
-        hist = [benchgate.extract_metrics(benchgate.load_bench_doc(p))
-                for p in sorted(__import__("glob").glob(
-                    os.path.join(REPO, "BENCH_r*.json")))
-                if benchgate.load_bench_doc(p)]
-        assert hist, "repo should carry BENCH history"
-        some_key = next(k for h in hist for k in h)
-        fresh = {some_key: {"value": 0.001, "fraction": None}}
-        rows = benchgate.gate(fresh, hist, 0.25, 1)
-        assert any(r["verdict"] == "regression" for r in rows)
-
 
 class TestTraceviewFields:
     def test_fields_from_trace_and_metrics(self, exp1_file, tmp_path):

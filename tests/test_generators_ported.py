@@ -1,8 +1,9 @@
 """End-to-end reads of the ported reference data generators
 (examples-collection TestDataGen1/7/8/9/11/13a/13b/16/17 — the exp1/2/3
-profiles are covered by the bench and golden tests). Each test generates a
-dataset with the reference's record layout and reads it back through
-read_cobol, pinning row counts and representative decoded values."""
+profiles are covered by the benchmark's cells and the golden tests).
+Each test generates a dataset with the reference's record layout and
+reads it back through read_cobol, pinning row counts and representative
+decoded values."""
 import os
 import tempfile
 

@@ -3,7 +3,8 @@ import numpy as np
 import pytest
 
 from cobrix_tpu import native
-from cobrix_tpu.testing.generators import ebcdic_encode, generate_exp2
+from cobrix_tpu.testing.generators import (ebcdic_encode, generate_exp2,
+                                           generate_exp3)
 
 
 def _rdw_le(n: int) -> bytes:
@@ -34,6 +35,55 @@ def test_rdw_scan_matches_exp2_generator():
     offs, lens = native.rdw_scan(raw, big_endian=False)
     assert len(offs) == 500
     assert set(lens) <= {60, 64, 68}
+
+
+def _walk_rdw(raw: bytes, big_endian: bool, adjustment: int = 0):
+    """The RDW chain walked header by header, in plain Python: payload
+    offsets and lengths, the last length cut to the bytes present."""
+    offsets, lengths = [], []
+    pos = 0
+    while pos + 4 <= len(raw):
+        if big_endian:
+            n = raw[pos] << 8 | raw[pos + 1]
+        else:
+            n = raw[pos + 3] << 8 | raw[pos + 2]
+        n += adjustment
+        offsets.append(pos + 4)
+        lengths.append(min(n, len(raw) - (pos + 4)))
+        pos += 4 + n
+    return offsets, lengths
+
+
+# length stored +4 (the RDW counts itself): read with rdw_adjustment=-4
+_ADJUSTED = b"".join(_rdw_be(len(r) + 4) + r
+                     for r in (b"ABCD", b"EFGHIJ", b"XY"))
+# the tail record declares 8 bytes and holds 2
+_TRUNCATED = _rdw_le(4) + b"ABCD" + _rdw_le(8) + b"EF"
+
+
+@pytest.mark.parametrize("image, scan_kw, expected", [
+    (lambda: generate_exp2(400, seed=11), dict(big_endian=False), None),
+    (lambda: generate_exp2(400, seed=11, big_endian_rdw=True),
+     dict(big_endian=True), None),
+    (lambda: generate_exp3(40, seed=11), dict(big_endian=False), None),
+    (lambda: _ADJUSTED, dict(big_endian=True, rdw_adjustment=-4),
+     ([4, 12, 22], [4, 6, 2])),
+    (lambda: _TRUNCATED, dict(big_endian=False), ([4, 12], [4, 2])),
+    (lambda: b"", dict(big_endian=False), ([], [])),
+    (lambda: b"\x00\x00", dict(big_endian=False), ([], [])),
+], ids=["exp2-le", "exp2-be", "exp3-wide", "adjustment", "truncated-tail",
+        "empty", "two-bytes"])
+def test_rdw_scan_matches_header_walk(image, scan_kw, expected):
+    """The host scan that frames every cell against a header walk that
+    imports nothing of the product (or the literal, where one is short
+    enough to state)."""
+    raw = image()
+    walked = _walk_rdw(raw, scan_kw["big_endian"],
+                       scan_kw.get("rdw_adjustment", 0))
+    if expected is not None:
+        assert walked == expected
+    offs, lens = native.rdw_scan(raw, **scan_kw)
+    assert (offs.tolist(), lens.tolist()) == walked
 
 
 def test_rdw_zero_header_raises():

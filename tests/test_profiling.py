@@ -59,7 +59,7 @@ def test_read_metrics_multihost(tmp_path):
 
 def test_profile_trace_writes_artifact(tmp_path):
     """A jax.profiler trace wrapping a jax-backend decode produces an
-    artifact directory (the bench records one per run)."""
+    artifact directory."""
     data = generate_exp1(8, seed=6)
     p = tmp_path / "exp1.dat"
     p.write_bytes(data.tobytes())

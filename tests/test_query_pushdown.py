@@ -266,6 +266,40 @@ class TestVrlParity:
                                  pc.not_equal(full["COMPANY_ID"], ""))
             assert got.equals(_posthoc(full, mask))
 
+    @pytest.mark.parametrize("extra", EXECUTION_GRID[:2],
+                             ids=["sequential", "pipelined"])
+    def test_select_and_in_filter_leave_the_occurs_plane_out(
+            self, vrl_file, extra):
+        """`select` of three fields beside an `in` filter on the wide
+        copybook: the rows and the selected columns are the full
+        decode's filtered afterwards, and the 2000-slot OCCURS that
+        neither names comes back without a value."""
+        full = read_cobol(vrl_file, **VRL_OPTS, **extra).to_arrow()
+        ids = sorted(set(full["COMPANY_ID"].to_pylist()))[:3]
+        got = read_cobol(
+            vrl_file, select="SEGMENT-ID,COMPANY-ID,COMPANY-NAME",
+            filter="COMPANY_ID in (%s)" % ", ".join(
+                f"'{i}'" for i in ids),
+            **VRL_OPTS, **extra).to_arrow()
+        expect = _posthoc(full, pc.is_in(full["COMPANY_ID"],
+                                         value_set=pa.array(ids)))
+        assert 0 < got.num_rows == expect.num_rows < full.num_rows
+        for name in ("SEGMENT_ID", "COMPANY_ID"):
+            assert got[name].equals(expect[name])
+
+        def details(table, *path):
+            return pc.struct_field(
+                table["STATIC_DETAILS"].combine_chunks(), list(path))
+
+        def num1_slots(table):
+            return pc.struct_field(pc.list_flatten(
+                details(table, "STRATEGY", "STRATEGY_DETAIL")), "NUM1")
+
+        assert details(got, "COMPANY_NAME").equals(
+            details(expect, "COMPANY_NAME"))
+        assert 0 < len(num1_slots(got)) == num1_slots(got).null_count
+        assert num1_slots(expect).null_count == 0
+
     def test_segment_conjunct_drops_pre_decode(self, vrl_file):
         data = read_cobol(vrl_file, filter=segment_is("P"), **VRL_OPTS)
         pd = data.metrics.pushdown

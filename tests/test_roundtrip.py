@@ -173,6 +173,21 @@ def test_multiseg_redefines(tmp_path):
     assert out.to_ebcdic(framing="rdw") == data
 
 
+@pytest.mark.parametrize("write, options, framing", [
+    (corpus.write_fixed_corpus, corpus.fixed_read_options, "fixed"),
+    (corpus.write_multiseg_corpus, corpus.multiseg_read_options, "rdw"),
+], ids=["fixed", "multiseg"])
+def test_factory_corpus_reencodes_to_its_own_bytes(tmp_path, write,
+                                                   options, framing):
+    """What testing/corpus.py promises of every file it writes: the
+    decoded rows encode back to exactly the file's bytes."""
+    path = str(tmp_path / "corpus.dat")
+    write(path, 2000, seed=100)
+    with open(path, "rb") as f:
+        data = f.read()
+    assert read_cobol(path, **options()).to_ebcdic(framing=framing) == data
+
+
 def test_permissive_corrupt_record_roundtrip(tmp_path):
     """Encoder-aware damage + permissive policy: the damaged field
     decodes to None, and decode→encode→decode is stable (the re-encoded

@@ -239,17 +239,3 @@ def test_exp1_decode_pallas_whole_program(one_chip, mosaic):
             assert "cobrix.group." not in line, line
             assert "cobrix.lookup." not in line, line
 
-
-def test_device_framing_scan(one_chip):
-    """ops/device_framing's pointer-doubling RDW scan over a 32 MiB image
-    compiles and fits (it keeps several per-byte arrays live)."""
-    import jax
-
-    from cobrix_tpu.ops import device_framing
-
-    image = jax.ShapeDtypeStruct((32 * 1024 * 1024,), np.uint8,
-                                 sharding=one_chip)
-    compiled = device_framing._build_scan(False, 0).lower(image).compile()
-    mem = compiled.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes) < HBM_BYTES

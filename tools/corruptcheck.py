@@ -1,7 +1,7 @@
 """Corruption smoke check: a permissive read must survive a dirty file.
 
-Usage (bench.py-style — prints ONE JSON line on stdout, progress on
-stderr, exit code 0 only if every check holds):
+Usage (prints ONE JSON line on stdout, progress on stderr, exit
+code 0 only if every check holds):
 
     python tools/corruptcheck.py [--records N] [--seed S]
 
