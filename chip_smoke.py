@@ -248,7 +248,13 @@ def phase_read(name: str, device: dict, path: str, warm_path: str,
             h2d_bytes=stats["h2d_bytes"], d2h_bytes=stats["d2h_bytes"],
             launches=stats["launches"], devices=stats["devices"],
             has_kernel=stats["has_kernel"],
-            interpreted=stats["interpreted"])
+            interpreted=stats["interpreted"],
+            device_groups=stats["device_groups"],
+            # batches with segment row masks that launched by redefine,
+            # those the plan's widths kept whole, the rows by set
+            partitioned_batches=stats["partitioned_batches"],
+            declined_batches=stats["declined_batches"],
+            set_rows=stats["set_rows"])
         tables[backend] = table
 
     host = read_cobol(path, backend="numpy", **options).to_arrow()

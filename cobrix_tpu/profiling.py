@@ -253,7 +253,12 @@ class DeviceStats:
     of the decoders it launched took which route: `device_groups`), and
     the read's
     seconds and entries by stage (`stage_s`, `stage_n`: self time, each
-    instant split among the threads inside stages; profiling.Stage).
+    instant split among the threads inside stages; profiling.Stage), and
+    what became of the batches that came with segment row masks: how
+    many launched by redefine (`partitioned_batches`), how many the
+    plan's widths kept whole (`declined_batches`), and the rows launched
+    by set (`set_rows`: a redefine's name, "" for the rows under none;
+    columnar.ColumnarDecoder.decode_raw).
     The record a caller needs to tell a read that used the chip from one
     that only says so. Shared like PassCounters: scan threads reach it
     through the ObsContext."""
@@ -270,6 +275,11 @@ class DeviceStats:
         self.devices: set = set()
         self.has_kernel: Optional[bool] = None
         self.interpreted: Optional[bool] = None
+        # what the launches of kernel programs have said so far
+        self._kernel_held: Optional[bool] = None
+        self.partitioned_batches = 0
+        self.declined_batches = 0
+        self.set_rows: Dict[str, int] = {}
         # route counts of every decode program launched, by identity: a
         # read launches one decoder's program many times
         self._program_groups: Dict[int, Dict[str, int]] = {}
@@ -321,12 +331,29 @@ class DeviceStats:
                 self.compiles += 1
                 self.compile_s += program.compile_s
                 self.lower_s += program.lower_s
-            # every launch of the read must agree before the read may
-            # claim the kernel: one launch without it turns the flag off
-            self.has_kernel = (program.has_kernel if self.has_kernel is None
-                               else self.has_kernel and program.has_kernel)
+            # every launch of a program that was built round the Pallas
+            # kernel must hold it before the read may claim the kernel:
+            # one such launch without it turns the flag off for good. A
+            # program with nothing for the kernel to take (`interpreted`
+            # None: backend "jax", or a set of rows whose groups are all
+            # strings) has no say: without a kernel program the answer
+            # is no
             if interpreted is not None:
+                self._kernel_held = (program.has_kernel
+                                     and self._kernel_held is not False)
                 self.interpreted = bool(self.interpreted) or interpreted
+            self.has_kernel = bool(self._kernel_held)
+
+    def note_partition(self, set_rows: Optional[Dict[str, int]]) -> None:
+        """One batch that came with segment row masks: the rows of each
+        set it launched, or None where it stayed whole."""
+        with self._lock:
+            if set_rows is None:
+                self.declined_batches += 1
+                return
+            self.partitioned_batches += 1
+            for name, rows in set_rows.items():
+                self.set_rows[name] = self.set_rows.get(name, 0) + rows
 
     @property
     def device_groups(self) -> Dict[str, int]:
@@ -354,6 +381,9 @@ class DeviceStats:
                 "devices": sorted(self.devices),
                 "has_kernel": self.has_kernel,
                 "interpreted": self.interpreted,
+                "partitioned_batches": self.partitioned_batches,
+                "declined_batches": self.declined_batches,
+                "set_rows": dict(sorted(self.set_rows.items())),
                 "stage_s": {k: round(v, 6) for k, v
                             in sorted(self.stage_s.items())},
                 "stage_n": dict(sorted(self.stage_n.items())),

@@ -104,11 +104,32 @@ def _validity_buffer(valid: np.ndarray):
     return pa.py_buffer(np.packbits(valid, bitorder="little"))
 
 
+# columns a step of `_record_major`: a cache line of int32 values
+_TRANSPOSE_COLUMNS = 16
+
+
+def _record_major(plane: np.ndarray) -> np.ndarray:
+    """The [n, k] plane flat in record-major order: as it lies where its
+    rows are contiguous, one copy where they are not. A TPU hands its
+    planes back column-major, and numpy's copy of such a matrix walks
+    the destination's rows, taking one element from each of k far-apart
+    columns; a few columns at a time it reads whole cache lines down the
+    columns instead. On the chip's host, a [6500, 2000] plane: int32 154
+    to 99 ms, bool 95 to 25 ms (PERF.md section 6, PR 29)."""
+    if plane.ndim != 2 or abs(plane.strides[1]) <= abs(plane.strides[0]):
+        return np.ascontiguousarray(plane).reshape(-1)
+    out = np.empty(plane.shape, dtype=plane.dtype)
+    for s in range(0, plane.shape[1], _TRANSPOSE_COLUMNS):
+        out[:, s:s + _TRANSPOSE_COLUMNS] = plane[:, s:s + _TRANSPOSE_COLUMNS]
+    return out.reshape(-1)
+
+
 def _packed_validity(valid: np.ndarray):
-    """(Arrow validity bitmap, null count) of a contiguous bool plane:
-    no bitmap where nothing is null (the usual case, and `all` reads a
-    byte plane several times faster than packing it), else packed once."""
-    if valid.all():
+    """(Arrow validity bitmap, null count) of a contiguous bool plane
+    (None: nothing is null): no bitmap where nothing is null (the usual
+    case, and `all` reads a byte plane several times faster than packing
+    it), else packed once."""
+    if valid is None or valid.all():
         return None, 0
     packed = native.pack_validity(valid.view(np.uint8))
     if packed is None:  # no native library
@@ -120,13 +141,15 @@ def _packed_validity(valid: np.ndarray):
 
 def _decimal128_from_mantissa(mantissa: np.ndarray, valid: np.ndarray,
                               pa_type):
-    """decimal128 array with the int64 mantissa as the unscaled value."""
+    """decimal128 array with the int64 mantissa as the unscaled value
+    (`valid` None: nothing is null)."""
     pa = _pa()
     n = len(mantissa)
     le = np.zeros((n, 2), dtype="<i8")
     le[:, 0] = mantissa
     le[:, 1] = mantissa >> 63  # sign extension of the high limb
-    vbuf = None if valid.all() else _validity_buffer(valid)
+    vbuf = (None if valid is None or valid.all()
+            else _validity_buffer(valid))
     return pa.Array.from_buffers(pa_type, n, [vbuf, pa.py_buffer(le)])
 
 
@@ -1017,25 +1040,30 @@ class ArrowBatchBuilder:
                                           compact_rows)
         return arr
 
-    def _slot_plane(self, cols):
-        """(values matrix, valid matrix, column slice) when the slot
+    def _slot_plane(self, cols, compact_mask=None):
+        """(values matrix, valid matrix, column slice, whether the
+        matrices hold the rows of `compact_mask` alone) when the slot
         columns `cols` of one OCCURS leaf, in slot order, are evenly
         spaced columns of ONE decoded group matrix (`plane` of
         columnar._store_numeric: consecutive where the leaf has the
         group's columns to itself within an element, strided where
         sibling leaves of the same kernel group sit between its slots).
-        None where a slot has no such plane: wide limbs, host-fallback
-        values, strings."""
-        planes = [self.batch.column_arrays(c).get("plane") for c in cols]
+        A group that the device decoded for the rows of `compact_mask`
+        alone hands its subset matrices over unscattered
+        (DecodedBatch.plane_of). None where a slot has no such plane:
+        wide limbs, host-fallback values, strings."""
+        found = [self.batch.plane_of(c, compact_mask) for c in cols]
+        planes = [p for p, _ in found]
         if any(p is None for p in planes):
             return None
+        subset = found[0][1]
         values, valid, p0 = planes[0]
         step = planes[1][2] - p0 if len(planes) > 1 else 1
         stop = p0 + len(planes) * step
         if (step < 1 or any(p[0] is not values for p in planes)
                 or [p[2] for p in planes] != list(range(p0, stop, step))):
             return None
-        return values, valid, slice(p0, stop, step)
+        return values, valid, slice(p0, stop, step), subset
 
     def _plane_flat_values(self, cols, spec0, pa_type, relevant,
                            compact_rows):
@@ -1044,9 +1072,12 @@ class ArrowBatchBuilder:
         matrix [n, ncols] (every device-backend batch): the matrix's rows
         are the record-major order a list's values want, so the slots'
         columns are sliced out of it in one piece — no per-slot arrays,
-        no stack, no interleaving take. `compact_rows`: one ascending
-        row gather keeps only the visible rows (the caller gives hidden
-        rows empty lists). Otherwise positional, and `relevant` (rows
+        no stack, no interleaving take. `compact_rows` (the rows of
+        `relevant`): one ascending row gather keeps only the visible
+        rows (the caller gives hidden rows empty lists), and no gather
+        at all where the device decoded the group for those rows alone:
+        the subset matrix is the visible rows. Otherwise positional, and
+        `relevant` (rows
         that cannot be dropped) nulls the hidden rows' slots, as the
         fused native pass does. The matrix may have any strides: the
         host kernels' and XLA:CPU's are row-major, a TPU hands its
@@ -1061,19 +1092,24 @@ class ArrowBatchBuilder:
         shift = _static_decimal_shift(spec0, pa_type) if is_decimal else 0
         if shift is None:
             return None
-        plane = self._slot_plane(cols)
+        plane = self._slot_plane(
+            cols, relevant if compact_rows is not None else None)
         if plane is None:
             return None
-        values, valid, slots = plane
+        values, valid, slots, subset = plane
         values, valid = values[:, slots], valid[:, slots]
         if compact_rows is not None:
-            values, valid = values[compact_rows], valid[compact_rows]
+            # a subset matrix's rows are `compact_rows`, in their order
+            if not subset:
+                values, valid = values[compact_rows], valid[compact_rows]
         elif relevant is not None:
             valid = valid & relevant[:, None]
         # one copy into record-major order; none where every row stays
-        # and the leaf's slots are the whole of a row-major matrix
-        flat = np.ascontiguousarray(values).reshape(-1)
-        fvalid = np.ascontiguousarray(valid).reshape(-1)
+        # and the leaf's slots are the whole of a row-major matrix. The
+        # validity plane is copied only where something is null: `all`
+        # reads it in whatever order it lies
+        flat = _record_major(values)
+        fvalid = None if valid.all() else _record_major(valid)
         if self.batch.pass_counts is not None:
             self.batch.pass_counts.incr("plane_list")
         if is_decimal:

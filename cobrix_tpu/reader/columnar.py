@@ -176,11 +176,13 @@ def _scatter_rows(arr: np.ndarray, mask: np.ndarray, n: int) -> np.ndarray:
 def _scatter_outputs(outputs: Dict[int, dict], mask: np.ndarray,
                      n: int) -> Dict[int, dict]:
     """Scatter the columns' output dicts of one subset decode from subset
-    rows to full length. Only plain array planes reach here: string
-    codecs defer before the masked routing and HOST_FALLBACK groups are
-    excluded from it explicitly in decode_raw. A group matrix that
-    `_store_numeric` handed out column by column is scattered once, and
-    the columns stay views of it (their `plane` stays true)."""
+    rows to full length. Only array planes reach here (values, limbs,
+    dot scales, a device backend's code-point matrices): the host
+    kernels' string codecs defer before the masked routing, and
+    HOST_FALLBACK groups are excluded from it explicitly in decode_raw.
+    A group matrix that `_store_numeric` handed out column by column is
+    scattered once, and the columns stay views of it (their `plane`
+    stays true)."""
     whole: Dict[int, np.ndarray] = {}
 
     def matrix(m):
@@ -222,11 +224,15 @@ def _masks_equal(a, b) -> bool:
 
 class _KernelGroup:
     def __init__(self, codec: Codec, width: int, variant: tuple,
-                 columns: List[ColumnSpec], names: Tuple[str, ...]):
+                 columns: List[ColumnSpec], names: Tuple[str, ...],
+                 segment: Optional[str] = None):
         self.codec = codec
         self.width = width
         self.variant = variant
         self.columns = columns
+        # the segment redefine (upper case) whose rows alone read these
+        # columns; None: every row reads them (_column_owner)
+        self.segment = segment
         self.offsets = np.array([c.offset for c in columns], dtype=np.int64)
         # cost-attribution identity: plan-resolved field names (OCCURS
         # slots repeat a name and merge into one cost row; names reused
@@ -240,6 +246,18 @@ class _KernelGroup:
         """uint128-limb output layout (values_hi/values/negative planes)."""
         return (self.codec in _NUMERIC_CODECS and self.variant
                 and self.variant[-1] is True)
+
+
+def _column_owner(spec: ColumnSpec) -> Optional[str]:
+    """The segment redefine (upper case) whose rows alone read the
+    column; None for a column outside every redefine, and for a
+    DEPENDING ON dependee: the oracle's walk registers its counter on
+    EVERY row, from whatever bytes are there (other segments' overlays
+    included), so no row mask may ever hide it."""
+    if spec.segment is None or (spec.statement is not None
+                                and spec.statement.is_dependee):
+        return None
+    return spec.segment.upper()
 
 
 def fixed_point_exponent(spec: ColumnSpec) -> int:
@@ -371,6 +389,77 @@ def _merged_spans(intervals) -> List[Tuple[int, int]]:
     return spans
 
 
+def _groups_extent(groups) -> int:
+    """The furthest byte any column of `groups` reads (at least 1)."""
+    return max((int(g.offsets.max()) + g.width
+                for g in groups if len(g.columns)), default=1)
+
+
+def _fetched_bytes(g: _KernelGroup) -> int:
+    """Bytes a row that the device program hands back for `g`, from the
+    plan alone (the dtypes of `_run_group_jax`'s tuples)."""
+    if g.codec is Codec.HOST_FALLBACK:
+        return 0
+    if g.codec in _STRING_CODECS:
+        # EBCDIC comes back as uint16 code points
+        return len(g.columns) * g.width * (
+            2 if g.codec is Codec.EBCDIC_STRING else 1)
+    if g.wide:
+        cell = 8 + 8 + 1 + 1
+    elif g.codec in (Codec.DOUBLE_IBM, Codec.DOUBLE_IEEE):
+        cell = 8 + 1
+    elif g.codec in _FLOAT_CODECS:
+        cell = 4 + 1
+    else:
+        fits32 = g.variant[{Codec.BINARY: 2, Codec.BCD: 0}.get(g.codec, 3)]
+        cell = (4 if fits32 else 8) + 1
+    if g.codec in (Codec.DISPLAY_NUM, Codec.DISPLAY_NUM_ASCII):
+        cell += 4  # the dot_scale plane
+    return len(g.columns) * cell
+
+
+class _RowSet:
+    """The rows of a batch under one active segment redefine (`name`;
+    None: the rows under no mask), with the kernel groups they read:
+    the common ones and that redefine's."""
+
+    __slots__ = ("name", "mask", "rows", "groups", "extent")
+
+    def __init__(self, name, mask, rows, groups):
+        self.name = name
+        self.mask = mask          # the caller's own mask object
+        self.rows = rows          # ascending row indices
+        self.groups = groups      # in the order of `kernel_groups`
+        self.extent = _groups_extent(groups)
+
+    @property
+    def own(self):
+        """The redefine's own groups; the others are every set's."""
+        return [g for g in self.groups
+                if self.name is not None and g.segment == self.name]
+
+
+class _SubsetPlanes:
+    """One kernel group decoded for its redefine's own rows only:
+    `outputs` are its columns' output dicts over [rows of the set, ...]
+    arrays (None: the batch has no such row), `mask` says which rows of
+    the batch they are."""
+
+    __slots__ = ("mask", "group", "outputs")
+
+    def __init__(self, mask, group, outputs):
+        self.mask = mask
+        self.group = group
+        self.outputs = outputs
+
+    def columns(self, decoder: "ColumnarDecoder") -> Dict[int, dict]:
+        """The output dicts to scatter from: over no rows at all where
+        the batch has no row of the redefine."""
+        if self.outputs is None:
+            self.outputs = decoder.zero_row_outputs(self.group)
+        return self.outputs
+
+
 class DecodedBatch:
     """Decoded columns of one record batch."""
 
@@ -425,7 +514,39 @@ class DecodedBatch:
         elif "lazy_numeric" in out:
             self._materialize_numeric(out["lazy_numeric"][0])
             out = self._out[col]
+        elif len(out) == 1 and "subset" in out:
+            # a column decoded for its redefine's rows alone, asked for
+            # by position (rows, the hierarchical walk, scalars and
+            # strings of the Arrow path): this column's arrays go to
+            # their places, hidden rows zeros and invalid. Its group's
+            # matrices stay as they are for `plane_of` (a scalar that
+            # shares a matrix with an OCCURS leaf's slots must not
+            # scatter them all)
+            part = out["subset"]
+            out.update(
+                (key, _scatter_rows(np.asarray(arr), part.mask,
+                                    self.n_records))
+                for key, arr in part.columns(self.decoder)[col].items()
+                if key != "plane")
         return out
+
+    # -- subset planes (device launches partitioned by redefine) -----------
+
+    def plane_of(self, col: int, rows_mask=None):
+        """(`plane` of the column or None, whether it is a subset plane).
+        A column decoded for the rows of `rows_mask` alone (the mask
+        object decode_raw was given) hands its [rows of the set, ncols]
+        matrices over as they are: the caller wants exactly those rows.
+        Any other column answers by position: a subset group is
+        scattered to full length first, matrices and all, once."""
+        part = self._out[col].get("subset")
+        if part is None:
+            return self.column_arrays(col).get("plane"), False
+        if rows_mask is not None and part.mask is rows_mask:
+            return part.columns(self.decoder)[col].get("plane"), True
+        self._out.update(_scatter_outputs(
+            part.columns(self.decoder), part.mask, self.n_records))
+        return self._out[col].get("plane"), False
 
     # -- lazy numeric planes ----------------------------------------------
 
@@ -1026,6 +1147,20 @@ DEVICE_BACKENDS = ("jax", "pallas")
 # bounded whatever the shard size and a big read compiles one shape
 DEVICE_BLOCK_BYTES = 128 * 1024 * 1024
 
+# a device decode_raw with segment row masks launches by redefine where
+# that spares the link at least this many bytes a row, averaged over the
+# batch: (the plan's extent and fetched bytes, less the set's own) times
+# each set's share of the rows, all known before any launch. Against it
+# stand a fancy index of offsets and lengths a set, a launch more a batch
+# and the scatter of whatever is later asked for by position. The twin of
+# the host kernels' `hidden * g.width < 4.0` (_group_segment_mask). Set
+# between the two readings that bracket the break-even on the chip's host
+# (PERF.md section 6, PR 29): exp2's copybook spares 101 B a row and reads
+# 3 % slower partitioned (its strings are all asked for by position); the
+# same records with an OCCURS 32 of 8 B under 'C' spare 485 B and read
+# 12 % faster; exp3 spares 24 KB
+PARTITION_MIN_SAVED_BYTES = 256
+
 
 def validate_backend(backend: str) -> str:
     """`backend` if it names a decode backend, else ValueError. A name
@@ -1087,14 +1222,18 @@ class ColumnarDecoder:
         """(Re)build kernel groups and lookup maps from the plan columns —
         called at construction and after an offset remap (device byte
         projection rewrites column offsets into a packed layout)."""
+        # a group is one redefine's or nobody's, never a mix: a row mask
+        # of a segment then covers whole groups (the host kernels' subset
+        # decode, the device launches partitioned by redefine)
         groups: Dict[tuple, List[ColumnSpec]] = {}
         for c in self.plan.columns:
-            key = (c.codec, c.width) + _variant_key(c)
+            key = (c.codec, c.width, _variant_key(c), _column_owner(c))
             groups.setdefault(key, []).append(c)
         self.kernel_groups = [
-            _KernelGroup(key[0], key[1], key[2:], cols,
-                         tuple(self.plan.cost_name(c) for c in cols))
-            for key, cols in groups.items()]
+            _KernelGroup(codec, width, variant, cols,
+                         tuple(self.plan.cost_name(c) for c in cols),
+                         segment=owner)
+            for (codec, width, variant, owner), cols in groups.items()]
         # column index -> its kernel group (group-batched Arrow builds)
         self.group_of_col: Dict[int, _KernelGroup] = {
             c.index: g for g in self.kernel_groups for c in g.columns}
@@ -1119,6 +1258,8 @@ class ColumnarDecoder:
             if c.statement is not None and c.statement.is_dependee:
                 self.dependee_columns.setdefault(c.statement.name, c.index)
         self._jax_fn = None
+        # programs of group subsets (_program_for), by the groups' ids
+        self._set_programs: Dict[tuple, object] = {}
 
     # ------------------------------------------------------------------
 
@@ -1157,25 +1298,39 @@ class ColumnarDecoder:
                    start_offset: int = 0,
                    segment_row_masks: Optional[Dict[str, np.ndarray]] = None,
                    lazy_masked: bool = False) -> DecodedBatch:
-        """Decode framed records in place from the file image: numeric
-        groups read straight through the native raw kernels (no
-        [batch, extent] pack copy — for wide records the pack costs as
-        much as the decode), and only the narrow prefix covering the
-        remaining groups is packed. Falls back to pack + `decode` when the
-        native library or numpy backend is unavailable.
+        """Decode framed records in place from the file image.
 
-        `segment_row_masks`: segment-redefine name -> row-visibility mask.
-        A kernel group whose columns all belong to one masked segment
-        decodes ONLY that segment's rows (subset kernel + scatter);
-        hidden rows come back invalid instead of as decoded garbage.
-        On interleaved multisegment profiles (hierarchical) this skips
-        the majority of the numeric decode work.
+        On the numpy backend numeric groups read straight through the
+        native raw kernels (no [batch, extent] pack copy — for wide
+        records the pack costs as much as the decode), and only the
+        narrow prefix covering the remaining groups is packed. A device
+        backend packs every record to the plan's extent and decodes the
+        matrix (`decode`), unless the masks let it launch by redefine
+        (below); so does the numpy backend without the native library.
 
-        `lazy_masked`: defer even the masked numeric groups. The fused
-        native Arrow assembly applies the same row masks in-kernel
-        (hidden rows emit null without being decoded), so Arrow
-        consumers skip both the subset gather and the Python scatter —
-        the decode-once multisegment path uses this instead of
+        `segment_row_masks`: segment-redefine name -> row-visibility mask
+        (disjoint: a row has at most one active redefine). On the numpy
+        backend a kernel group of one masked redefine decodes ONLY that
+        segment's rows (subset kernel + scatter); hidden rows come back
+        invalid instead of as decoded garbage. On interleaved
+        multisegment profiles (hierarchical) this skips the majority of
+        the numeric decode work. On a device backend the rows are
+        partitioned by their active redefine (`_segment_sets`): each set
+        is packed at its own extent and launched through its own
+        program (the common groups and that redefine's), so only a
+        segment's own rows cross the link, at the segment's own width,
+        where the plan's widths make that worth a launch more a batch
+        (PARTITION_MIN_SAVED_BYTES); a redefine's outputs stay subset
+        planes, [rows of the set, ncols], until a consumer asks for
+        them by position (DecodedBatch.column_arrays scatters once a
+        group; the Arrow list builder takes the subset whole through
+        DecodedBatch.plane_of).
+
+        `lazy_masked`: defer even the masked numeric groups (numpy
+        backend). The fused native Arrow assembly applies the same row
+        masks in-kernel (hidden rows emit null without being decoded),
+        so Arrow consumers skip both the subset gather and the Python
+        scatter — the decode-once multisegment path uses this instead of
         splitting size-skewed profiles into per-segment decodes."""
         rec_lengths = np.asarray(rec_lengths, dtype=np.int64)
         extent_full = self.plan.max_extent
@@ -1188,6 +1343,12 @@ class ColumnarDecoder:
                                             start_offset=start_offset)
             return self.decode(batch, lengths=lengths)
 
+        if self.backend in DEVICE_BACKENDS and segment_row_masks:
+            partitioned = self._decode_raw_partitioned(
+                data, rec_offsets, rec_lengths, start_offset,
+                segment_row_masks, lengths)
+            if partitioned is not None:
+                return partitioned
         if self.backend != "numpy" or not native.available():
             return packed_fallback()
 
@@ -1295,24 +1456,12 @@ class ColumnarDecoder:
 
     @staticmethod
     def _group_segment_mask(g: "_KernelGroup", segment_row_masks):
-        """The shared row mask when EVERY column of `g` belongs to one
-        masked segment redefine; None keeps the full decode."""
-        if not segment_row_masks:
+        """The row mask of the segment redefine that owns `g`
+        (_column_owner: never a dependee's group); None keeps the full
+        decode."""
+        if not segment_row_masks or g.segment is None:
             return None
-        # dependee (DEPENDING ON counter) columns are read on EVERY row
-        # by the oracle's walk (registered from whatever bytes are there,
-        # including other segments' overlays) — they must never be masked
-        if any(c.statement is not None and c.statement.is_dependee
-               for c in g.columns):
-            return None
-        segs = {c.segment.upper() if c.segment else None
-                for c in g.columns}
-        if len(segs) != 1:
-            return None
-        (seg,) = segs
-        if seg is None:
-            return None
-        m = segment_row_masks.get(seg)
+        m = segment_row_masks.get(g.segment)
         if m is None:
             return None
         # engage only when the skipped decode outweighs the subset
@@ -1322,6 +1471,148 @@ class ColumnarDecoder:
         if hidden * g.width < 4.0:
             return None
         return m
+
+    def _segment_sets(self, segment_row_masks, n: int):
+        """The rows of a batch of `n` split by their active redefine for
+        the device: a _RowSet a masked redefine that owns kernel groups
+        (its rows may be none), one more for the rows under no mask.
+        None where the masks are no partition (two of them share a row),
+        where no group is any masked redefine's, or where the plan's
+        widths say the link would be spared too little
+        (PARTITION_MIN_SAVED_BYTES). A redefine without a mask keeps
+        its columns in every set: every row decodes them, as the host
+        kernels do."""
+        masks = {k.upper(): v for k, v in segment_row_masks.items()}
+        masked = [name for name in masks
+                  if any(g.segment == name for g in self.kernel_groups)]
+        if not masked or not n:
+            return None
+        rest = np.ones(n, dtype=bool)
+        sets, covered = [], 0
+        for name in masked:
+            rows = np.flatnonzero(masks[name])
+            rest[rows] = False
+            covered += len(rows)
+            sets.append(_RowSet(name, masks[name], rows, [
+                g for g in self.kernel_groups
+                if g.segment == name or g.segment not in masked]))
+        rest_rows = np.flatnonzero(rest)
+        if covered + len(rest_rows) != n:
+            return None
+        if len(rest_rows):
+            sets.append(_RowSet(None, rest, rest_rows, [
+                g for g in self.kernel_groups if g.segment not in masked]))
+
+        def link_bytes(extent, groups):
+            return extent + sum(_fetched_bytes(g) for g in groups)
+
+        whole = link_bytes(self.plan.max_extent, self.kernel_groups)
+        saved = sum(len(rs.rows) * (whole - link_bytes(rs.extent, rs.groups))
+                    for rs in sets) / n
+        return sets if saved >= PARTITION_MIN_SAVED_BYTES else None
+
+    def _decode_raw_partitioned(self, data, rec_offsets, rec_lengths,
+                                start_offset: int, segment_row_masks,
+                                lengths) -> Optional[DecodedBatch]:
+        """decode_raw on a device backend, the launches partitioned by
+        segment redefine (`_segment_sets`); None where it declines. Each
+        set's rows are packed once, straight into their launch's
+        bucket-sized buffers at the set's own extent, and decoded by the
+        set's own program. The common groups' outputs are put together
+        by position; a redefine's stay subset planes (_SubsetPlanes)."""
+        n = len(rec_offsets)
+        ctx = obs_context.current()
+        stats = ctx.device_stats if ctx is not None else None
+        sets = self._segment_sets(segment_row_masks, n)
+        if stats is not None and n:
+            stats.note_partition(
+                None if sets is None
+                else {rs.name or "": len(rs.rows) for rs in sets})
+        if sets is None:
+            return None
+        buf = (np.ascontiguousarray(data, dtype=np.uint8)
+               if isinstance(data, np.ndarray)
+               else np.frombuffer(data, dtype=np.uint8))
+        offs = np.ascontiguousarray(rec_offsets, dtype=np.int64)
+        lens = rec_lengths
+        if start_offset:
+            offs = offs + start_offset
+            lens = lens - start_offset
+
+        def packed_blocks(rs: _RowSet):
+            """The set's rows as [block, extent] launch buffers, zero
+            rows after the last real one."""
+            with Stage("pack"):
+                set_offs, set_lens = offs[rs.rows], lens[rs.rows]
+            block = self._device_block(len(rs.rows), rs.extent)
+            for start in range(0, len(rs.rows), block):
+                with Stage("pack"):
+                    o = set_offs[start:start + block]
+                    ln = set_lens[start:start + block]
+                    m = len(o)
+                    if m != block:
+                        # a record of no length packs as a row of zeros
+                        o, ln = (np.concatenate(
+                            [a, np.zeros(block - m, dtype=np.int64)])
+                            for a in (o, ln))
+                    rows = native.pack_records(buf, o, ln, rs.extent)
+                yield rows, m
+
+        fc = fieldcost.current()
+        tok = fc.begin() if fc is not None else None
+        launched = [rs for rs in sets if len(rs.rows)]
+        with annotate("cobrix_decode"):
+            parts = [self._launch_blocks(self._program_for(rs.groups),
+                                         packed_blocks(rs), stats)
+                     for rs in launched]
+        # set name -> {id(group): its fetched tuple}
+        fetched = {rs.name: dict(zip(map(id, rs.groups),
+                                     self._merge_blocks(p)))
+                   for rs, p in zip(launched, parts)}
+        outputs: Dict[int, dict] = {}
+        with Stage("collect"):
+            for rs in sets:
+                # what the host decodes row by row is no launch's
+                own = [g for g in rs.own
+                       if g.codec is not Codec.HOST_FALLBACK]
+                sub = None
+                if len(rs.rows):
+                    outs = fetched[rs.name]
+                    sub = self.collect_outputs(
+                        [outs[id(g)] for g in own], len(rs.rows), own)
+                for g in own:
+                    part = _SubsetPlanes(
+                        rs.mask, g, None if sub is None else
+                        {c.index: sub[c.index] for c in g.columns})
+                    for c in g.columns:
+                        outputs[c.index] = {"subset": part}
+            # every set decoded the groups no masked redefine owns: their
+            # rows go back to their places (one set with every row: as is)
+            common = [g for g in sets[0].groups if g not in sets[0].own]
+            whole = []
+            for g in common:
+                pieces = [(rs.rows, fetched[rs.name][id(g)])
+                          for rs in launched]
+                if len(pieces) == 1:
+                    whole.append(pieces[0][1])
+                    continue
+                arrays = []
+                for j, first in enumerate(pieces[0][1]):
+                    full = np.empty((n,) + first.shape[1:], first.dtype)
+                    for rows, outs in pieces:
+                        full[rows] = outs[j][:len(rows)]
+                    arrays.append(full)
+                whole.append(tuple(arrays))
+            outputs.update(self.collect_outputs(whole, n, common))
+        self._commit_device_cost(fc, tok, n)
+        # the packed matrix covers what the host decodes row by row
+        # (HOST_FALLBACK columns); everything else reads the file image
+        batch = native.pack_records(buf, offs, lens, _groups_extent(
+            [g for g in self.kernel_groups
+             if g.codec is Codec.HOST_FALLBACK]))
+        self._decode_host_fallback(batch, outputs)
+        return DecodedBatch(self, batch, outputs, lengths=lengths,
+                            raw_source=(buf, offs, lens))
 
     @staticmethod
     def _bucket_size(n: int) -> int:
@@ -1623,11 +1914,20 @@ class ColumnarDecoder:
 
     # -- jax backend ------------------------------------------------------
 
-    def build_jax_decode_fn(self, mesh=None):
+    def build_jax_decode_fn(self, mesh=None, groups=None):
         """The pure decode program: [batch, record_len] uint8 -> list of
         per-kernel-group output tuples. One XLA computation; suitable for
         `jax.jit` directly (single chip) or a sharded jit over a device mesh
         (parallel.ShardedColumnarDecoder).
+
+        `groups`: the kernel groups the program decodes, one output tuple
+        each in their order; default all of `kernel_groups`, at the
+        plan's extent. decode_raw's launches partitioned by segment
+        redefine build one program a set from the common groups and that
+        redefine's (`_program_for`): its input is as wide as the
+        furthest byte those groups read, the other redefines' columns
+        are not in it, and a group's tuple is what it is in the whole
+        program.
 
         backend "pallas": numeric groups whose offsets form an arithmetic
         progression (OCCURS-array layouts) decode through the single fused
@@ -1651,7 +1951,10 @@ class ColumnarDecoder:
         from ..ops import batch_jax
 
         batch_jax.ensure_x64()
-        kernel_groups = self.kernel_groups
+        kernel_groups = (self.kernel_groups if groups is None
+                         else list(groups))
+        extent = (self.plan.max_extent if groups is None
+                  else _groups_extent(kernel_groups))
         lut = self.lut
 
         fused = None
@@ -1667,8 +1970,7 @@ class ColumnarDecoder:
                     fused_indices.append(gi)
                     strided.append(sg)
             if strided:
-                fused = pallas_tpu.build_fused_decode(
-                    strided, self.plan.max_extent)
+                fused = pallas_tpu.build_fused_decode(strided, extent)
                 interpret = fused.interpret
                 if mesh is not None and mesh.devices.size > 1:
                     from jax.sharding import PartitionSpec
@@ -1820,6 +2122,26 @@ class ColumnarDecoder:
                         device_groups=fn.device_groups)
         return self._jax_fn
 
+    def _program_for(self, groups):
+        """The DeviceProgram that decodes `groups` and no other (a set
+        of launches partitioned by redefine), built once a decoder;
+        all of `kernel_groups` is `device_program()` itself."""
+        if len(groups) == len(self.kernel_groups):
+            return self.device_program()
+        key = tuple(id(g) for g in groups)
+        program = self._set_programs.get(key)
+        if program is None:
+            with _decoder_build_lock:
+                program = self._set_programs.get(key)
+                if program is None:
+                    from ..ops.device import DeviceProgram
+
+                    fn = self.build_jax_decode_fn(groups=groups)
+                    program = self._set_programs[key] = DeviceProgram(
+                        fn, interpreted=fn.interpret,
+                        device_groups=fn.device_groups)
+        return program
+
     def _device_block(self, n: int, extent: int) -> int:
         """Rows per device launch: the jit bucket for `n`, capped so one
         launch reads at most DEVICE_BLOCK_BYTES."""
@@ -1829,8 +2151,10 @@ class ColumnarDecoder:
         return min(self._bucket_size(n), cap)
 
     def _decode_jax(self, arr: np.ndarray) -> Dict[int, dict]:
-        import jax
-
+        """Every row of the packed [n, extent] matrix through the whole
+        program, in blocks of one bucket size (`_device_block`). What
+        decode_raw's row masks can spare the link never comes here: see
+        `_decode_raw_partitioned`."""
         program = self.device_program()
         n, extent = arr.shape
         block = self._device_block(n, extent)
@@ -1838,8 +2162,8 @@ class ColumnarDecoder:
         stats = ctx.device_stats if ctx is not None else None
         fc = fieldcost.current()
         tok = fc.begin() if fc is not None else None
-        parts = []
-        with annotate("cobrix_decode"):
+
+        def blocks():
             # an empty batch still launches once: the outputs' dtypes and
             # column counts come from the program
             for start in range(0, max(n, 1), block):
@@ -1850,50 +2174,78 @@ class ColumnarDecoder:
                         padded = np.zeros((block, extent), dtype=np.uint8)
                         padded[:m] = rows
                     rows = padded
-                with Stage("h2d"):
-                    x = jax.device_put(rows)
-                compiled, built = program.compiled_for(x)
-                with Stage("launch"):
-                    device_outs = compiled.executable(x)
-                with Stage("d2h_wait"):
-                    host_outs = jax.device_get(device_outs)
-                if stats is not None:
-                    leaves = jax.tree_util.tree_leaves(device_outs)
-                    stats.note_launch(
-                        (block, extent), m, x.nbytes,
-                        sum(leaf.nbytes for leaf in leaves),
-                        {d for leaf in leaves for d in leaf.devices()},
-                        compiled, built, program.interpreted,
-                        program.device_groups)
-                parts.append((host_outs, m))
-        if len(parts) == 1:
-            merged = parts[0][0]
-        else:
-            with Stage("merge"):
-                merged = [tuple(np.concatenate([outs[gi][k][:m]
-                                                for outs, m in parts])
-                                for k in range(len(group_outs)))
-                          for gi, group_outs in enumerate(parts[0][0])]
+                yield rows, m
+
+        with annotate("cobrix_decode"):
+            parts = self._launch_blocks(program, blocks(), stats)
+        merged = self._merge_blocks(parts)
         with Stage("collect"):
             outputs = self.collect_outputs(merged, n)
+        self._commit_device_cost(fc, tok, n)
+        return outputs
+
+    def _commit_device_cost(self, fc, tok, n: int) -> None:
+        """The device decode of `n` rows, charged: jitted programs decode
+        every group at once, so their wall (transfers included) is split
+        across the groups by bytes touched — coarser than the host
+        path's per-launch timing, but the same table."""
         if tok is not None:
-            # one jitted program decodes every group: split its wall
-            # (incl. transfers) across groups by bytes touched — coarser
-            # than the host path's per-launch timing, but the same table
             fc.commit_weighted(
                 tok,
                 [(g.names, g.width, n * g.width, g.label)
                  for g in self.kernel_groups
                  if g.codec is not Codec.HOST_FALLBACK and g.names],
                 fieldcost.PLANE_DECODE, n)
-        return outputs
 
-    def collect_outputs(self, device_outs, n: int) -> Dict[int, dict]:
+    @staticmethod
+    def _launch_blocks(program, blocks, stats) -> list:
+        """Each ([block, extent] uint8 buffer, its real rows) of `blocks`
+        over the link, through `program` and back: [(fetched outputs,
+        real rows)]."""
+        import jax
+
+        parts = []
+        for rows, m in blocks:
+            with Stage("h2d"):
+                x = jax.device_put(rows)
+            compiled, built = program.compiled_for(x)
+            with Stage("launch"):
+                device_outs = compiled.executable(x)
+            with Stage("d2h_wait"):
+                host_outs = jax.device_get(device_outs)
+            if stats is not None:
+                leaves = jax.tree_util.tree_leaves(device_outs)
+                stats.note_launch(
+                    rows.shape, m, x.nbytes,
+                    sum(leaf.nbytes for leaf in leaves),
+                    {d for leaf in leaves for d in leaf.devices()},
+                    compiled, built, program.interpreted,
+                    program.device_groups)
+            parts.append((host_outs, m))
+        return parts
+
+    @staticmethod
+    def _merge_blocks(parts) -> list:
+        """The blocks' outputs as one, padding dropped between them; a
+        lone block's are handed on as fetched (collect_outputs drops its
+        padding)."""
+        if len(parts) == 1:
+            return parts[0][0]
+        with Stage("merge"):
+            return [tuple(np.concatenate([outs[gi][k][:m]
+                                          for outs, m in parts])
+                          for k in range(len(group_outs)))
+                    for gi, group_outs in enumerate(parts[0][0])]
+
+    def collect_outputs(self, device_outs, n: int,
+                        groups=None) -> Dict[int, dict]:
         """Per-group program outputs (device arrays, or already fetched)
         as host numpy column arrays, dropping batch padding (`n` = real
-        record count)."""
+        record count). `groups`: the groups the program was built from
+        (build_jax_decode_fn), default all."""
         outputs: Dict[int, dict] = {}
-        for g, out in zip(self.kernel_groups, device_outs):
+        for g, out in zip(self.kernel_groups if groups is None else groups,
+                          device_outs):
             if g.codec is Codec.HOST_FALLBACK:
                 continue
             if g.codec in _STRING_CODECS:
@@ -1913,6 +2265,29 @@ class ColumnarDecoder:
                     # bitcasts on TPU round through the emulation path
                     values = values.view(np.float64)
                 self._store_numeric(g, outputs, values, valid)
+        return outputs
+
+    def zero_row_outputs(self, g: _KernelGroup) -> Dict[int, dict]:
+        """The output dicts of `g`'s columns over no rows at all, in the
+        planes `collect_outputs` would hand out: what a batch without a
+        single row of `g`'s redefine scatters from."""
+        outputs: Dict[int, dict] = {}
+        shape = (0, len(g.columns))
+        display = g.codec in (Codec.DISPLAY_NUM, Codec.DISPLAY_NUM_ASCII)
+        dots = np.zeros(shape, dtype=np.int32) if display else None
+        valid = np.zeros(shape, dtype=bool)
+        if g.codec in _STRING_CODECS:
+            chars = np.zeros(shape + (g.width,), dtype=(
+                np.uint16 if g.codec is Codec.EBCDIC_STRING else np.uint8))
+            for pos, c in enumerate(g.columns):
+                outputs[c.index] = {"bytes": chars[:, pos]}
+        elif g.wide:
+            limbs = np.zeros(shape, dtype=np.uint64)
+            self._store_wide(g, outputs, limbs, limbs, valid, valid, dots)
+        else:
+            self._store_numeric(g, outputs, np.zeros(shape, dtype=(
+                np.float64 if g.codec in _FLOAT_CODECS else np.int64)),
+                valid, dots)
         return outputs
 
     def _run_group_jax(self, g: _KernelGroup, slab, jnp, batch_jax):

@@ -52,7 +52,13 @@ def test_one_chip_phases_rehearsal(cpu_device, tmp_path, capsys):
     for line in reads.values():
         assert line["compiles_in_steady"] == 0 and line["d2h_bytes"] > 0
         assert line["devices"] == [str(jax.devices()[0])]
-    assert reads[("read_exp3", "pallas")]["interpreted"] is True
+    exp3 = reads[("read_exp3", "pallas")]
+    assert exp3["interpreted"] is True
+    # exp3 launches by redefine, two programs; exp1 brings no masks
+    assert exp3["partitioned_batches"] >= 1 and not exp3["declined_batches"]
+    assert sum(exp3["set_rows"].values()) == exp3["records"]
+    assert exp3["device_groups"] == {"fused": 2, "sliced": 10, "gathered": 0}
+    assert not reads[("read_exp1", "pallas")]["set_rows"]
     assert reads[("read_exp1", "jax")]["interpreted"] is None
     parity = [line for line in lines if line.get("parity") == "ok"]
     assert [line["phase"] for line in parity] == [

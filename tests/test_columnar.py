@@ -118,14 +118,22 @@ def test_jit_bucket_padding():
     assert len(batch.to_rows()) == 3
 
 
-def test_decode_raw_segment_masks_match_full_decode():
+@pytest.mark.jax
+@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+def test_decode_raw_segment_masks_match_full_decode(backend, monkeypatch):
     """decode_raw(segment_row_masks=...) decodes each masked group only on
     its own rows; visible rows must match the unmasked decode exactly and
-    hidden rows must come back invalid (None), not as decoded garbage."""
+    hidden rows must come back invalid (None), not as decoded garbage.
+    The host kernels subset-decode and scatter at once; a device backend
+    launches by redefine and scatters a group when it is asked for by
+    position (these widths spare the link too little to engage unasked)."""
     import numpy as np
 
     from cobrix_tpu import parse_copybook
+    from cobrix_tpu.reader import columnar
     from cobrix_tpu.reader.columnar import ColumnarDecoder
+
+    monkeypatch.setattr(columnar, "PARTITION_MIN_SAVED_BYTES", 0)
 
     cb = parse_copybook("""
        01 R.
@@ -158,16 +166,27 @@ def test_decode_raw_segment_masks_match_full_decode():
 
     dec = ColumnarDecoder(cb)
     full = dec.decode_raw(data, offsets, lengths)
-    dec2 = ColumnarDecoder(cb)
+    dec2 = ColumnarDecoder(cb, backend=backend)
     masked = dec2.decode_raw(data, offsets, lengths,
                              segment_row_masks=masks)
     upper_masks = {k.upper(): v for k, v in masks.items()}
     from cobrix_tpu.reader.columnar import _STRING_CODECS
-    engaged = {c.index
-               for g in dec2.kernel_groups
-               if g.codec not in _STRING_CODECS
-               and dec2._group_segment_mask(g, upper_masks) is not None
-               for c in g.columns}
+    if backend == "numpy":
+        engaged = {c.index
+                   for g in dec2.kernel_groups
+                   if g.codec not in _STRING_CODECS
+                   and dec2._group_segment_mask(g, upper_masks) is not None
+                   for c in g.columns}
+    else:
+        # every group of a redefine came back for its own rows alone
+        subsets = {c.index for c in dec2.plan.columns
+                   if "subset" in masked._out[c.index]}
+        assert subsets == {c.index for c in dec2.plan.columns if c.segment}
+        for index in subsets:
+            part = masked._out[index]["subset"]
+            assert part.mask is masks[part.group.segment]
+        engaged = {c.index for c in dec2.plan.columns
+                   if c.segment and c.codec not in _STRING_CODECS}
     assert engaged, "heuristic should engage at least one group"
     for c in dec.plan.columns:
         seg = (c.segment or "").upper()

@@ -256,13 +256,32 @@ def test_device_lists_equal_the_host_kernels(case, backend, tmp_path,
 
 
 @pytest.mark.parametrize("backend", ["pallas", "jax"])
-def test_exp3_lists_hold_only_the_visible_rows(backend, tmp_path):
+@pytest.mark.parametrize("planes", ["subset", "whole"])
+def test_exp3_lists_hold_only_the_visible_rows(planes, backend, tmp_path,
+                                               monkeypatch):
     """Two thirds of exp3's rows are 64 B 'P' records under a null
-    struct: their 2,000 slots are not built."""
+    struct: their 2,000 slots are not built. `subset`: the device
+    decoded the 'C' rows alone and the list's values are its matrix,
+    with no row gathered; `whole`: every row was decoded (as before the
+    launches went by redefine) and the visible ones are gathered."""
+    if planes == "whole":
+        monkeypatch.setattr(columnar, "PARTITION_MIN_SAVED_BYTES", 1 << 30)
+    taken = []
+    plane_of = columnar.DecodedBatch.plane_of
+
+    def watched(self, col, rows_mask=None):
+        plane, subset = plane_of(self, col, rows_mask)
+        taken.append((subset, None if plane is None else plane[0].shape[0]))
+        return plane, subset
+
+    monkeypatch.setattr(columnar.DecodedBatch, "plane_of", watched)
     data, options = exp3(np.random.default_rng(7))
     path = tmp_path / "exp3.bin"
     path.write_bytes(data)
-    table = read_cobol(str(path), backend=backend, **options).to_arrow()
+    read = read_cobol(str(path), backend=backend, **options)
+    table = read.to_arrow()
+    assert read.metrics.as_dict()["device"]["partitioned_batches"] == (
+        planes == "subset")
     (_, strategy), = table_lists(table)
     details = (table.column("COMPANY_DETAILS").combine_chunks()
                .field("STATIC_DETAILS"))
@@ -276,6 +295,38 @@ def test_exp3_lists_hold_only_the_visible_rows(backend, tmp_path):
     hidden = np.flatnonzero(~np.asarray(details.is_valid()))
     lengths = np.diff(np.asarray(strategy.offsets))
     assert not lengths[hidden].any()
+    # all 4,000 slot columns came from matrices of the visible rows
+    # alone, or of every row
+    assert len(taken) == 4000
+    assert set(taken) == {(True, visible) if planes == "subset"
+                          else (False, table.num_rows)}
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64, bool])
+@pytest.mark.parametrize("layout", [
+    "row_major", "row_major_columns_sliced", "column_major",
+    "column_major_rows_sliced", "column_major_columns_strided"])
+def test_record_major_of_any_layout(layout, dtype):
+    """A plane flat in record-major order whatever its strides: a TPU's
+    column-major matrix (a block's real rows, an OCCURS leaf's every
+    second column) as well as the host kernels' row-major one."""
+    from cobrix_tpu.reader.arrow_out import _record_major
+
+    base = (np.random.default_rng(4).integers(0, 1 << 30, size=(70, 37))
+            .astype(dtype))
+    plane = {
+        "row_major": lambda: base,
+        "row_major_columns_sliced": lambda: base[:, 1:],
+        "column_major": lambda: np.asfortranarray(base),
+        "column_major_rows_sliced": lambda: np.asfortranarray(base)[:51],
+        "column_major_columns_strided":
+            lambda: np.asfortranarray(base)[:, 1::2],
+    }[layout]()
+    flat = _record_major(plane)
+    assert flat.flags.c_contiguous and flat.dtype == plane.dtype
+    np.testing.assert_array_equal(flat, np.array(plane).reshape(-1))
+    if layout == "row_major":
+        assert np.shares_memory(flat, base)       # no copy at all
 
 
 def test_a_scattered_group_keeps_its_plane():

@@ -196,17 +196,21 @@ def test_concurrent_add_stage_loses_no_update():
     assert stats.stage_n == {"h2d": 32000}
 
 
+# enough 'C' records (a third of them) to fill more than one block of 256
+RECORDS = 1000
+
+
 @pytest.fixture(scope="module")
 def exp3_file(tmp_path_factory):
     path = tmp_path_factory.mktemp("exp3") / "exp3.bin"
-    path.write_bytes(bytes(generate_exp3(600, seed=24)))
+    path.write_bytes(bytes(generate_exp3(RECORDS, seed=24)))
     return str(path)
 
 
 @pytest.fixture
 def small_blocks(monkeypatch):
-    """Blocks of 256 rows, so that a few hundred records launch several
-    and their outputs have to be merged."""
+    """Blocks of 256 wide rows, so that a few hundred 'C' records launch
+    several and their outputs have to be merged."""
     monkeypatch.setattr(columnar, "DEVICE_BLOCK_BYTES", 1 << 20)
 
 
@@ -239,6 +243,12 @@ def test_exp3_read_counts_every_stage(exp3_file, small_blocks):
     assert again["device"]["stage_n"]["assemble.list"] == 2
     assert again["native_passes"]["plane_list"] == 4
     assert "assemble.list.slots" not in again["device"]["stage_s"]
+    # the one batch launched by redefine, and says which rows went where
+    assert device["partitioned_batches"] == 1
+    assert device["declined_batches"] == 0
+    assert set(device["set_rows"]) == {"STATIC_DETAILS", "CONTACTS"}
+    assert sum(device["set_rows"].values()) == device["records"] == RECORDS
+    assert device["set_rows"]["STATIC_DETAILS"] > 256
 
 
 def test_a_sharded_read_splits_the_wall_among_its_threads(exp3_file):
@@ -247,11 +257,11 @@ def test_a_sharded_read_splits_the_wall_among_its_threads(exp3_file):
     takes none, so the stages still add up to at most the wall."""
     t0 = time.perf_counter()
     data = read_cobol(exp3_file, backend="pallas", parallelism="4",
-                      input_split_records="150", **EXP3_OPTIONS)
+                      input_split_records=str(RECORDS // 4), **EXP3_OPTIONS)
     table = data.to_arrow()
     wall_s = time.perf_counter() - t0
     metrics = data.metrics.as_dict()
-    assert table.num_rows == 600 and metrics["shards"] == 4
+    assert table.num_rows == RECORDS and metrics["shards"] == 4
     device = metrics["device"]
     check_stage_record(device, wall_s, EXP3_STAGES - {"merge"})
     assert device["stage_n"]["decode"] == 4
@@ -278,6 +288,10 @@ def test_a_list_of_strings_is_built_slot_by_slot(tmp_path):
     assert device["stage_n"]["assemble.list.slots"] == 1
     assert device["stage_n"]["assemble.list"] == 1
     assert "plane_list" not in metrics["native_passes"]
+    # a fixed-length read brings no row masks: nothing to partition,
+    # nothing declined
+    assert (device["partitioned_batches"], device["declined_batches"],
+            device["set_rows"]) == (0, 0, {})
 
 
 def test_a_pipelined_read_counts_on_its_stage_threads(exp3_file):
@@ -414,7 +428,7 @@ def test_the_serve_trailer_carries_busy_seconds_and_stage_counters(exp3_file):
                 metrics = stream.summary["metrics"]
         finally:
             server.stop()
-    assert table.num_rows == 600
+    assert table.num_rows == RECORDS
     busy = metrics["stage_busy_s"]
     assert {"read", "frame", "decode", "assemble"} <= set(busy)
     stage_s = metrics["device"]["stage_s"]

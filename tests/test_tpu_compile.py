@@ -122,6 +122,37 @@ def test_exp3_decode_pallas(one_chip, mosaic, batch):
         assert temp < PARENT_EXP3_TEMP_BYTES + 2 * batch * 64
 
 
+@pytest.mark.parametrize("redefine, batch, extent, groups", [
+    ("STATIC_DETAILS", 8192, EXP3_EXTENT,
+     {"fused": 2, "sliced": 6, "gathered": 0}),
+    ("CONTACTS", 16384, 60, {"fused": 0, "sliced": 4, "gathered": 0}),
+])
+def test_exp3_set_programs(one_chip, mosaic, redefine, batch, extent,
+                           groups):
+    """The two programs an exp3 read launches by redefine, at the shapes
+    a 100 MiB shard gives them (about 6.5 k 'C' rows and 13 k 'P' rows):
+    the 'C' rows' keeps the kernel and reads 16,064 B rows, the 'P'
+    rows' is four string groups over 60 B rows and holds no kernel."""
+    decoder = ColumnarDecoder(exp3_copybook(), backend="pallas")
+    n = 19_500
+    company = np.arange(n) % 3 == 0
+    sets = {rs.name: rs for rs in decoder._segment_sets(
+        {"STATIC_DETAILS": company, "CONTACTS": ~company}, n)}
+    rs = sets[redefine]
+    assert rs.extent == extent
+    assert decoder._device_block(len(rs.rows), rs.extent) == batch
+    fn = decoder.build_jax_decode_fn(groups=rs.groups)
+    assert fn.device_groups == groups
+    assert fn.interpret is (False if groups["fused"] else None)
+    compiled = compile_on(one_chip, fn, batch, extent)
+    assert (KERNEL in compiled.as_text()) == bool(groups["fused"])
+    assert GATHER not in compiled.as_text()
+    if redefine == "STATIC_DETAILS":
+        # the whole program's kernel planes, and no more
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < PARENT_EXP3_TEMP_BYTES + 2 * batch * 64
+
+
 def test_exp2_decode_pallas_full_block(one_chip, mosaic):
     """64 B records of strings, half a vreg's lanes, at the largest batch
     the decoder launches (2,097,152 rows when this was written: a 100 MiB
