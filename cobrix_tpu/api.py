@@ -1518,6 +1518,23 @@ def _read_fixed_len_chunked(reader, file_path: str, params, backend: str,
                     stage_times=stage_times), len(data)))
             done += len(data)
         return results
+    return [track(reader.read_result(
+        data, backend=backend, file_id=file_order,
+        first_record_id=base_record_id + done // rs,
+        input_file_name=file_path, ignore_file_size=ignore_file_size,
+        stage_times=stage_times), len(data))
+        for done, data in _fixed_len_chunks(
+            file_path, size, rs, chunk_bytes, retry, on_retry, io,
+            stage_times)]
+
+
+def _fixed_len_chunks(file_path: str, size: int, rs: int, chunk_bytes: int,
+                      retry=None, on_retry=None, io=None, stage_times=None):
+    """(offset, bytes) of each read chunk of a fixed-length file, one
+    stream, `chunk_bytes` of whole records at a time: what a read decodes
+    and a device aggregate reduces, chunk after chunk."""
+    from .reader.stream import open_stream
+
     done = 0
     with open_stream(file_path, retry=retry, on_retry=on_retry,
                      io=io) as stream:
@@ -1528,14 +1545,75 @@ def _read_fixed_len_chunked(reader, file_path: str, params, backend: str,
                 break
             if len(data) % rs and done + len(data) < size:
                 raise IOError(f"Short read from {file_path} at {done}")
-            results.append(track(reader.read_result(
-                data, backend=backend, file_id=file_order,
-                first_record_id=base_record_id + done // rs,
-                input_file_name=file_path,
-                ignore_file_size=ignore_file_size,
-                stage_times=stage_times), len(data)))
+            yield done, data
             done += len(data)
-    return results
+
+
+def aggregate_on_device(files, copybook_contents, options: dict,
+                        backend: str, specs, filter_expr, keys, schema):
+    """``dataset(...).aggregate()`` answered on the chip: (result, the
+    call's ReadMetrics), or parallel.query.NotOnDevice before a byte is
+    read where the chip cannot answer exactly (the caller then decodes).
+
+    The route is taken from what can be seen: plain fixed-length records
+    (no variable-length reader, segments, offsets, permissive policy,
+    statistics, pipeline or worker hosts), and a query `bind_query`
+    accepts. Each file is read in the fixed-length reader's own chunks
+    (`_fixed_len_chunks`); a chunk's records are packed to the query's
+    bytes, launched, and its groups' partials merged on the host
+    (parallel/query.QueryRun), under the stages and DeviceStats counts of
+    a read plus `query.bind`, `query.merge`, `query.fallback` and the
+    `query_*` counts."""
+    from .io.compress import compressed_chunkable
+    from .obs.context import activate as obs_activate
+    from .parallel.query import NotOnDevice, aggregator_for, bind_query
+    from .profiling import Stage
+    from .reader.stream import source_size
+
+    params, opts = parse_options(dict(options))
+    if (params.needs_var_len_reader or params.multisegment is not None
+            or params.is_permissive or params.use_stats
+            or params.select or params.filter
+            or params.start_offset or params.end_offset
+            or params.file_start_offset or params.file_end_offset
+            or opts.get_int("hosts", 0) > 1
+            or opts.get_bool("debug_ignore_file_size")
+            or params.resolved_pipeline_workers() > 0):
+        raise NotOnDevice("not a plain fixed-length read")
+    metrics = ReadMetrics(files=len(files), backend=backend)
+    io_cfg = _io_config(params)
+    if not all(compressed_chunkable(path, io_cfg) for path in files):
+        raise NotOnDevice("a compressed input without a block cache")
+    retry = _retry_policy(params)
+    stats = metrics.device_stats
+    obs_ctx = _build_obs_context(params, metrics, None)
+    with obs_activate(obs_ctx):
+        with Stage("query.bind", stats):
+            reader = FixedLenReader(copybook_contents, params)
+            if reader.record_size != reader.copybook.record_size:
+                raise NotOnDevice("records wider than the copybook")
+            query = bind_query(reader.copybook, specs, filter_expr, keys,
+                               schema)
+            aggregator = aggregator_for(query, backend)
+        rs = reader.record_size
+        chunk_bytes = max(rs, (FIXED_READ_CHUNK_BYTES // rs) * rs)
+        run = aggregator.start(stats)
+        with stage(metrics, "scan"):
+            for file_path in files:
+                size = source_size(file_path, retry=retry, io=io_cfg)
+                reader.check_binary_data_validity(size)
+                metrics.bytes_read += size
+                for _done, data in _fixed_len_chunks(
+                        file_path, size, rs, chunk_bytes, retry, None,
+                        io_cfg):
+                    with Stage("frame"):
+                        matrix = reader.to_record_matrix(data)
+                    run.add(matrix)
+                # the chunk in flight views the stream's bytes
+                run.drain()
+            result = run.finish()
+    metrics.records = stats.query_rows_scanned
+    return result, metrics
 
 
 def _read_cobol_multihost(files, copybook_contents, params, hosts: int,

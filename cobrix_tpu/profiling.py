@@ -258,7 +258,10 @@ class DeviceStats:
     many launched by redefine (`partitioned_batches`), how many the
     plan's widths kept whole (`declined_batches`), and the rows launched
     by set (`set_rows`: a redefine's name, "" for the rows under none;
-    columnar.ColumnarDecoder.decode_raw).
+    columnar.ColumnarDecoder.decode_raw), and for a device aggregate
+    (`query_*`, present only then): its chunks, those of them the host
+    answered, the rows scanned and passed by the predicate, the groups
+    of the result.
     The record a caller needs to tell a read that used the chip from one
     that only says so. Shared like PassCounters: scan threads reach it
     through the ObsContext."""
@@ -280,6 +283,12 @@ class DeviceStats:
         self.partitioned_batches = 0
         self.declined_batches = 0
         self.set_rows: Dict[str, int] = {}
+        # a device aggregate's chunks (parallel/query.DeviceAggregator)
+        self.query_chunks = 0
+        self.query_fallback_chunks = 0
+        self.query_rows_scanned = 0
+        self.query_rows_passed = 0
+        self.query_groups = 0
         # route counts of every decode program launched, by identity: a
         # read launches one decoder's program many times
         self._program_groups: Dict[int, Dict[str, int]] = {}
@@ -355,6 +364,17 @@ class DeviceStats:
             for name, rows in set_rows.items():
                 self.set_rows[name] = self.set_rows.get(name, 0) + rows
 
+    def note_query_chunk(self, rows: int, passed: int,
+                         fallback: bool) -> None:
+        """One chunk of a device aggregate: `rows` scanned, `passed` by
+        the predicate; `fallback`: the host answered it, because the
+        device could not prove its partials exact."""
+        with self._lock:
+            self.query_chunks += 1
+            self.query_fallback_chunks += bool(fallback)
+            self.query_rows_scanned += rows
+            self.query_rows_passed += passed
+
     @property
     def device_groups(self) -> Dict[str, int]:
         """Kernel groups by the route they took on the device (fused
@@ -368,7 +388,14 @@ class DeviceStats:
     def as_dict(self) -> dict:
         device_groups = self.device_groups
         with self._lock:
+            query = {} if not self.query_chunks else {
+                "query_chunks": self.query_chunks,
+                "query_fallback_chunks": self.query_fallback_chunks,
+                "query_rows_scanned": self.query_rows_scanned,
+                "query_rows_passed": self.query_rows_passed,
+                "query_groups": self.query_groups}
             return {
+                **query,
                 "device_groups": device_groups,
                 "launches": {f"{b}x{e}": n for (b, e), n
                              in sorted(self.launches.items())},

@@ -16,6 +16,13 @@ scanner accepts filters in any of three forms:
 A pyarrow expression outside the supported subset falls back to a
 post-hoc in-memory filter (correct, unpruned) rather than failing.
 
+``aggregate(aggs, filter=, group_by=)`` reduces instead of materialising:
+exact sums (products of fields, ``(1-F)``, ``(1+F)`` included), min, max,
+avg and count, per group if asked. On a device backend the chip answers
+what it can prove exact and only the groups' partials come back; every
+other call decodes and reduces in ``pyarrow.compute``
+(``_aggregate_by_decode``, the definition of every answer).
+
 DuckDB / Polars worked example (README "Query pushdown")::
 
     dset = cobrix_tpu.query.dataset("companies.dat", copybook="c.cob",
@@ -33,7 +40,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence
 
-from .expr import Expr, normalize_filter, parse_filter
+from .expr import Expr, from_wire, normalize_filter, parse_filter
 
 
 def _lower_filter(filter_):
@@ -174,6 +181,8 @@ class CobolDataset:
         self.options = dict(options)
         self.schema = schema
         self.generated_columns = generated_columns
+        # the ReadMetrics of the last aggregate() the device answered
+        self.metrics = None
 
     def scanner(self, columns: Optional[Sequence[str]] = None,
                 filter=None, batch_size: int = 131072,
@@ -222,29 +231,65 @@ class CobolDataset:
         return self.scanner(self._narrowest_columns(filter),
                             filter).count_rows()
 
-    def aggregate(self, aggs: Sequence[str], filter=None) -> dict:
-        """Evaluate simple aggregates over the dataset.
+    def aggregate(self, aggs: Sequence[str], filter=None,
+                  group_by: Optional[Sequence[str]] = None):
+        """Evaluate aggregates over the dataset, optionally per group.
 
-        `aggs` is a list of specs: ``"count"``, ``"min:FIELD"``,
-        ``"max:FIELD"``, ``"sum:FIELD"``. Returns ``{spec: value}``
-        (``None`` = SQL NULL over no values; nulls are ignored by
-        min/max/sum, counted by count).
+        `aggs` is a list of specs (stats/aggregate.parse_specs):
+        ``"count"``, ``"min:FIELD"``, ``"max:FIELD"``, ``"sum:FIELD"``,
+        ``"avg:FIELD"``, and ``"sum:"`` of a ``*``-product of factors
+        ``FIELD``, ``(1-FIELD)``, ``(1+FIELD)``. Without `group_by` the
+        result is ``{spec: value}`` (``None`` = SQL NULL over no values;
+        nulls are ignored by min/max/sum/avg, counted by count); with it
+        a ``pyarrow.Table``, the key columns first, one row per group
+        present in the filtered data, ascending by key (a null key
+        last). Sums are exact: Python ints over integer fields, else
+        ``decimal.Decimal`` at the sum of the factors' scales (a null
+        factor makes the row's product null); ``avg`` is that sum over
+        the count of non-null values, divided in ``decimal``'s default
+        context. Float fields sum in float64.
 
-        With ``use_stats=true``, no filter, and a warm profile for
-        EVERY input file, the answer comes from persisted statistics
-        without decoding a byte (stats/aggregate.py) — and is exact by
-        construction: anything short of proof (missing profile,
-        NaN-tainted chunk, float sum, unknown field) silently falls
-        back to the decode path below, never an approximate answer.
+        Three routes, one answer. With ``use_stats=true``, no filter,
+        no `group_by` and a warm profile for EVERY input file, plain
+        count/min/max/sum come from persisted statistics without
+        decoding a byte (stats/aggregate.py), exact by construction:
+        anything short of proof falls back, never approximates. On a
+        device backend (``jax``, ``pallas``) a query the chip can answer
+        exactly goes to it, chunk by chunk, and only the groups'
+        partials come back (api.aggregate_on_device has the rule,
+        parallel/query.py the program and what binds). Everything else is
+        `_aggregate_by_decode`, which DEFINES what the other two must
+        reproduce digit for digit.
+
+        `self.metrics` is afterwards the call's ``ReadMetrics`` where
+        the device answered (``metrics.as_dict()["device"]`` as a read's
+        has it, with the ``query_*`` counts), else None.
         """
         from ..stats.aggregate import parse_specs
 
         specs = parse_specs(aggs)
-        if filter is None:
+        keys = [str(k) for k in (group_by or ())]
+        self.metrics = None
+        if filter is None and not keys:
             fast = self._aggregate_from_stats(specs)
             if fast is not None:
                 return fast
-        return self._aggregate_by_decode(specs, filter)
+        from ..reader.columnar import DEVICE_BACKENDS
+
+        wire, posthoc = _lower_filter(filter)
+        if self.backend in DEVICE_BACKENDS and posthoc is None:
+            from ..api import aggregate_on_device
+            from ..parallel.query import NotOnDevice
+
+            try:
+                result, self.metrics = aggregate_on_device(
+                    self.files, self.copybook_contents, self.options,
+                    self.backend, specs, from_wire(wire) if wire else None,
+                    keys, self.schema)
+                return result
+            except NotOnDevice:
+                pass  # raised before a byte is read: decode instead
+        return self._aggregate_by_decode(specs, filter, keys)
 
     def _aggregate_from_stats(self, specs) -> Optional[dict]:
         """Stats-only answer, or None (then the caller decodes)."""
@@ -264,35 +309,125 @@ class CobolDataset:
         copybook = copybook_for_params(self.copybook_contents, params)
         return aggregates_from_profiles(profiles, copybook, specs)
 
-    def _aggregate_by_decode(self, specs, filter_) -> dict:
+    def _decoded_table(self, columns, filter_):
+        """The decoded table `_aggregate_by_decode` computes over. The
+        scalar oracle (``backend="host"``) has no pushdown: its table is
+        decoded whole and filtered by the same bound expression
+        (query/pushdown.BoundFilter), so a literal meets a field the
+        same way on every backend."""
+        if filter_ is None or self.backend != "host":
+            return self.to_table(columns=columns, filter=filter_)
+        wire, posthoc = _lower_filter(filter_)
+        table = self.to_table(columns=None)
+        if posthoc is not None:
+            import pyarrow.dataset as pads
+
+            return pads.dataset(table).to_table(filter=posthoc)
+        from ..api import parse_options
+        from ..plan.cache import copybook_for_params
+        from .pushdown import BoundFilter
+
+        params, _opts = parse_options(dict(self.options))
+        bound = BoundFilter(
+            from_wire(wire),
+            copybook_for_params(self.copybook_contents, params), params)
+        return table.filter(bound.eval_table(table))
+
+    def _aggregate_by_decode(self, specs, filter_, keys=()):
         """The ground-truth path: decode, then pyarrow compute. The
-        semantics here DEFINE what the stats path must reproduce."""
+        semantics here DEFINE what the stats and device paths must
+        reproduce. Products and sums are made in decimal256 (float64
+        where a factor is a float), so nothing rounds or wraps."""
+        import decimal
+
+        import pyarrow as pa
         import pyarrow.compute as pc
 
+        from ..stats.aggregate import average, key_order, shape_result
         from ..stats.collect import leaf_columns
 
-        wanted = sorted({field for _, field in specs if field})
+        wanted = sorted({f for spec in specs for f in spec.fields}
+                        | set(keys))
         known = set(self.schema.names)
         cols = (wanted if wanted and all(f in known for f in wanted)
                 else None)  # nested leaves need the full-width decode
-        table = self.to_table(columns=cols, filter=filter_)
+        table = self._decoded_table(cols, filter_)
         leaves = leaf_columns(table)
-        out: dict = {}
-        for fn, field in specs:
-            if fn == "count":
-                out["count"] = table.num_rows
-                continue
-            if field not in leaves:
+
+        def leaf(name):
+            if name not in leaves:
                 raise KeyError(
-                    f"aggregate field {field!r} is not a primitive "
+                    f"aggregate field {name!r} is not a primitive "
                     "column of the decoded output")
-            _kind, col = leaves[field]
-            if fn == "sum":
-                out[f"sum:{field}"] = pc.sum(col).as_py()
-            else:
-                mm = pc.min_max(col).as_py()
-                out[f"{fn}:{field}"] = mm[fn]
-        return out
+            return leaves[name][1]
+
+        def exact(col, floats: bool):
+            if floats:
+                return pc.cast(col, pa.float64())
+            if pa.types.is_integer(col.type):
+                return pc.cast(col, pa.decimal256(20, 0))
+            return pc.cast(col, pa.decimal256(col.type.precision,
+                                              col.type.scale))
+
+        def values_of(spec):
+            """(the column a sum, avg, min or max of `spec` reduces,
+            whether its sum is an integer)."""
+            cols_ = [leaf(f.field) for f in spec.factors]
+            if spec.fn in ("min", "max"):
+                return cols_[0], False
+            floats = any(pa.types.is_floating(c.type) for c in cols_)
+            one = (pa.scalar(1.0) if floats else
+                   pa.scalar(decimal.Decimal(1), pa.decimal256(1, 0)))
+            product = None
+            for factor, col in zip(spec.factors, cols_):
+                term = exact(col, floats)
+                if factor.sign:
+                    term = (pc.add if factor.sign > 0
+                            else pc.subtract)(one, term)
+                product = term if product is None \
+                    else pc.multiply(product, term)
+            return product, all(pa.types.is_integer(c.type) for c in cols_)
+
+        work = {f"k{i}": leaf(name) for i, name in enumerate(keys)}
+        wants, integral = [([], "count_all")], {}
+        for j, spec in enumerate(specs):
+            if spec.fn == "count":
+                continue
+            work[f"v{j}"], integral[j] = values_of(spec)
+            wants += ([(f"v{j}", "sum"), (f"v{j}", "count")]
+                      if spec.fn in ("sum", "avg") else [(f"v{j}", spec.fn)])
+        if keys:
+            grouped = pa.table(work).group_by(
+                list(work)[:len(keys)], use_threads=False).aggregate(wants)
+            rows = grouped.to_pylist()
+        else:
+            # one group, there even over no rows at all
+            row = {"count_all": table.num_rows}
+            for name, fn in wants[1:]:
+                value = {"sum": pc.sum, "count": pc.count, "min": pc.min,
+                         "max": pc.max}[fn](work[name]).as_py()
+                row[f"{name}_{fn}"] = value
+            rows = [row]
+        groups = []
+        for row in rows:
+            values = {}
+            for j, spec in enumerate(specs):
+                if spec.fn == "count":
+                    values[spec.text] = row["count_all"]
+                elif spec.fn in ("min", "max"):
+                    values[spec.text] = row[f"v{j}_{spec.fn}"]
+                else:
+                    total = row[f"v{j}_sum"]
+                    if total is not None and integral[j]:
+                        total = int(total)
+                    values[spec.text] = (
+                        total if spec.fn == "sum"
+                        else average(total, row[f"v{j}_count"]))
+            groups.append((tuple(row[f"k{i}"] for i in range(len(keys))),
+                           values))
+        groups.sort(key=lambda g: key_order(g[0]))
+        return shape_result(specs, keys, groups,
+                            [leaf(k).type for k in keys])
 
     def __repr__(self) -> str:
         return (f"<CobolDataset files={len(self.files)} "

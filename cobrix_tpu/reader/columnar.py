@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import NamedTuple, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -246,6 +246,32 @@ class _KernelGroup:
         """uint128-limb output layout (values_hi/values/negative planes)."""
         return (self.codec in _NUMERIC_CODECS and self.variant
                 and self.variant[-1] is True)
+
+
+class GroupPlanes(NamedTuple):
+    """One kernel group's program outputs by name, each `[rows, columns]`
+    (the tuple's order by codec is `_run_group_jax`'s and the Pallas
+    kernel's): `values` the int32/int64 mantissas, float bit patterns or
+    string code points, for a wide group the low uint64 limb beside `hi`
+    and `negative`; `valid`; `dots` the per-value exponent plane of
+    DISPLAY groups. None where the group has no such plane."""
+
+    values: object
+    valid: object = None
+    dots: object = None
+    hi: object = None
+    negative: object = None
+
+
+def group_planes(g: "_KernelGroup", out) -> GroupPlanes:
+    """`out`, the output tuple of group `g`, read by name: what the
+    device aggregate's reductions and predicate read a group through."""
+    if g.codec in _STRING_CODECS or g.codec is Codec.HOST_FALLBACK:
+        return GroupPlanes(out[0] if out else None)
+    if g.wide:
+        return GroupPlanes(out[1], out[3], out[4] if len(out) > 4 else None,
+                           hi=out[0], negative=out[2])
+    return GroupPlanes(out[0], out[1], out[2] if len(out) > 2 else None)
 
 
 def _column_owner(spec: ColumnSpec) -> Optional[str]:
@@ -2198,31 +2224,45 @@ class ColumnarDecoder:
                 fieldcost.PLANE_DECODE, n)
 
     @staticmethod
-    def _launch_blocks(program, blocks, stats) -> list:
+    def _submit_block(program, rows, m: int, *more):
+        """One [block, extent] uint8 buffer of `m` real rows over the
+        link and into `program` (with `more` arguments after it), not
+        waited for: the launch `_fetch_block` brings home."""
+        import jax
+
+        with Stage("h2d"):
+            x = jax.device_put(rows)
+        compiled, built = program.compiled_for(x, *more)
+        with Stage("launch"):
+            device_outs = compiled.executable(x, *more)
+        return rows.shape, m, x.nbytes, device_outs, compiled, built, program
+
+    @staticmethod
+    def _fetch_block(launched, stats):
+        """(fetched outputs, real rows) of one `_submit_block` launch,
+        counted in `stats`."""
+        import jax
+
+        shape, m, h2d_bytes, device_outs, compiled, built, program = launched
+        with Stage("d2h_wait"):
+            host_outs = jax.device_get(device_outs)
+        if stats is not None:
+            leaves = jax.tree_util.tree_leaves(device_outs)
+            stats.note_launch(
+                shape, m, h2d_bytes,
+                sum(leaf.nbytes for leaf in leaves),
+                {d for leaf in leaves for d in leaf.devices()},
+                compiled, built, program.interpreted,
+                program.device_groups)
+        return host_outs, m
+
+    @classmethod
+    def _launch_blocks(cls, program, blocks, stats) -> list:
         """Each ([block, extent] uint8 buffer, its real rows) of `blocks`
         over the link, through `program` and back: [(fetched outputs,
         real rows)]."""
-        import jax
-
-        parts = []
-        for rows, m in blocks:
-            with Stage("h2d"):
-                x = jax.device_put(rows)
-            compiled, built = program.compiled_for(x)
-            with Stage("launch"):
-                device_outs = compiled.executable(x)
-            with Stage("d2h_wait"):
-                host_outs = jax.device_get(device_outs)
-            if stats is not None:
-                leaves = jax.tree_util.tree_leaves(device_outs)
-                stats.note_launch(
-                    rows.shape, m, x.nbytes,
-                    sum(leaf.nbytes for leaf in leaves),
-                    {d for leaf in leaves for d in leaf.devices()},
-                    compiled, built, program.interpreted,
-                    program.device_groups)
-            parts.append((host_outs, m))
-        return parts
+        return [cls._fetch_block(cls._submit_block(program, rows, m), stats)
+                for rows, m in blocks]
 
     @staticmethod
     def _merge_blocks(parts) -> list:

@@ -7,11 +7,18 @@ profiled record counts, ``min``/``max`` fold the per-chunk zone maps,
 and ``sum`` folds the per-chunk exact sums (int/decimal kinds only —
 float sums are order-dependent, never answered from stats). Anything
 short of proof — a missing profile, a NaN-tainted chunk, an unknown
-field, an inexact kind — returns None and the caller decodes, so a
-stats answer is always byte-identical to the decoded one.
+field, an inexact kind, a product, an average, a ``group_by`` — returns
+None and the caller decodes, so a stats answer is always byte-identical
+to the decoded one.
+
+The spec grammar (`parse_specs`) and the shape of a result
+(`shape_result`) are here too, one spelling for the stats, decode and
+device paths.
 """
 from __future__ import annotations
 
+import re
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .profile import FileProfile
@@ -19,24 +26,92 @@ from .profile import FileProfile
 _UNPROVABLE = object()
 
 
-def parse_specs(aggs: Sequence[str]) -> List[Tuple[str, Optional[str]]]:
-    """``["count", "min:FIELD", ...]`` -> ``[(fn, field|None), ...]``
-    (validated; the one spelling both the stats and decode paths
-    share)."""
-    out: List[Tuple[str, Optional[str]]] = []
-    for spec in aggs:
-        fn, sep, field = str(spec).partition(":")
-        fn = fn.strip().lower()
-        field = field.strip()
-        if fn == "count" and not field:
-            out.append(("count", None))
-            continue
-        if fn in ("min", "max", "sum") and sep and field:
-            out.append((fn, field))
-            continue
+@dataclass(frozen=True)
+class Factor:
+    """One factor of a product: the field itself, ``(1-FIELD)`` or
+    ``(1+FIELD)`` (`sign` 0, -1, +1)."""
+
+    field: str
+    sign: int = 0
+
+    def __str__(self) -> str:
+        if not self.sign:
+            return self.field
+        return f"(1{'+' if self.sign > 0 else '-'}{self.field})"
+
+
+@dataclass(frozen=True)
+class AggSpec:
+    """One aggregate: `fn` is count, min, max, sum or avg; `factors` is
+    empty for count, one plain field for min, max and avg, and one or
+    more factors for sum. `text` is the canonical spelling, the key of
+    the result."""
+
+    fn: str
+    factors: Tuple[Factor, ...] = ()
+
+    @property
+    def text(self) -> str:
+        if not self.factors:
+            return self.fn
+        return f"{self.fn}:{'*'.join(str(f) for f in self.factors)}"
+
+    @property
+    def field(self) -> Optional[str]:
+        """The one plain field of a single-field spec, else None."""
+        if len(self.factors) == 1 and not self.factors[0].sign:
+            return self.factors[0].field
+        return None
+
+    @property
+    def fields(self) -> List[str]:
+        return [f.field for f in self.factors]
+
+    def __iter__(self):
+        # (fn, field): what a spec was before it could be a product
+        return iter((self.fn, self.field))
+
+
+_FIELD = r"[A-Za-z_][A-Za-z0-9_\-:.]*"
+_FACTOR_RE = re.compile(
+    rf"^(?:(?P<plain>{_FIELD})|\(1(?P<sign>[+-])(?P<inner>{_FIELD})\))$")
+
+GRAMMAR = ("'count', 'min:FIELD', 'max:FIELD', 'sum:FIELD', 'avg:FIELD', or "
+           "'sum:' of a '*'-product of factors FIELD, (1-FIELD), (1+FIELD)")
+
+
+def _parse_factor(text: str, spec) -> Factor:
+    m = _FACTOR_RE.match(text)
+    if m is None:
         raise ValueError(
-            f"unsupported aggregate spec {spec!r} (use 'count', "
-            f"'min:FIELD', 'max:FIELD', or 'sum:FIELD')")
+            f"unsupported factor {text!r} in aggregate spec {spec!r} "
+            f"(use {GRAMMAR})")
+    if m.group("plain"):
+        return Factor(m.group("plain"))
+    return Factor(m.group("inner"), 1 if m.group("sign") == "+" else -1)
+
+
+def parse_specs(aggs: Sequence[str]) -> List[AggSpec]:
+    """``["count", "min:FIELD", "sum:A*(1-B)", ...]`` -> ``[AggSpec]``
+    (validated; the one spelling the stats, decode and device paths
+    share). A spec still unpacks as ``(fn, field)``; `field` is None
+    for count and for a product."""
+    out: List[AggSpec] = []
+    for spec in aggs:
+        fn, sep, body = str(spec).partition(":")
+        fn = fn.strip().lower()
+        body = re.sub(r"\s+", "", body)
+        if fn == "count" and not body:
+            out.append(AggSpec("count"))
+            continue
+        if fn in ("min", "max", "sum", "avg") and sep and body:
+            factors = tuple(_parse_factor(part, spec)
+                            for part in body.split("*"))
+            if fn == "sum" or (len(factors) == 1 and not factors[0].sign):
+                out.append(AggSpec(fn, factors))
+                continue
+        raise ValueError(
+            f"unsupported aggregate spec {spec!r} (use {GRAMMAR})")
     if not out:
         raise ValueError("aggregate() needs at least one spec")
     return out
@@ -140,6 +215,9 @@ def aggregates_from_profiles(profiles: List[FileProfile], copybook,
         if fn == "count":
             out["count"] = sum(p.total_records for p in profiles)
             continue
+        if field is None or fn == "avg":
+            # a product's sum and an average are no chunk's statistic
+            return None
         leaf = resolve_leaf(copybook, field)
         if leaf is None:
             return None
@@ -149,3 +227,79 @@ def aggregates_from_profiles(profiles: List[FileProfile], copybook,
             return None
         out[f"{fn}:{field}"] = value
     return out
+
+
+# -- the shape of a result --------------------------------------------------
+
+def scaled(total: int, scale: int):
+    """`total` units of 10^-`scale` as a Decimal of that exponent, every
+    digit kept (``Decimal.scaleb`` would round to the context's 28)."""
+    import decimal
+
+    return decimal.Decimal((int(total < 0),
+                            tuple(int(d) for d in str(abs(total))), -scale))
+
+
+def average(total, count: int):
+    """`avg`: the exact sum over the exact count, divided in `decimal`'s
+    default context; None over no values."""
+    import decimal
+
+    if not count or total is None:
+        return None
+    return decimal.Decimal(total) / decimal.Decimal(count)
+
+
+def _column(values: list):
+    """A pyarrow array of one result column: int64 for ints that fit,
+    one decimal type wide enough for every Decimal at their largest
+    scale (no value is rounded), else what pyarrow infers."""
+    import decimal
+
+    import pyarrow as pa
+
+    present = [v for v in values if v is not None]
+    if present and all(isinstance(v, int) and not isinstance(v, bool)
+                       for v in present):
+        if all(-2 ** 63 <= v < 2 ** 63 for v in present):
+            return pa.array(values, type=pa.int64())
+        values = [None if v is None else decimal.Decimal(v) for v in values]
+        present = [v for v in values if v is not None]
+    if present and all(isinstance(v, decimal.Decimal) for v in present):
+        scale = max(0, *(-v.as_tuple().exponent for v in present))
+        digits = max(len(v.as_tuple().digits) + v.as_tuple().exponent
+                     for v in present)
+        wide = max(1, digits) + scale > 38
+        return pa.array(values, type=(pa.decimal256(76, scale) if wide
+                                      else pa.decimal128(38, scale)))
+    return pa.array(values)
+
+
+def shape_result(specs: Sequence[AggSpec], keys: Sequence[str],
+                 groups: Sequence[Tuple[tuple, Dict[str, object]]],
+                 key_types: Sequence = ()):
+    """What ``aggregate()`` returns, from `groups`: ``[(key values,
+    {spec text: value})]``, ascending by key. Without `keys` there is
+    one group and the result is its ``{spec text: value}``; with them a
+    ``pyarrow.Table``, key columns first (of `key_types`, the decoded
+    columns' own), one row per group."""
+    import pyarrow as pa
+
+    if not keys:
+        (_key, values), = groups
+        return {spec.text: values[spec.text] for spec in specs}
+    columns = {name: pa.array([key[i] for key, _ in groups],
+                              type=key_types[i] if key_types else None)
+               for i, name in enumerate(keys)}
+    for spec in specs:
+        if spec.text in columns:
+            continue
+        column = [values[spec.text] for _, values in groups]
+        columns[spec.text] = (pa.array(column, type=pa.int64())
+                              if spec.fn == "count" else _column(column))
+    return pa.table(columns)
+
+
+def key_order(key: tuple) -> tuple:
+    """Sort key of a group's key values: ascending, nulls last."""
+    return tuple((v is None, 0 if v is None else v) for v in key)

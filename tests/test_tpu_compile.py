@@ -210,6 +210,50 @@ def test_device_aggregator(topo, mosaic, n_chips):
     assert ("all-reduce" in text) == (n_chips > 1)
 
 
+@pytest.mark.parametrize("name", ["q6", "q1"])
+def test_tpch_query_programs(one_chip, mosaic, name):
+    """The cell tpch_q6_q1's two programs at the shape a 64 MiB read chunk
+    of 149 B records launches: the kernel, the predicate and the grouped
+    integer reductions in one program, no gather, scatter or sort, far
+    inside the chip's memory, a few hundred bytes out."""
+    import json
+
+    import jax
+
+    from benchmark.generators import tpch_lineitem
+    from cobrix_tpu.parallel.query import DeviceAggregator, bind_query
+    from cobrix_tpu.query.expr import parse_filter
+    from cobrix_tpu.reader.arrow_out import arrow_schema
+    from cobrix_tpu.reader.schema import CobolOutputSchema
+    from cobrix_tpu.copybook.datatypes import SchemaRetentionPolicy
+    from cobrix_tpu.stats.aggregate import parse_specs
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "tpch_lineitem_sf1.json")) as f:
+        q = json.load(f)["queries"][name]
+    copybook = parse_copybook(tpch_lineitem.COPYBOOK)
+    schema = arrow_schema(CobolOutputSchema(
+        copybook, policy=SchemaRetentionPolicy.COLLAPSE_ROOT).schema)
+    agg = DeviceAggregator(copybook, backend="pallas", query=bind_query(
+        copybook, parse_specs(q["aggs"]), parse_filter(q["filter"]),
+        q.get("group_by", []), schema))
+    extent = {"q6": 29, "q1": 38}[name]
+    assert agg.decoder.plan.max_extent == extent
+    program = agg.device_program()
+    assert program.interpreted is False
+    rows = agg.decoder._bucket_size(450_395)
+    compiled = program._jit.lower(
+        jax.ShapeDtypeStruct((rows, extent), np.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < HBM_BYTES // 8
+    assert mem.output_size_in_bytes < 32768
+    text = compiled.as_text()
+    assert KERNEL in text and GATHER not in text
+    assert " scatter(" not in text and " sort(" not in text
+
+
 # one group of every fused kind x output width (ops/pallas_tpu.py
 # StridedGroup); the display kind follows the copybook's encoding
 KINDS_COPYBOOK = """
