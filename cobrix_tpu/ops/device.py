@@ -73,7 +73,8 @@ class DeviceProgram:
     shape. `interpreted` says whether the program's Pallas kernel runs
     through the interpreter (None: it has no Pallas kernel);
     `device_groups` how many of the decode's kernel groups took which
-    route ({"fused", "sliced", "gathered"}: build_jax_decode_fn)."""
+    route ({"fused", "fused_rows_in_lanes", "sliced", "gathered"}:
+    build_jax_decode_fn)."""
 
     def __init__(self, fn, interpreted: Optional[bool] = None,
                  device_groups: Optional[Dict[str, int]] = None,

@@ -7,31 +7,58 @@ reference's per-field hot loop, RecordExtractors.scala:49 +
 BinaryNumberDecoders.scala:21, BCDNumberDecoders.scala:29,
 StringDecoders.scala:154).
 
-Layout stays in XLA: byte ``j`` of every field in a group is one strided
-slice `data[:, base+j::stride]` when the group's offsets form an
-arithmetic progression (OCCURS arrays — e.g. exp3's `STRATEGY-DETAIL
-OCCURS 2000`, TestDataGen4CompaniesWide.scala:37-54), or one gather
-`data[:, offsets + j]` for irregular layouts (exp1's 195 heterogeneous
-fields). Mosaic (the Pallas TPU compiler) does not support strided lane
-slices or u8 lane gathers inside a kernel, so the byte planes are
-computed in XLA and flow into the kernel.
+Arithmetic is the Pallas kernel: binary two's complement, packed BCD, and
+zoned DISPLAY (the overpunch state machine as int32 VPU compare/select
+math), element-wise on whatever slabs it is handed. Values wider than 32
+bits (10-18 digit fields, and the 19-38 digit BigDecimal plane) are
+accumulated in base-2^16 limbs held in int32 lanes (TPUs have no native
+int64) and assembled into int64 / uint64-pair outputs by XLA after the
+kernel, so every fused group returns exactly the tuples the plain XLA
+route produces (`columnar._run_group_jax` contracts). String groups stay
+on that route (static slices and an element-wise lookup,
+`batch_jax.transcode_ebcdic`); floats and host-fallback columns are the
+only other non-fused planes.
 
-Arithmetic is the Pallas kernel: ONE launch decodes every numeric group —
-binary two's complement, packed BCD, and zoned DISPLAY (the overpunch
-state machine as int32 VPU compare/select math) — over `[BATCH_TILE,
-count]` tiles. Values wider than 32 bits (10-18 digit fields, and the
-19-38 digit BigDecimal plane) are accumulated in base-2^16 limbs held in
-int32 lanes — TPUs have no native int64 — and assembled into int64 /
-uint64-pair outputs by XLA after the kernel, so every fused group returns
-exactly the tuples the plain XLA route produces (`columnar.
-_run_group_jax` contracts). String groups stay on that route (static
-slices and an element-wise lookup, `batch_jax.transcode_ebcdic`); floats
-and host-fallback columns are the only other non-fused planes.
+Layout is XLA's, and the kernel has two orientations. Which one a group
+takes is read off the plan, its column count against the 128 lanes
+(`LANE_FILL_MIN`); a program holding both kinds makes two pallas_calls.
 
-Parity is pinned by tests/test_pallas_kernels.py against the numpy
-blueprint kernels through the interpreter, tests/test_tpu_compile.py
-compiles the Mosaic kernel for a described v5e, and chip_smoke.py runs
-it on the chip against the host kernels and the scalar oracle.
+* **Row tiles** (`_fused_kernel`), for a group whose columns fill the
+  lanes (OCCURS arrays: exp3's `STRATEGY-DETAIL OCCURS 2000`,
+  TestDataGen4CompaniesWide.scala:37-54): `[BATCH_TILE, count]` tiles,
+  32 rows in the sublanes and the group's columns in the lanes. Byte
+  ``j`` of every column is one strided slice `data[:, base+j::stride]`
+  (one gather `data[:, offsets + j]` where the offsets are no
+  progression): Mosaic supports neither strided lane slices nor u8 lane
+  gathers inside a kernel, so the byte planes are computed in XLA and
+  flow in side by side.
+* **Rows in the lanes** (`_lane_kernel`), for every narrower group (1 to
+  15 columns in exp1, 1 to 4 in the TPC-H queries): with columns in the
+  lanes such a group used 1 to 15 lanes of 128 and a launch of 524,288
+  rows made 16,384 grid steps of 32. Here the bytes the groups read are
+  transposed once in XLA to `[bytes, B / 128, 128]`, plane-major, and a
+  grid step takes `LANE_TILE` = 4,096 rows: a byte of a field is one
+  native `[32, 128]` uint8 tile, a value four whole int32 registers,
+  however few columns the group has. A field's bytes are adjacent
+  leading indices of that array whatever its offset in the record, so no
+  layout is irregular and nothing is gathered. A group is one loop over
+  its columns (the first plane of each from a table in scalar memory),
+  its body traced and compiled once. The `[columns, B / 128, 128]`
+  outputs go back to the contract's `[b, count]` planes in XLA.
+
+Measured on a TPU v5e (PERF.md section 6, PR 31): the whole exp1 program
+at `65536x1493` 352 ms a launch with every group in row tiles, 14 ms with
+its 61 groups' rows in the lanes; the TPC-H programs at `524288` rows 44
+to 1.9 and 3.3 ms. exp3's two groups (2,001 and 2,000 columns of four
+bytes, 48 KB read and written a row) would need 197 MB of vector memory
+a buffer at 4,096 rows a step, of a v5e's 128 MiB; they fill the lanes
+as they are and keep the row tiles.
+
+Parity of both orientations is pinned by tests/test_pallas_kernels.py
+against the numpy blueprint kernels through the interpreter,
+tests/test_tpu_compile.py compiles the Mosaic kernels for a described
+v5e at the benchmark's launch shapes, and chip_smoke.py runs them on the
+chip against the host kernels and the scalar oracle.
 """
 from __future__ import annotations
 
@@ -43,7 +70,23 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-BATCH_TILE = 32  # uint8 sublane tile
+from ..plan.compiler import merged_spans, packed_position
+
+BATCH_TILE = 32  # uint8 sublane tile: rows a grid step, row-tile kernel
+LANES = 128
+# rows a grid step of the rows-in-lanes kernel: [32, 128] uint8, one native
+# tile a byte of the record
+LANE_TILE = 32 * LANES
+# a group of this many columns fills the lanes with columns and keeps the
+# row-tile kernel; a narrower one puts the batch's rows in the lanes
+LANE_FILL_MIN = LANES
+# the rows-in-lanes kernel's blocks are double-buffered in vector memory
+# (128 MiB on a v5e, Mosaic's default limit 16 MiB): exp1's 1,457 byte
+# planes, 631 int32 and 249 bool columns are 4,977 B a row, 40.8 MB at
+# LANE_TILE rows, and stay one call; a program past 6 KiB a row (48 MiB)
+# is cut into several
+LANE_ROW_BYTES_MAX = 6 * 1024
+LANE_VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 
 # 16-bit limbs in int32 lanes: 4 limbs = one 64-bit value, 8 = 128-bit
 _LIMBS = {"i32": 1, "i64": 4, "wide": 8}
@@ -53,8 +96,9 @@ class StridedGroup:
     """Static decode spec for one fused kernel group.
 
     base/stride/count describe the offset progression when regular;
-    `offsets` carries the raw offsets for irregular groups (the byte
-    planes are then XLA gathers). width is the field byte width; kind is
+    `offsets` carries the raw offsets for irregular groups (in row tiles
+    the byte planes are then XLA gathers; with the rows in the lanes no
+    layout is irregular). width is the field byte width; kind is
     "binary", "bcd", "display_ebcdic" or "display_ascii"; `out` selects
     the value plane: "i32" (native int32 lanes), "i64" (4x16-bit limbs),
     or "wide" (8x16-bit limbs, the uint128 BigDecimal plane).
@@ -369,11 +413,12 @@ def _out_dtypes(g: StridedGroup):
 
 
 def _fused_kernel(layout, in_ref, o32_ref, obool_ref):
-    """ONE kernel for every group: reads each group's byte planes from the
-    packed input buffer and writes its outputs into column segments of the
+    """The row-tile kernel: `BATCH_TILE` rows in the sublanes, a group's
+    columns in the lanes. Reads each group's byte planes from the packed
+    input buffer and writes its outputs into column segments of the
     packed int32 / bool output buffers. Packing matters on TPU: separate
     [batch, count] buffers with tiny counts would each pad to the 128-lane
-    tile (a 128x memory blowup for exp1's 1-2 column groups)."""
+    tile."""
     for g, in_base, slots in layout:
         planes = [in_ref[:, in_base + j * g.count:
                          in_base + (j + 1) * g.count]
@@ -381,6 +426,29 @@ def _fused_kernel(layout, in_ref, o32_ref, obool_ref):
         for (space, start), arr in zip(slots, _decode_group(planes, g)):
             ref = o32_ref if space == "i32" else obool_ref
             ref[:, start:start + g.count] = arr
+
+
+def _lane_kernel(layout, rows_ref, in_ref, o32_ref, obool_ref):
+    """The rows-in-lanes kernel: `LANE_TILE` rows of the batch fill whole
+    vector registers ([LANE_TILE / 128, 128]: one native uint8 tile a
+    byte, four int32 registers a value) however few columns a group has.
+    The input is plane-major, one leading index a byte of the record;
+    `rows_ref` (scalar memory) holds the index of every column's first
+    byte, its other bytes follow. A group is one loop over its columns,
+    so its body is traced and compiled once and a column's live values
+    fit the register file."""
+    for g, col_base, slots in layout:
+
+        def column(c, carry):
+            first = rows_ref[col_base + c]
+            planes = [in_ref[first + jnp.int32(j)] for j in range(g.width)]
+            for (space, start), arr in zip(slots, _decode_group(planes, g)):
+                ref = o32_ref if space == "i32" else obool_ref
+                ref[jnp.int32(start) + c] = arr
+            return carry
+
+        # typed bounds: under jax_enable_x64 a Python int traces as i64
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(g.count), column, None)
 
 
 # ---------------------------------------------------------------------------
@@ -442,81 +510,67 @@ def _assemble_group(outs, g: StridedGroup):
     return (hi, lo, negative, valid) + tail
 
 
-def build_fused_decode(groups: Sequence[StridedGroup], record_len: int,
-                       interpret: bool | None = None):
-    """Returns fn(data: [B, record_len] uint8) -> [group tuples, ...] in
-    the `columnar._run_group_jax` output format for each group.
-
-    jit-traceable; pads the batch to the tile size, extracts the byte
-    planes in XLA, runs the single fused pallas_call over batch tiles,
-    and assembles limb outputs into int64 / uint64-pair planes.
-
-    `interpret=None` runs the kernel through the Pallas interpreter
-    everywhere but on a TPU (the CPU tests' parity tool). The choice is
-    recorded on the returned function as `fn.interpret`, so a caller that
-    needs the Mosaic kernel can refuse anything else.
-    """
-    from jax.experimental import pallas as pl
-
-    from .batch_jax import ensure_x64
-
-    ensure_x64()  # the limb assembly builds int64/uint64 planes
-    groups = list(groups)
-    need_len = max([record_len] + [g.end for g in groups])
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    # static layout: each group's byte planes occupy a column range of the
-    # packed uint8 input; each output occupies a range of the packed int32
-    # or bool output buffer
-    layout = []
-    in_base = 0
-    i32_base = 0
-    bool_base = 0
+def _output_slots(groups: Sequence[StridedGroup]):
+    """Where every output of every group lies in a kernel's packed int32
+    and bool buffers: [(space, first column), ...] a group, in
+    `_decode_group` order, and the two buffers' column counts."""
+    base = {"i32": 0, "bool": 0}
+    slots_of = []
     for g in groups:
         slots = []
         for dtype in _out_dtypes(g):
-            if dtype is jnp.bool_:
-                slots.append(("bool", bool_base))
-                bool_base += g.count
-            else:
-                slots.append(("i32", i32_base))
-                i32_base += g.count
+            space = "bool" if dtype is jnp.bool_ else "i32"
+            slots.append((space, base[space]))
+            base[space] += g.count
+        slots_of.append(slots)
+    return slots_of, max(base["i32"], 1), max(base["bool"], 1)
+
+
+def _i32_index(*dims):
+    """A block index map of static zeros and the grid step. Typed zeros:
+    under jax_enable_x64 a literal 0 traces as i64 and Mosaic rejects the
+    (i32, i64) index tuple."""
+    def index_map(i, *_):
+        return tuple(i if d else jnp.int32(0) for d in dims)
+    return index_map
+
+
+def _build_row_tiles(groups: Sequence[StridedGroup], interpret: bool):
+    """fn([B, need_len] uint8) -> one tuple a group, through the row-tile
+    kernel: byte planes `[B, count]` by strided slices (gathers where the
+    offsets are irregular) side by side, `BATCH_TILE` rows a grid step."""
+    from jax.experimental import pallas as pl
+
+    slots_of, total_i32, total_bool = _output_slots(groups)
+    layout = []
+    in_base = 0
+    for g, slots in zip(groups, slots_of):
         layout.append((g, in_base, slots))
         in_base += g.width * g.count
-    total_in = max(in_base, 1)
-    total_i32 = max(i32_base, 1)
-    total_bool = max(bool_base, 1)
+    total_in = in_base
 
     def fn(data):
         b = data.shape[0]
         bpad = -b % BATCH_TILE
-        lpad = need_len - data.shape[1]
-        if bpad or lpad > 0:
-            data = jnp.pad(data, ((0, bpad), (0, max(lpad, 0))))
-        n_tiles = (b + bpad) // BATCH_TILE
-
-        def batch_row(i):
-            # typed zero: under jax_enable_x64 a literal 0 traces as i64
-            # and Mosaic rejects the (i32, i64) index tuple
-            return (i, jnp.int32(0))
-
+        if bpad:
+            data = jnp.pad(data, ((0, bpad), (0, 0)))
         # scopes named by the plan, not by the order of fusion: they land
         # in every operation's `op_name`, so a trace names the step
         with jax.named_scope("cobrix.planes"):
             planes = []
             for g in groups:
                 planes.extend(_byte_planes(data, g))
-            packed = (jnp.concatenate(planes, axis=1) if planes
-                      else data[:, :1])
+            packed = jnp.concatenate(planes, axis=1)
         with jax.named_scope("cobrix.kernel"):
             o32, obool = pl.pallas_call(
                 functools.partial(_fused_kernel, layout),
-                grid=(n_tiles,),
-                in_specs=[pl.BlockSpec((BATCH_TILE, total_in), batch_row)],
-                out_specs=[pl.BlockSpec((BATCH_TILE, total_i32), batch_row),
+                grid=((b + bpad) // BATCH_TILE,),
+                in_specs=[pl.BlockSpec((BATCH_TILE, total_in),
+                                       _i32_index(1, 0))],
+                out_specs=[pl.BlockSpec((BATCH_TILE, total_i32),
+                                        _i32_index(1, 0)),
                            pl.BlockSpec((BATCH_TILE, total_bool),
-                                        batch_row)],
+                                        _i32_index(1, 0))],
                 out_shape=[jax.ShapeDtypeStruct((b + bpad, total_i32),
                                                 jnp.int32),
                            jax.ShapeDtypeStruct((b + bpad, total_bool),
@@ -526,12 +580,148 @@ def build_fused_decode(groups: Sequence[StridedGroup], record_len: int,
         results = []
         with jax.named_scope("cobrix.outputs"):
             for g, _, slots in layout:
-                bufs = []
-                for space, start in slots:
-                    src = o32 if space == "i32" else obool
-                    bufs.append(src[:b, start:start + g.count])
+                bufs = [(o32 if space == "i32" else obool)[
+                    :b, start:start + g.count] for space, start in slots]
                 results.append(tuple(_assemble_group(bufs, g)))
         return results
 
+    return fn
+
+
+def _build_rows_in_lanes(groups: Sequence[StridedGroup], interpret: bool):
+    """fn([B, need_len] uint8) -> one tuple a group, through the
+    rows-in-lanes kernel. The bytes the groups read (their fields' spans,
+    merged) are transposed once in XLA to `[bytes, B / 128, 128]`: a
+    field's bytes are adjacent leading indices whatever its offset, so no
+    layout is irregular here and nothing is gathered. The kernel's
+    `[columns, B / 128, 128]` outputs come back as the `[B, count]` planes
+    of the `_run_group_jax` contract by a small transpose (a reshape
+    where the group is one column)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    spans = merged_spans((o, o + g.width) for g in groups
+                         for o in g.offsets)
+    total_in = sum(hi - lo for lo, hi in spans)
+    slots_of, total_i32, total_bool = _output_slots(groups)
+    layout = []
+    first_planes: List[int] = []
+    for g, slots in zip(groups, slots_of):
+        layout.append((g, len(first_planes), slots))
+        first_planes.extend(packed_position(spans, o) for o in g.offsets)
+    sub = LANE_TILE // LANES
+
+    def fn(data):
+        b = data.shape[0]
+        bpad = -b % LANE_TILE
+        folds = (b + bpad) // LANES
+        with jax.named_scope("cobrix.planes"):
+            parts = [jax.lax.slice_in_dim(data, lo, hi, axis=1)
+                     for lo, hi in spans]
+            needed = (parts[0] if len(parts) == 1
+                      else jnp.concatenate(parts, axis=1))
+            if bpad:
+                needed = jnp.pad(needed, ((0, bpad), (0, 0)))
+            planes = needed.T.reshape(total_in, folds, LANES)
+        with jax.named_scope("cobrix.kernel"):
+            o32, obool = pl.pallas_call(
+                functools.partial(_lane_kernel, layout),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(folds // sub,),
+                    in_specs=[pl.BlockSpec((total_in, sub, LANES),
+                                           _i32_index(0, 1, 0))],
+                    out_specs=[pl.BlockSpec((total_i32, sub, LANES),
+                                            _i32_index(0, 1, 0)),
+                               pl.BlockSpec((total_bool, sub, LANES),
+                                            _i32_index(0, 1, 0))]),
+                out_shape=[jax.ShapeDtypeStruct((total_i32, folds, LANES),
+                                                jnp.int32),
+                           jax.ShapeDtypeStruct((total_bool, folds, LANES),
+                                                jnp.bool_)],
+                compiler_params=pltpu.CompilerParams(
+                    vmem_limit_bytes=LANE_VMEM_LIMIT_BYTES),
+                interpret=interpret,
+            )(jnp.asarray(first_planes, dtype=jnp.int32), planes)
+        results = []
+        with jax.named_scope("cobrix.outputs"):
+            for g, _, slots in layout:
+                bufs = []
+                for space, start in slots:
+                    src = o32 if space == "i32" else obool
+                    rows = src[start:start + g.count].reshape(
+                        g.count, folds * LANES)[:, :b]
+                    bufs.append(rows.T)
+                results.append(tuple(_assemble_group(bufs, g)))
+        return results
+
+    return fn
+
+
+def _lane_calls(picked: Sequence[int], groups: Sequence[StridedGroup]):
+    """`picked` (indices of rows-in-lanes groups) cut, in order, into the
+    pallas_calls whose blocks fit the vector memory: one call unless the
+    groups read and write more than LANE_ROW_BYTES_MAX a row."""
+    calls: List[List[int]] = []
+    room = 0
+    for i in picked:
+        g = groups[i]
+        # a bool leaves the kernel as an int32 (Mosaic's memref type)
+        cost = g.count * (g.width + 4 * len(_out_dtypes(g)))
+        if not calls or cost > room:
+            calls.append([])
+            room = LANE_ROW_BYTES_MAX
+        calls[-1].append(i)
+        room -= cost
+    return calls
+
+
+def build_fused_decode(groups: Sequence[StridedGroup], record_len: int,
+                       interpret: bool | None = None):
+    """Returns fn(data: [B, record_len] uint8) -> [group tuples, ...] in
+    the `columnar._run_group_jax` output format for each group.
+
+    jit-traceable. Groups of at least `LANE_FILL_MIN` columns go through
+    the row-tile kernel, narrower ones through the rows-in-lanes kernel:
+    at most two pallas_calls a program (more only where the narrow groups
+    outgrow the vector memory, `_lane_calls`), one result list in group
+    order.
+    `fn.rows_in_lanes` counts the groups of the second kind.
+
+    `interpret=None` runs the kernels through the Pallas interpreter
+    everywhere but on a TPU (the CPU tests' parity tool). The choice is
+    recorded on the returned function as `fn.interpret`, so a caller that
+    needs the Mosaic kernel can refuse anything else.
+    """
+    from .batch_jax import ensure_x64
+
+    ensure_x64()  # the limb assembly builds int64/uint64 planes
+    groups = list(groups)
+    need_len = max([record_len] + [g.end for g in groups])
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+
+    lane_full = [g.count >= LANE_FILL_MIN for g in groups]
+    parts = []
+    picked = [i for i, full in enumerate(lane_full) if full]
+    if picked:
+        parts.append((picked, _build_row_tiles(
+            [groups[i] for i in picked], interpret)))
+    for picked in _lane_calls(
+            [i for i, full in enumerate(lane_full) if not full], groups):
+        parts.append((picked, _build_rows_in_lanes(
+            [groups[i] for i in picked], interpret)))
+
+    def fn(data):
+        lpad = need_len - data.shape[1]
+        if lpad > 0:
+            data = jnp.pad(data, ((0, 0), (0, lpad)))
+        results: List[Optional[tuple]] = [None] * len(groups)
+        for picked, part in parts:
+            for i, outs in zip(picked, part(data)):
+                results[i] = outs
+        return results
+
     fn.interpret = interpret
+    fn.rows_in_lanes = lane_full.count(False)
     return fn

@@ -394,3 +394,25 @@ def compile_plan(copybook: Copybook,
         is_utf16_big_endian=copybook.is_utf16_big_endian,
         floating_point_format=copybook.floating_point_format,
     )
+
+
+def merged_spans(intervals) -> List[Tuple[int, int]]:
+    """Byte intervals [lo, hi) merged where they overlap or touch."""
+    spans: List[Tuple[int, int]] = []
+    for lo, hi in sorted(intervals):
+        if spans and lo <= spans[-1][1]:
+            spans[-1] = (spans[-1][0], max(spans[-1][1], hi))
+        else:
+            spans.append((lo, hi))
+    return spans
+
+
+def packed_position(spans: Sequence[Tuple[int, int]], offset: int) -> int:
+    """Where byte `offset` of a record lies once `spans` (disjoint, each
+    [lo, hi)) are laid side by side in their order."""
+    at = 0
+    for lo, hi in spans:
+        if lo <= offset < hi:
+            return at + offset - lo
+        at += hi - lo
+    raise ValueError(f"byte {offset} lies in none of {list(spans)}")

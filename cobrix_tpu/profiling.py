@@ -378,12 +378,14 @@ class DeviceStats:
     @property
     def device_groups(self) -> Dict[str, int]:
         """Kernel groups by the route they took on the device (fused
-        Pallas kernel, static slices, XLA gather), summed over the
-        decoders whose programs this read launched."""
+        Pallas kernel, and how many of those with the batch's rows in
+        the lanes; static slices; XLA gather), summed over the decoders
+        whose programs this read launched."""
         with self._lock:
             counted = list(self._program_groups.values())
         return {route: sum(groups.get(route, 0) for groups in counted)
-                for route in ("fused", "sliced", "gathered")}
+                for route in ("fused", "fused_rows_in_lanes", "sliced",
+                              "gathered")}
 
     def as_dict(self) -> dict:
         device_groups = self.device_groups

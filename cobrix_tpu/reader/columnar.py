@@ -43,7 +43,9 @@ from ..obs import fieldcost
 from ..ops import batch_np
 from ..profiling import Stage, annotate
 from ..plan.cache import cached_code_page_lut, cached_compile_plan
-from ..plan.compiler import Codec, ColumnSpec, FieldPlan
+from ..plan.compiler import (Codec, ColumnSpec, FieldPlan,
+                             merged_spans as _merged_spans,
+                             packed_position)
 from .extractors import DecodeOptions
 import decimal as _decimal
 
@@ -347,7 +349,9 @@ def _pallas_group_spec(g: _KernelGroup):
     host (host fallback).
     Every numeric plane is fused: int32 lanes natively, 10-18-digit and
     wide (BigDecimal) fields via base-2^16 limb arithmetic in int32
-    lanes; irregular offsets feed the kernel through XLA gathers."""
+    lanes; the kernel reads a group of fewer than 128 columns from the
+    transposed record matrix, whatever its offsets, and a wider one from
+    byte planes XLA cuts (strided slices, gathers for irregular offsets)."""
     from ..ops import pallas_tpu
 
     if g.codec is Codec.BINARY:
@@ -402,17 +406,6 @@ def _slice_pieces(offsets, width: int) -> List[Tuple[int, int, int]]:
 
 def _dense(piece: Tuple[int, int, int], width: int) -> bool:
     return piece[1] == 1 or piece[2] == width
-
-
-def _merged_spans(intervals) -> List[Tuple[int, int]]:
-    """Byte intervals [lo, hi) merged where they overlap or touch."""
-    spans: List[Tuple[int, int]] = []
-    for lo, hi in sorted(intervals):
-        if spans and lo <= spans[-1][1]:
-            spans[-1] = (spans[-1][0], max(spans[-1][1], hi))
-        else:
-            spans.append((lo, hi))
-    return spans
 
 
 def _groups_extent(groups) -> int:
@@ -1955,10 +1948,16 @@ class ColumnarDecoder:
         are not in it, and a group's tuple is what it is in the whole
         program.
 
-        backend "pallas": numeric groups whose offsets form an arithmetic
-        progression (OCCURS-array layouts) decode through the single fused
-        Pallas kernel — one VMEM pass of each batch tile for the whole
-        numeric plane (ops/pallas_tpu.py). Every other group, and with
+        backend "pallas": every numeric group (`_pallas_group_spec`)
+        decodes through the fused Pallas kernel, one VMEM pass of each
+        batch tile for the whole numeric plane (ops/pallas_tpu.py), in
+        the orientation its column count asks for: a group that fills
+        the 128 lanes with columns (OCCURS arrays) in row tiles, 32 rows
+        a grid step, its byte planes strided slices; a narrower one
+        (scattered scalars: all of exp1, the TPC-H queries, exp2) with
+        the batch's rows in the lanes, 4,096 a grid step, its bytes read
+        from one transpose of the record matrix. A program with both
+        kinds makes two pallas_calls. Every other group, and with
         backend "jax" every group, takes the XLA route: its [n, columns,
         width] bytes are static slices chosen from its offsets (one for a
         run of adjacent columns, `width` strided ones for a run with other
@@ -1966,9 +1965,10 @@ class ColumnarDecoder:
         and EBCDIC strings become code points in one element-wise lookup
         a program (batch_jax.transcode_ebcdic) over the bytes their
         fields cover, each byte once however many redefines read it.
-        `decode_all.device_groups` counts the groups by route. `mesh`:
-        with a multi-device mesh the fused
-        pallas_call is wrapped in shard_map over the ``data`` axis (GSPMD
+        `decode_all.device_groups` counts the groups by route, and
+        under `fused_rows_in_lanes` the fused ones of the second
+        orientation. `mesh`: with a multi-device mesh the fused
+        pallas_calls are wrapped in shard_map over the ``data`` axis (GSPMD
         cannot partition a custom call — an unwrapped kernel would force
         an all-gather of the whole batch onto every chip); the non-fused
         XLA groups stay in the outer GSPMD context."""
@@ -1985,6 +1985,7 @@ class ColumnarDecoder:
 
         fused = None
         interpret = None
+        rows_in_lanes = 0
         fused_indices: List[int] = []
         if self.backend == "pallas":
             from ..ops import pallas_tpu
@@ -1998,6 +1999,7 @@ class ColumnarDecoder:
             if strided:
                 fused = pallas_tpu.build_fused_decode(strided, extent)
                 interpret = fused.interpret
+                rows_in_lanes = fused.rows_in_lanes
                 if mesh is not None and mesh.devices.size > 1:
                     from jax.sharding import PartitionSpec
 
@@ -2022,6 +2024,7 @@ class ColumnarDecoder:
             pieces_of[gi] = pieces if slices <= SLICE_PIECES_MAX else None
         device_groups = {
             "fused": len(fused_indices),
+            "fused_rows_in_lanes": rows_in_lanes,
             "sliced": sum(p is not None for p in pieces_of.values()),
             "gathered": sum(p is None for p in pieces_of.values())}
         # EBCDIC bytes become code points in one lookup a program: first
@@ -2034,12 +2037,6 @@ class ColumnarDecoder:
             for gi, g in enumerate(kernel_groups)
             if g.codec is Codec.EBCDIC_STRING and pieces_of.get(gi)
             for piece in pieces_of[gi] if _dense(piece, g.width))
-        span_at = np.cumsum([0] + [hi - lo for lo, hi in spans])
-
-        def span_position(offset: int) -> int:
-            k = next(k for k, (lo, hi) in enumerate(spans)
-                     if lo <= offset < hi)
-            return int(span_at[k]) + offset - spans[k][0]
 
         def piece_bytes(data, piece, width):
             """[n, columns, width] bytes of one run of columns, by one
@@ -2086,7 +2083,7 @@ class ColumnarDecoder:
             # each EBCDIC group reads its columns in it: (position, columns)
             blocks = [jax.lax.slice_in_dim(data, lo, hi, axis=1)
                       for lo, hi in spans]
-            cursor = int(span_at[-1])
+            cursor = sum(hi - lo for lo, hi in spans)
             reads: Dict[int, list] = {}
             for gi, pieces in pieces_of.items():
                 g = kernel_groups[gi]
@@ -2098,7 +2095,8 @@ class ColumnarDecoder:
                     for piece in pieces or [None]:
                         if piece is not None and _dense(piece, g.width):
                             reads[gi].append(
-                                (span_position(piece[0]), piece[1]))
+                                (packed_position(spans, piece[0]),
+                                 piece[1]))
                             continue
                         block = (group_bytes(data, g, None) if piece is None
                                  else piece_bytes(data, piece, g.width))

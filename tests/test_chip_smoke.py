@@ -57,7 +57,10 @@ def test_one_chip_phases_rehearsal(cpu_device, tmp_path, capsys):
     # exp3 launches by redefine, two programs; exp1 brings no masks
     assert exp3["partitioned_batches"] >= 1 and not exp3["declined_batches"]
     assert sum(exp3["set_rows"].values()) == exp3["records"]
-    assert exp3["device_groups"] == {"fused": 2, "sliced": 10, "gathered": 0}
+    assert exp3["device_groups"] == {"fused": 2, "fused_rows_in_lanes": 0,
+                                     "sliced": 10, "gathered": 0}
+    assert reads[("read_exp1", "pallas")]["device_groups"] == {
+        "fused": 61, "fused_rows_in_lanes": 61, "sliced": 4, "gathered": 0}
     assert not reads[("read_exp1", "pallas")]["set_rows"]
     assert reads[("read_exp1", "jax")]["interpreted"] is None
     parity = [line for line in lines if line.get("parity") == "ok"]

@@ -421,8 +421,8 @@ def test_stages_and_counters_of_a_device_aggregate(tmp_path, small_chunks):
         "query_groups": got.num_rows}
     assert device["records"] == 2400 and out["records"] == 2400
     assert out["bytes_read"] == 2400 * RECORD
-    assert device["device_groups"] == {"fused": 0, "sliced": 3,
-                                       "gathered": 0}
+    assert device["device_groups"] == {"fused": 0, "fused_rows_in_lanes": 0,
+                                       "sliced": 3, "gathered": 0}
     # a read's record has no query counts
     from cobrix_tpu import read_cobol
     read = read_cobol(str(path), backend="jax", **LINEITEM)

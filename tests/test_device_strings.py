@@ -73,7 +73,10 @@ def test_all_bytes_of_every_code_page(page, backend):
     want = batch_np.transcode_ebcdic(arr[:, :256], lut)
     assert got.dtype == np.uint16 and got.shape == want.shape
     np.testing.assert_array_equal(got, want)
+    # the one COMP column rides the kernel with the rows in the lanes
     assert fn.device_groups == {"fused": int(backend == "pallas"),
+                                "fused_rows_in_lanes": int(
+                                    backend == "pallas"),
                                 "sliced": 2 - int(backend == "pallas"),
                                 "gathered": 0}
 
@@ -192,7 +195,8 @@ def test_layout_parity(layout, backend):
         layout == "irregular_columns_past_the_limit"), routes
     if layout == "float_group" and backend == "jax":
         # the two floats are one group, evenly spaced; the double another
-        assert routes == {"fused": 0, "sliced": 3, "gathered": 0}
+        assert routes == {"fused": 0, "fused_rows_in_lanes": 0,
+                          "sliced": 3, "gathered": 0}
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -231,11 +235,16 @@ UPSTREAM = {
     # exp3 adds the OCCURS planes (COMP and COMP-3 are one fused group
     # each, TAXPAYER-NUM rides with the COMP one); exp1: 61 numeric
     # groups, three string columns in two groups (two of them adjacent)
-    # and two float groups, none past the slice limit
-    "exp2": (EXP2_COPYBOOK, True, {"fused": 1, "sliced": 8, "gathered": 0}),
-    "exp3": (EXP3_COPYBOOK, True, {"fused": 2, "sliced": 8, "gathered": 0}),
+    # and two float groups, none past the slice limit. Of the fused
+    # groups only exp3's two fill the 128 lanes with columns (OCCURS
+    # 2000); every other puts the batch's rows there
+    "exp2": (EXP2_COPYBOOK, True, {"fused": 1, "fused_rows_in_lanes": 1,
+                                   "sliced": 8, "gathered": 0}),
+    "exp3": (EXP3_COPYBOOK, True, {"fused": 2, "fused_rows_in_lanes": 0,
+                                   "sliced": 8, "gathered": 0}),
     "exp1": (EXP1_COPYBOOK, False,
-             {"fused": 61, "sliced": 4, "gathered": 0}),
+             {"fused": 61, "fused_rows_in_lanes": 61, "sliced": 4,
+              "gathered": 0}),
 }
 
 
@@ -250,7 +259,8 @@ def test_device_groups_of_upstream_copybooks(name):
     # without the kernel its groups are sliced like the others
     on_xla = ColumnarDecoder(cb, backend="jax").build_jax_decode_fn()
     assert on_xla.device_groups == {
-        "fused": 0, "sliced": want["fused"] + want["sliced"], "gathered": 0}
+        "fused": 0, "fused_rows_in_lanes": 0,
+        "sliced": want["fused"] + want["sliced"], "gathered": 0}
 
 
 def test_read_metrics_carry_device_groups(tmp_path):
@@ -271,3 +281,33 @@ def test_read_metrics_carry_device_groups(tmp_path):
     # a host read launches nothing and says nothing of routes
     host = read_cobol(str(path), backend="numpy", **options)
     assert "device_groups" not in host.metrics.as_dict()
+
+
+@pytest.mark.parametrize("shape", ["exp1", "exp3"])
+def test_read_metrics_say_which_way_the_kernel_was_turned(tmp_path, shape):
+    """`fused_rows_in_lanes` through a read's own record: every fused
+    group of a fixed-length file of scattered narrow numerics (exp1), none
+    of a file whose numerics are two OCCURS 2000 planes (exp3, the two
+    programs of its launches by redefine summed)."""
+    from cobrix_tpu import read_cobol
+    from cobrix_tpu.testing.generators import generate_exp1, generate_exp3
+
+    path = tmp_path / f"{shape}.bin"
+    if shape == "exp1":
+        path.write_bytes(generate_exp1(20, seed=6).tobytes())
+        options = dict(copybook_contents=EXP1_COPYBOOK)
+        want = UPSTREAM["exp1"][2]
+    else:
+        path.write_bytes(generate_exp3(40, seed=6))
+        options = dict(copybook_contents=EXP3_COPYBOOK,
+                       is_record_sequence="true",
+                       segment_field="SEGMENT-ID",
+                       redefine_segment_id_map="STATIC-DETAILS => C",
+                       redefine_segment_id_map_1="CONTACTS => P")
+        want = {"fused": 2, "fused_rows_in_lanes": 0, "sliced": 10,
+                "gathered": 0}
+    data = read_cobol(str(path), backend="pallas", **options)
+    device = data.metrics.as_dict()["device"]
+    assert device["device_groups"] == want
+    assert data.metrics.as_dict()["device_groups"] == want
+    assert device["interpreted"] is True

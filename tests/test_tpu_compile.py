@@ -15,6 +15,7 @@ worker. For the same reason everything compiles in this process, in
 this one file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -30,6 +31,12 @@ pytestmark = pytest.mark.jax
 
 KERNEL = "tpu_custom_call"
 GATHER = " gather("
+# the kernel's input among its custom call's operand layouts, by
+# orientation (ops/pallas_tpu.py): `[B, bytes]` rows in the sublanes and a
+# group's columns in the lanes, or `[bytes, B / 128, 128]` rows in the lanes
+ROW_TILES = re.compile(r"operand_layout_constraints=\{u8\[\d+,\d+\]")
+ROWS_IN_LANES = re.compile(
+    r"operand_layout_constraints=\{s32\[\d+\]\{0\}, u8\[\d+,\d+,128\]")
 EXP3_EXTENT = 16064
 HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
 # temporaries of PR 26's programs (the parent of the change that took the
@@ -84,6 +91,16 @@ def exp3_copybook():
                           segment_redefines=["STATIC_DETAILS", "CONTACTS"])
 
 
+def kernel_calls(text: str):
+    """(row-tile kernels, rows-in-lanes kernels) in a compiled program."""
+    calls = [line for line in text.splitlines()
+             if KERNEL in line and " custom-call(" in line]
+    tiles = sum(bool(ROW_TILES.search(line)) for line in calls)
+    lanes = sum(bool(ROWS_IN_LANES.search(line)) for line in calls)
+    assert tiles + lanes == len(calls), calls
+    return tiles, lanes
+
+
 def compile_on(sharding, fn, batch: int, extent: int):
     import jax
 
@@ -113,7 +130,10 @@ def test_exp3_decode_pallas(one_chip, mosaic, batch):
     assert KERNEL in compiled.as_text()
     # the eight string groups are static slices of one looked-up plane
     assert GATHER not in compiled.as_text()
-    assert fn.device_groups == {"fused": 2, "sliced": 8, "gathered": 0}
+    # both OCCURS 2000 groups fill the lanes: the row-tile kernel
+    assert fn.device_groups == {"fused": 2, "fused_rows_in_lanes": 0,
+                                "sliced": 8, "gathered": 0}
+    assert kernel_calls(compiled.as_text()) == (1, 0)
     if batch == 8192:
         # the kernel's planes are all but 1 MB of it, as in the parent
         # (the string gathers' temporaries were small at 8,192 rows); the
@@ -124,8 +144,9 @@ def test_exp3_decode_pallas(one_chip, mosaic, batch):
 
 @pytest.mark.parametrize("redefine, batch, extent, groups", [
     ("STATIC_DETAILS", 8192, EXP3_EXTENT,
-     {"fused": 2, "sliced": 6, "gathered": 0}),
-    ("CONTACTS", 16384, 60, {"fused": 0, "sliced": 4, "gathered": 0}),
+     {"fused": 2, "fused_rows_in_lanes": 0, "sliced": 6, "gathered": 0}),
+    ("CONTACTS", 16384, 60,
+     {"fused": 0, "fused_rows_in_lanes": 0, "sliced": 4, "gathered": 0}),
 ])
 def test_exp3_set_programs(one_chip, mosaic, redefine, batch, extent,
                            groups):
@@ -145,7 +166,8 @@ def test_exp3_set_programs(one_chip, mosaic, redefine, batch, extent,
     assert fn.device_groups == groups
     assert fn.interpret is (False if groups["fused"] else None)
     compiled = compile_on(one_chip, fn, batch, extent)
-    assert (KERNEL in compiled.as_text()) == bool(groups["fused"])
+    assert kernel_calls(compiled.as_text()) == (
+        int(bool(groups["fused"])), 0)
     assert GATHER not in compiled.as_text()
     if redefine == "STATIC_DETAILS":
         # the whole program's kernel planes, and no more
@@ -157,7 +179,9 @@ def test_exp2_decode_pallas_full_block(one_chip, mosaic):
     """64 B records of strings, half a vreg's lanes, at the largest batch
     the decoder launches (2,097,152 rows when this was written: a 100 MiB
     shard of an exp2 read is 1.6 million records). No gather is left (the
-    parent's program held 456), and fewer temporaries than the parent's."""
+    parent's program held 456), and fewer temporaries than the parent's.
+    The one COMP column is decoded with the rows in the lanes: 512 grid
+    steps where the row-tile kernel took 65,536."""
     decoder = ColumnarDecoder(
         parse_copybook(EXP2_COPYBOOK,
                        segment_redefines=["STATIC_DETAILS", "CONTACTS"]),
@@ -165,9 +189,10 @@ def test_exp2_decode_pallas_full_block(one_chip, mosaic):
     assert decoder.plan.max_extent == 64
     batch = full_block(decoder)
     fn = decoder.build_jax_decode_fn()
-    assert fn.device_groups == {"fused": 1, "sliced": 8, "gathered": 0}
+    assert fn.device_groups == {"fused": 1, "fused_rows_in_lanes": 1,
+                                "sliced": 8, "gathered": 0}
     compiled = compile_on(one_chip, fn, batch, 64)
-    assert KERNEL in compiled.as_text()
+    assert kernel_calls(compiled.as_text()) == (0, 1)
     assert GATHER not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < PARENT_EXP2_TEMP_BYTES
@@ -183,30 +208,43 @@ def test_exp3_decode_xla_gather(one_chip):
     compiled = compile_on(one_chip, fn, 2048, EXP3_EXTENT)
     assert KERNEL not in compiled.as_text()
     # the route keeps its old name; its groups are strided slices now
-    assert fn.device_groups == {"fused": 0, "sliced": 10, "gathered": 0}
+    assert fn.device_groups == {"fused": 0, "fused_rows_in_lanes": 0,
+                                "sliced": 10, "gathered": 0}
     assert GATHER not in compiled.as_text()
 
 
 @pytest.mark.parametrize("n_chips", [1, 4])
-def test_device_aggregator(topo, mosaic, n_chips):
-    """NUM1+NUM2 over the exp3 'C' records: the kernel on every mesh, and
-    the cross-chip reduction only on the four-chip one."""
+@pytest.mark.parametrize("columns", ["occurs_2000", "single_columns"])
+def test_device_aggregator(topo, mosaic, n_chips, columns):
+    """NUM1+NUM2 over the exp3 'C' records (two groups that fill the
+    lanes: the row-tile kernel), and three single columns of the kinds
+    copybook (the rows-in-lanes kernel): the kernel inside `shard_map` on
+    every mesh, and the cross-chip reduction only on the four-chip one."""
     import jax
     from jax.sharding import Mesh
 
     from cobrix_tpu.parallel import DeviceAggregator
 
     mesh = Mesh(np.asarray(topo.devices[:n_chips]), axis_names=("data",))
-    agg = DeviceAggregator(exp3_copybook(), columns=["NUM1", "NUM2"],
-                           active_segment="STATIC_DETAILS", mesh=mesh,
-                           backend="pallas")
+    if columns == "occurs_2000":
+        agg = DeviceAggregator(exp3_copybook(), columns=["NUM1", "NUM2"],
+                               active_segment="STATIC_DETAILS", mesh=mesh,
+                               backend="pallas")
+    else:
+        agg = DeviceAggregator(parse_copybook(KINDS_COPYBOOK),
+                               columns=["BIN-I32", "BCD-I64", "DSP-I32"],
+                               mesh=mesh, backend="pallas")
     program = agg.device_program()
     assert program.interpreted is False
+    assert program.device_groups["fused_rows_in_lanes"] == (
+        0 if columns == "occurs_2000" else 3)
     compiled, built = program.compiled_for(
         jax.ShapeDtypeStruct((2048, agg.record_extent), np.uint8),
         jax.ShapeDtypeStruct((), np.int32))
     assert built and compiled.has_kernel
     text = compiled.executable.as_text()
+    assert kernel_calls(text) == (
+        (1, 0) if columns == "occurs_2000" else (0, 1))
     assert ("all-reduce" in text) == (n_chips > 1)
 
 
@@ -250,7 +288,12 @@ def test_tpch_query_programs(one_chip, mosaic, name):
     assert mem.temp_size_in_bytes < HBM_BYTES // 8
     assert mem.output_size_in_bytes < 32768
     text = compiled.as_text()
-    assert KERNEL in text and GATHER not in text
+    # two narrow groups a query (COMP-3 and the DISPLAY date): one
+    # rows-in-lanes kernel of 128 grid steps, where 32 rows a step made
+    # 16,384
+    assert program.device_groups["fused"] == 2
+    assert program.device_groups["fused_rows_in_lanes"] == 2
+    assert kernel_calls(text) == (0, 1) and GATHER not in text
     assert " scatter(" not in text and " sort(" not in text
 
 
@@ -270,8 +313,14 @@ KINDS_COPYBOOK = """
 """
 
 
+@pytest.mark.parametrize("orientation", ["row_tiles", "rows_in_lanes"])
 @pytest.mark.parametrize("batch", [256, 4096])
-def test_kinds_matrix(one_chip, batch):
+def test_kinds_matrix(one_chip, monkeypatch, batch, orientation):
+    """Every fused kind and output width through both orientations of the
+    kernel (these single columns take the rows in the lanes; an OCCURS of
+    128 or more of any of them takes the row tiles)."""
+    if orientation == "row_tiles":
+        monkeypatch.setattr(pallas_tpu, "LANE_FILL_MIN", 1)
     covered = set()
     for encoding in (Encoding.EBCDIC, Encoding.ASCII):
         decoder = ColumnarDecoder(
@@ -283,34 +332,77 @@ def test_kinds_matrix(one_chip, batch):
         fused = pallas_tpu.build_fused_decode(
             groups, decoder.plan.max_extent, interpret=False)
         assert fused.interpret is False
+        lanes = orientation == "rows_in_lanes"
+        assert fused.rows_in_lanes == (len(groups) if lanes else 0)
         compiled = compile_on(one_chip, fused, batch,
                               decoder.plan.max_extent)
-        assert KERNEL in compiled.as_text()
+        assert kernel_calls(compiled.as_text()) == (
+            (0, 1) if lanes else (1, 0))
     assert covered == {
         (kind, out)
         for kind in ("binary", "bcd", "display_ebcdic", "display_ascii")
         for out in ("i32", "i64", "wide")}
 
 
+def test_program_of_both_orientations(one_chip):
+    """An OCCURS 2000 group between a single column and three irregular
+    ones: one row-tile kernel and one rows-in-lanes kernel in one
+    program, at the batch an exp3 read launches."""
+    groups = [
+        pallas_tpu.StridedGroup([3], 5, "bcd"),
+        pallas_tpu.StridedGroup([40 + 6 * k for k in range(2000)], 4,
+                                "binary", signed=True),
+        pallas_tpu.StridedGroup([10, 21, 29], 8, "binary", out="i64")]
+    fused = pallas_tpu.build_fused_decode(groups, 12040, interpret=False)
+    assert fused.rows_in_lanes == 2
+    compiled = compile_on(one_chip, fused, 8192, 12040)
+    assert kernel_calls(compiled.as_text()) == (1, 1)
+
+
+def test_rows_in_lanes_at_the_vector_memory_budget(one_chip):
+    """The most a row that one rows-in-lanes call may read and write
+    (pallas_tpu.LANE_ROW_BYTES_MAX, double-buffered at LANE_TILE rows a
+    step) fits the chip's vector memory; one column more is a second
+    call. 38-digit DISPLAY: 38 bytes in, eleven 4-byte planes out."""
+    cost = 38 + 4 * 11
+    count = pallas_tpu.LANE_ROW_BYTES_MAX // cost
+    assert count < pallas_tpu.LANE_FILL_MIN
+
+    def group(first, columns):
+        return pallas_tpu.StridedGroup(
+            [first + 40 * k for k in range(columns)], 38, "display_ebcdic",
+            out="wide", signed=True)
+
+    fused = pallas_tpu.build_fused_decode(
+        [group(0, count)], 40 * count, interpret=False)
+    compiled = compile_on(one_chip, fused, 65536, 40 * count)
+    assert kernel_calls(compiled.as_text()) == (0, 1)
+    fused = pallas_tpu.build_fused_decode(
+        [group(0, count), group(40 * count, 1)], 40 * count + 40,
+        interpret=False)
+    compiled = compile_on(one_chip, fused, 65536, 40 * count + 40)
+    assert kernel_calls(compiled.as_text()) == (0, 2)
+
+
 def test_exp1_decode_pallas_whole_program(one_chip, mosaic):
     """Every kernel kind at irregular offsets, strings and floats beside
-    them, at the batch a big exp1 read launches. The slow one (about a
-    minute and a half): exp1's kernel unrolls 59 groups. No group on the
-    XLA route keeps a gather by the slice limit (two string groups and
-    two float groups, one or two adjacent columns each); the gathers that
-    are left feed the kernel its irregular numerics (`cobrix.planes`,
-    pallas_tpu._byte_planes) and carry no group's scope."""
+    them, at the batch a big exp1 read launches. The slow one (about
+    three quarters of a minute): exp1's kernel holds 61 groups, each one
+    loop over its columns. No gather is left anywhere in the program: no
+    group on the XLA route keeps one by the slice limit (two string
+    groups and two float groups, one or two adjacent columns each), and
+    the kernel's 61 narrow groups (15 columns at most) read their bytes
+    from the transposed record matrix (`cobrix.planes`), where a field's
+    bytes are adjacent leading indices whatever its offset."""
     decoder = ColumnarDecoder(parse_copybook(EXP1_COPYBOOK),
                               backend="pallas")
     batch = full_block(decoder)
     assert batch == 65536
     fn = decoder.build_jax_decode_fn()
-    assert fn.device_groups == {"fused": 61, "sliced": 4, "gathered": 0}
+    assert fn.device_groups == {"fused": 61, "fused_rows_in_lanes": 61,
+                                "sliced": 4, "gathered": 0}
     compiled = compile_on(one_chip, fn, batch, decoder.plan.max_extent)
     text = compiled.as_text()
-    assert KERNEL in text
-    for line in text.splitlines():
-        if GATHER in line:
-            assert "cobrix.group." not in line, line
-            assert "cobrix.lookup." not in line, line
+    assert kernel_calls(text) == (0, 1)
+    assert GATHER not in text
 
