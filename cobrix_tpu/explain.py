@@ -282,7 +282,7 @@ def _copybook_summary(copybook, plan) -> dict:
 
 
 def _execution_plan(params, files: List[str], total_bytes: int,
-                    backend: str, hosts: int) -> dict:
+                    backend: str, hosts: int, copybook=None) -> dict:
     mode = ("variable-length" if params.needs_var_len_reader
             else "fixed-length")
     plan = {
@@ -307,6 +307,23 @@ def _execution_plan(params, files: List[str], total_bytes: int,
         plan["chunking"] = "sparse-index driven"
     if params.cache_dir:
         plan["cache_dir"] = params.cache_dir
+    if copybook is not None and mode == "variable-length":
+        from .reader.var_len_reader import variable_occurs_route
+
+        # variable_size_occurs: batched through the plan's regions, or
+        # walked record by record, and why
+        route = variable_occurs_route(copybook, params)
+        if route is not None:
+            plan["variable_occurs"] = route["route"]
+            if route["reason"]:
+                plan["variable_occurs_reason"] = route["reason"]
+            regions = [f"{r['array']}[{r['min']}..{r['max']}]x"
+                       f"{r['element_size']}B@{r['start']}"
+                       + (f" in {active}" if active else "")
+                       for active, rs in route["regions"].items()
+                       for r in rs]
+            if regions:
+                plan["variable_regions"] = regions
     return plan
 
 
@@ -376,7 +393,8 @@ def explain(copybook: Optional[str] = None,
         copybook_summary=_copybook_summary(copybook_obj, plan),
         fields=plan.describe(),
         groups=plan.group_summary(),
-        plan=_execution_plan(params, files, total_bytes, backend, hosts),
+        plan=_execution_plan(params, files, total_bytes, backend, hosts,
+                             copybook=copybook_obj),
         cache_planes=_cache_planes(dict(scope.stats), None,
                                    params.cache_dir),
         pushdown=describe_pushdown(copybook_obj, params),
@@ -401,7 +419,7 @@ def build_scan_report(params, files: List[str], data,
         fields=plan.describe(),
         groups=plan.group_summary(),
         plan=_execution_plan(params, files, metrics.bytes_read, backend,
-                             metrics.hosts),
+                             metrics.hosts, copybook=copybook_obj),
         cache_planes=_cache_planes(metrics.plan_cache, metrics.io,
                                    params.cache_dir),
         data=data,

@@ -293,18 +293,22 @@ def _clone_plan(plan: FieldPlan) -> FieldPlan:
         ascii_charset=plan.ascii_charset,
         is_utf16_big_endian=plan.is_utf16_big_endian,
         floating_point_format=plan.floating_point_format,
+        regions=plan.regions,
+        row_path_reason=plan.row_path_reason,
     )
 
 
 def cached_compile_plan(copybook, active_segment: Optional[str] = None,
-                        select: Optional[Sequence[str]] = None) -> FieldPlan:
+                        select: Optional[Sequence[str]] = None,
+                        variable_size_occurs: bool = False) -> FieldPlan:
     """compile_plan with a bounded identity-keyed LRU. The key holds a
     strong reference to the copybook, so an id() can never be recycled
     into a false hit while the entry lives; with the parse cache deduping
     copybooks by fingerprint, repeated scans key to the same object."""
     key = (id(copybook),
            active_segment.upper() if active_segment else None,
-           tuple(select) if select else None)
+           tuple(select) if select else None,
+           bool(variable_size_occurs))
     with _lock:
         entry = _PLAN_LRU.get(key)
         if entry is not None and entry[0] is copybook:
@@ -312,7 +316,8 @@ def cached_compile_plan(copybook, active_segment: Optional[str] = None,
             _bump("plan_hits")
             return _clone_plan(entry[1])
         _bump("plan_misses")
-    plan = compile_plan(copybook, active_segment, select=select)
+    plan = compile_plan(copybook, active_segment, select=select,
+                        variable_size_occurs=variable_size_occurs)
     with _lock:
         _PLAN_LRU[key] = (copybook, plan)
         while len(_PLAN_LRU) > _PLAN_CAP:

@@ -17,9 +17,20 @@ Variable layouts are handled statically where possible:
 - REDEFINES: multiple columns over the same offsets (decode is read-only).
 - Segment redefines: columns are tagged with their segment group; row
   materialization nulls inactive segments.
-- variable_size_occurs=true layouts are record-dependent; those fall back to
-  the host extractor (reader.extractors), like >18-digit arbitrary-precision
-  corner cases fall back to the scalar oracle.
+- variable_size_occurs=true: an OCCURS DEPENDING ON array takes
+  `count x element` bytes and everything behind it moves. The plan stays the
+  static max-size layout and names each such array as a *variable region*
+  (`FieldPlan.regions`): the decoders read each region's count from the
+  packed rows and move the bytes behind it to where the static layout has
+  them (ops/expand.py), after which the one static program applies. Batched:
+  any number of regions outside other arrays, each with an integral
+  COMP / COMP-3 / DISPLAY dependee earlier in the record (in the same segment
+  redefine, where there is one), plain REDEFINES before or behind them.
+  Walked record by record on the host (reader.extractors), with the reason
+  in `FieldPlan.row_path_reason`: a variable array inside another array or
+  under a plain REDEFINES, a dependee that is out of reach (another segment
+  redefine, inside an array, behind its array), of a string codec or with
+  handlers.
 """
 from __future__ import annotations
 
@@ -88,6 +99,43 @@ class Gate:
     elem_index: int
 
 
+@dataclass(frozen=True)
+class VariableRegion:
+    """An OCCURS DEPENDING ON array under `variable_size_occurs` that lies
+    in no other array: in the file it takes `count x element_size` bytes,
+    in the plan's static layout `max_size x element_size` from `start`.
+    The bytes from its compact end up to `scope_end` (the end of the
+    enclosing segment redefine, which keeps its static size in the walk;
+    None: the end of the record) move right by
+    `(max_size - count) x element_size`. `depend_col` is the plan column
+    of the dependee, static once the regions before this one are laid
+    out, and `depend_*`, `signed`, `big_endian` what the expansion
+    (ops/expand.py) needs to decode it: `depend_kind` is "binary",
+    "bcd", "display_ebcdic" or "display_ascii". Regions are ordered by
+    `start`."""
+
+    name: str
+    depend_col: int
+    depend_offset: int
+    depend_width: int
+    depend_kind: str
+    signed: bool
+    big_endian: bool
+    start: int
+    element_size: int
+    min_size: int
+    max_size: int
+    scope_end: Optional[int] = None
+
+    @property
+    def end(self) -> int:
+        return self.start + self.max_size * self.element_size
+
+    @property
+    def max_shift(self) -> int:
+        return (self.max_size - self.min_size) * self.element_size
+
+
 @dataclass
 class ColumnSpec:
     """One output column: a primitive leaf at one static OCCURS slot."""
@@ -125,6 +173,11 @@ class FieldPlan:
     ascii_charset: str
     is_utf16_big_endian: bool
     floating_point_format: FloatingPointFormat
+    # variable_size_occurs: the arrays whose size moves what lies behind
+    # them, by offset; and, where the layout cannot be laid to the static
+    # program, why the records are walked instead
+    regions: Tuple[VariableRegion, ...] = ()
+    row_path_reason: Optional[str] = None
 
     def columns_for(self, st: Statement) -> List["ColumnSpec"]:
         return [c for c in self.columns if c.statement is st]
@@ -269,9 +322,36 @@ def _classify(dtype, fp_format: FloatingPointFormat) -> Tuple[Codec, CodecParams
     raise ValueError(f"Unknown usage {usage}")
 
 
+_DEPENDEE_KINDS = {Codec.BINARY: "binary", Codec.BCD: "bcd",
+                   Codec.DISPLAY_NUM: "display_ebcdic",
+                   Codec.DISPLAY_NUM_ASCII: "display_ascii"}
+
+
+def _region_dependee_fault(spec: "ColumnSpec", array_start: int,
+                           segment: Optional[str]) -> Optional[str]:
+    """Why the column `spec` cannot size a variable region that starts at
+    `array_start` under `segment`, or None where it can: the count has to
+    be an integer the batch kernels decode, at a static offset before the
+    array, in every row that holds the array."""
+    if spec.slot_path:
+        return "is inside an array"
+    if spec.statement.depending_on_handlers or not isinstance(
+            spec.dtype, Integral):
+        return "is not an integral number"
+    if spec.codec not in _DEPENDEE_KINDS \
+            or spec.params.scale_factor or spec.params.precision > 18:
+        return "is not a COMP, COMP-3 or DISPLAY number of up to 18 digits"
+    if spec.offset + spec.width > array_start:
+        return "does not lie before its array"
+    if spec.segment is not None and spec.segment != segment:
+        return "lies in another segment redefine"
+    return None
+
+
 def compile_plan(copybook: Copybook,
                  active_segment: Optional[str] = None,
-                 select: Optional[Sequence[str]] = None) -> FieldPlan:
+                 select: Optional[Sequence[str]] = None,
+                 variable_size_occurs: bool = False) -> FieldPlan:
     """Flatten the AST into columns. `active_segment`: compile only columns
     visible when that segment redefine is active (plus common columns);
     None compiles everything (single-segment / fixed-length files).
@@ -291,6 +371,42 @@ def compile_plan(copybook: Copybook,
            {transform_identifier(str(s).strip()).upper() for s in select})
     # dependee statement name -> column index of its first compiled slot
     dependee_cols: Dict[str, int] = {}
+    regions: List[VariableRegion] = []
+    row_path_reasons: List[str] = []
+
+    def note_variable_array(st: Statement, offset: int, in_array: bool,
+                            overlaid: bool, segment: Optional[str],
+                            scope_end: Optional[int]) -> None:
+        """A DEPENDING ON array under variable_size_occurs, met at
+        `offset` of the static layout: a region, or a reason to walk."""
+        if in_array:
+            row_path_reasons.append(
+                f"{st.name} is a variable array inside another array")
+            return
+        if overlaid:
+            row_path_reasons.append(
+                f"{st.name} is a variable array under a REDEFINES")
+            return
+        col = dependee_cols.get(st.depending_on)
+        if col is None:
+            row_path_reasons.append(
+                f"{st.name} depends on {st.depending_on}, which the "
+                "record does not hold before it")
+            return
+        dep = columns[col]
+        fault = _region_dependee_fault(dep, offset, segment)
+        if fault is not None:
+            row_path_reasons.append(
+                f"{st.name} depends on {st.depending_on}, which {fault}")
+            return
+        regions.append(VariableRegion(
+            name=st.name, depend_col=col, depend_offset=dep.offset,
+            depend_width=dep.width, depend_kind=_DEPENDEE_KINDS[dep.codec],
+            signed=dep.params.signed, big_endian=dep.params.big_endian,
+            start=offset,
+            element_size=st.binary_properties.data_size,
+            min_size=st.array_min_size, max_size=st.array_max_size,
+            scope_end=scope_end))
 
     def resolve_gate(st: Statement, elem_index: int) -> Optional[Gate]:
         if st.depending_on is None:
@@ -329,17 +445,36 @@ def compile_plan(copybook: Copybook,
 
     def walk_children(group: Group, path: Tuple[str, ...], group_offset: int,
                       slot_path: Tuple[int, ...], gates: Tuple[Gate, ...],
-                      segment: Optional[str]) -> None:
+                      segment: Optional[str], overlaid: bool = False,
+                      scope_end: Optional[int] = None) -> None:
         for st in group.children:
             rel = st.binary_properties.offset - group.binary_properties.offset
             st_offset = group_offset + rel
+            # the walk gives a member of a REDEFINES its static size,
+            # whatever it holds: a variable array there moves nothing
+            # behind the member, and moving bytes inside it would spoil
+            # what the other members read (segment redefines apart: only
+            # the active one is compiled)
+            member = st.redefines is not None or st.is_redefined
+            if variable_size_occurs and st.is_array \
+                    and st.depending_on is not None:
+                if slot_path == () or not row_path_reasons:
+                    note_variable_array(
+                        st, st_offset, bool(slot_path),
+                        overlaid or (member and not (
+                            isinstance(st, Group)
+                            and st.is_segment_redefine)),
+                        segment, scope_end)
             if isinstance(st, Group):
-                seg = segment
+                seg, over, scope = segment, overlaid, scope_end
                 if st.is_segment_redefine:
                     if (active_segment is not None
                             and st.name.upper() != active_segment.upper()):
                         continue
                     seg = st.name
+                    scope = st_offset + st.binary_properties.data_size
+                elif member:
+                    over = True
                 if st.is_array:
                     stride = st.binary_properties.data_size
                     for k in range(st.array_max_size):
@@ -347,10 +482,11 @@ def compile_plan(copybook: Copybook,
                         new_gates = gates + ((gate,) if gate else ())
                         walk_children(st, path + (st.name,),
                                       st_offset + k * stride,
-                                      slot_path + (k,), new_gates, seg)
+                                      slot_path + (k,), new_gates, seg,
+                                      over, scope)
                 else:
                     walk_children(st, path + (st.name,), st_offset,
-                                  slot_path, gates, seg)
+                                  slot_path, gates, seg, over, scope)
             else:
                 if st.is_array:
                     stride = st.binary_properties.data_size
@@ -384,6 +520,14 @@ def compile_plan(copybook: Copybook,
             group_map[key] = ColumnGroup(codec=c.codec, width=c.width)
         group_map[key].columns.append(c)
 
+    if active_segment is None:
+        # every segment redefine is compiled, over the same bytes, for
+        # rows in which none is active (their columns come out null): a
+        # region inside one moves nothing there. The readers decode a
+        # multisegment file with regions segment by segment, each with
+        # the plan of its own redefine
+        regions = [r for r in regions if r.scope_end is None
+                   and columns[r.depend_col].segment is None]
     return FieldPlan(
         record_size=copybook.record_size,
         columns=columns,
@@ -393,6 +537,10 @@ def compile_plan(copybook: Copybook,
         ascii_charset=copybook.ascii_charset,
         is_utf16_big_endian=copybook.is_utf16_big_endian,
         floating_point_format=copybook.floating_point_format,
+        regions=(() if row_path_reasons
+                 else tuple(sorted(regions, key=lambda r: r.start))),
+        row_path_reason=(row_path_reasons[0] if row_path_reasons
+                         else None),
     )
 
 

@@ -261,7 +261,13 @@ class DeviceStats:
     columnar.ColumnarDecoder.decode_raw), and for a device aggregate
     (`query_*`, present only then): its chunks, those of them the host
     answered, the rows scanned and passed by the predicate, the groups
-    of the result.
+    of the result; and for a read of variable-size OCCURS records
+    (`odo_*`, present only then; ops/expand.py): the regions of the plans
+    it ran, the records laid to the static layout in batches
+    (`odo_records`), the bytes their shifts moved them by in all
+    (`odo_shifted_bytes`: with `h2d_bytes`, what an expansion on the host
+    would have sent more), and the records the row path walked instead
+    (`odo_fallback_records`).
     The record a caller needs to tell a read that used the chip from one
     that only says so. Shared like PassCounters: scan threads reach it
     through the ObsContext."""
@@ -289,6 +295,12 @@ class DeviceStats:
         self.query_rows_scanned = 0
         self.query_rows_passed = 0
         self.query_groups = 0
+        # variable-size OCCURS records (ColumnarDecoder._note_expansion,
+        # VarLenReader.read_result_columnar)
+        self.odo_regions = 0
+        self.odo_records = 0
+        self.odo_fallback_records = 0
+        self.odo_shifted_bytes = 0
         # route counts of every decode program launched, by identity: a
         # read launches one decoder's program many times
         self._program_groups: Dict[int, Dict[str, int]] = {}
@@ -375,6 +387,29 @@ class DeviceStats:
             self.query_rows_scanned += rows
             self.query_rows_passed += passed
 
+    def note_odo(self, regions: int = 0, records: int = 0,
+                 fallback_records: int = 0, shifted_bytes: int = 0) -> None:
+        """One batch of variable-size OCCURS records: `records` expanded
+        under a plan of `regions` regions, their shifts `shifted_bytes`
+        in all; or `fallback_records` walked by the row path."""
+        with self._lock:
+            self.odo_regions = max(self.odo_regions, regions)
+            self.odo_records += records
+            self.odo_fallback_records += fallback_records
+            self.odo_shifted_bytes += shifted_bytes
+
+    @property
+    def odo(self) -> Dict[str, int]:
+        """The `odo_*` counts, or {} for a read that met no variable-size
+        OCCURS record."""
+        with self._lock:
+            if not (self.odo_records or self.odo_fallback_records):
+                return {}
+            return {"odo_regions": self.odo_regions,
+                    "odo_records": self.odo_records,
+                    "odo_fallback_records": self.odo_fallback_records,
+                    "odo_shifted_bytes": self.odo_shifted_bytes}
+
     @property
     def device_groups(self) -> Dict[str, int]:
         """Kernel groups by the route they took on the device (fused
@@ -389,6 +424,7 @@ class DeviceStats:
 
     def as_dict(self) -> dict:
         device_groups = self.device_groups
+        odo = self.odo
         with self._lock:
             query = {} if not self.query_chunks else {
                 "query_chunks": self.query_chunks,
@@ -398,6 +434,7 @@ class DeviceStats:
                 "query_groups": self.query_groups}
             return {
                 **query,
+                **odo,
                 "device_groups": device_groups,
                 "launches": {f"{b}x{e}": n for (b, e), n
                              in sorted(self.launches.items())},
@@ -670,6 +707,11 @@ class ReadMetrics:
         if self.device_stats.launches:
             out["device_groups"] = self.device_stats.device_groups
             out["device"] = self.device_stats.as_dict()
+        odo = self.device_stats.odo
+        if odo:
+            # on every backend: the host kernels and the row path launch
+            # nothing and still say what they did with the regions
+            out["odo"] = odo
         roof = self.roofline()
         if roof is not None:
             out["roofline"] = roof

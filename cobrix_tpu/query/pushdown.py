@@ -293,8 +293,10 @@ class BoundFilter:
         cache = getattr(reader, "_decoders", None)
         if cache is None:
             cache = reader._seg_decoders
-        return decoder_for_segment(cache, self.copybook, active,
-                                   backend, select=self.filter_select)
+        return decoder_for_segment(
+            cache, self.copybook, active, backend,
+            select=self.filter_select,
+            variable_size_occurs=getattr(reader, "variable_arrays", False))
 
     def mask_matrix(self, reader, active: str, backend: str,
                     matrix: np.ndarray,

@@ -24,7 +24,7 @@ import numpy as np
 
 from .. import native
 from ..copybook.ast import Group, Primitive, Statement
-from ..copybook.datatypes import Integral
+from ..copybook.datatypes import AlphaNumeric, Integral
 from ..obs import fieldcost
 from ..plan.compiler import Codec
 from ..profiling import Stage
@@ -985,8 +985,26 @@ class ArrowBatchBuilder:
         return np.where((v >= st.array_min_size) & (v <= st.array_max_size),
                         v, st.array_max_size)
 
+    def _slots_truncated(self, cols, counts, relevant=None) -> bool:
+        """Whether a row that shows the slot columns `cols` of one OCCURS
+        leaf ends before the last slot it shows: its first `counts`
+        slots (all of them where `counts` is None). A record that is
+        short by its own count alone, as every record of a file of
+        variable-size OCCURS is, is whole."""
+        lengths = self.batch.lengths
+        if lengths is None:
+            return False
+        columns = self.decoder.plan.columns
+        ends = np.asarray([0] + [columns[c].offset + columns[c].width
+                                 for c in cols], dtype=np.int64)
+        trunc = lengths < (ends[-1] if counts is None else ends[counts])
+        if relevant is not None:
+            trunc = trunc & relevant
+        return bool(trunc.any())
+
     def _flat_slot_values(self, st: Primitive, slot_path, max_size: int,
-                          compact_mask=None, compact_rows=None):
+                          compact_mask=None, compact_rows=None,
+                          counts=None):
         """One record-major flat array covering every OCCURS slot of a
         numeric leaf (the slots live in one kernel group; per-slot
         pa.array calls would dominate wide-OCCURS materialization —
@@ -999,9 +1017,11 @@ class ArrowBatchBuilder:
         `compact_mask`/`compact_rows` (decode-once): build values for
         ONLY the visible rows — the caller verified hidden rows are
         nulled at an enclosing struct, where child buffers are invisible.
-        None -> caller uses the per-slot path (strings, wide or
-        dynamic-scale decimals, a truncated visible row, a leaf of
-        another segment arm, slots without a shared plane)."""
+        `counts`: the elements each row shows (DEPENDING ON; None: all).
+        None -> caller uses the per-slot path (strings outside a struct
+        element, wide or dynamic-scale decimals, a row that ends inside
+        an element it shows, a leaf of another segment arm, slots
+        without a shared plane)."""
         pa = _pa()
         pa_type = to_arrow_type(primitive_data_type(st))
         is_decimal = pa.types.is_decimal(pa_type)
@@ -1019,14 +1039,8 @@ class ArrowBatchBuilder:
         if is_decimal and (spec0.params.explicit_decimal
                            or _dyn_scale(spec0)):
             return None  # per-value exponent planes stay per slot
-        lengths = self.batch.lengths
-        if lengths is not None:
-            last = self.decoder.plan.columns[cols[-1]]
-            trunc = lengths < last.offset + last.width
-            if relevant is not None:
-                trunc = trunc & relevant
-            if bool(trunc.any()):
-                return None  # truncated tails own the partial-field rules
+        if self._slots_truncated(cols, counts, relevant):
+            return None  # truncated tails own the partial-field rules
         # bytes not yet decoded (the host backends defer): one fused
         # native pass from the record image into the flat buffers
         arr = self._native_flat_values(
@@ -1121,27 +1135,89 @@ class ArrowBatchBuilder:
             pa_type, len(flat), [vbuf, pa.py_buffer(flat)],
             null_count=nulls)
 
+    def _flat_string_values(self, st: Primitive, slot_path, max_size: int,
+                            counts, keep):
+        """Record-major string values of all OCCURS slots of one string
+        leaf, from the code points of its kernel group seen as
+        [rows x max, width] (the slots are columns of one group matrix),
+        cut to the rows of `keep` (flat bool over rows x max; None:
+        all) before any string is built. None -> the per-slot path (a
+        codec without a code-point matrix, code points past ASCII, a row
+        that ends inside an element it shows)."""
+        batch = self.batch
+        cols = [self.decoder.slot_map.get((id(st), slot_path + (k,)))
+                for k in range(max_size)]
+        if any(c is None for c in cols):
+            return None
+        spec0 = self.decoder.plan.columns[cols[0]]
+        if not batch._vectorizable_string(spec0) or not spec0.width \
+                or self._relevant_of(spec0) is not None \
+                or self._slots_truncated(cols, counts):
+            return None
+        planes = [batch.column_arrays(c).get("char_plane") for c in cols]
+        if any(p is None for p in planes):
+            return None
+        chars, p0 = planes[0]
+        step = planes[1][1] - p0 if len(planes) > 1 else 1
+        stop = p0 + len(planes) * step
+        if (step < 1 or any(p[0] is not chars for p in planes)
+                or [p[1] for p in planes] != list(range(p0, stop, step))):
+            return None
+        mat = chars[:, p0:stop:step].reshape(self.n * max_size,
+                                             spec0.width)
+        if keep is not None:
+            mat = mat[keep]
+        if mat.dtype == np.uint16 and bool((mat > 0x7F).any()):
+            return None  # real UTF-8 encoding: per value
+        return _string_from_codepoints(mat, self.decoder.plan.trimming)
+
     def _flat_struct_values(self, group: Group, slot_path, max_size: int,
-                            compact_mask=None, compact_rows=None):
+                            compact_mask=None, compact_rows=None,
+                            counts=None):
         """Record-major flat StructArray over all OCCURS slots of a group
-        element whose fields are all numeric leaves (exp3's
-        STRATEGY-DETAIL). None -> per-slot path."""
+        element whose fields are numeric leaves (exp3's STRATEGY-DETAIL)
+        and, where no row is hidden by a redefine, strings: each leaf one
+        record-major array of rows x max values. `counts` (DEPENDING
+        ON): the struct holds only the elements each row shows, in
+        record order. None -> per-slot path (nested groups or arrays in
+        the element, and what its leaves' own routes decline)."""
         pa = _pa()
+        keep = indices = None
+        if counts is not None:
+            mask = np.arange(max_size)[None, :] < counts[:, None]
+            if not bool(mask.all()):
+                keep = mask.reshape(-1)
+                indices = pa.array(np.flatnonzero(keep))
         names, children = [], []
+        strings = 0
         for child in group.children:
             if child.is_filler:
                 continue
             if isinstance(child, Group) or child.is_array:
                 return None
-            flat = self._flat_slot_values(child, slot_path, max_size,
-                                          compact_mask=compact_mask,
-                                          compact_rows=compact_rows)
+            if isinstance(child.dtype, AlphaNumeric):
+                if compact_mask is not None:
+                    return None
+                flat = self._flat_string_values(child, slot_path, max_size,
+                                                counts, keep)
+                strings += 1
+            else:
+                flat = self._flat_slot_values(child, slot_path, max_size,
+                                              compact_mask=compact_mask,
+                                              compact_rows=compact_rows,
+                                              counts=counts)
+                if flat is not None and indices is not None:
+                    # one ascending gather: a sequential copy
+                    flat = flat.take(indices)
             if flat is None:
                 return None
             names.append(child.name)
             children.append(flat)
         if not children:
             return None
+        if (strings or counts is not None) \
+                and self.batch.pass_counts is not None:
+            self.batch.pass_counts.incr("struct_list")
         return pa.StructArray.from_arrays(children, names=names)
 
     def _list_array(self, st: Statement, slot_path):
@@ -1268,11 +1344,19 @@ class ArrowBatchBuilder:
                                   out=offsets[1:])
                         return pa.ListArray.from_arrays(pa.array(offsets),
                                                         cflat)
-            if flat is None:
-                flat = (self._flat_struct_values(st, slot_path, max_size)
-                        if isinstance(st, Group)
-                        else self._flat_slot_values(st, slot_path,
-                                                    max_size))
+            if flat is None and isinstance(st, Group):
+                flat = self._flat_struct_values(st, slot_path, max_size,
+                                                counts=counts_probe)
+                if flat is not None and counts_probe is not None:
+                    # the struct's leaves came cut to the elements each
+                    # row shows
+                    offsets = np.zeros(n + 1, dtype=np.int32)
+                    np.cumsum(counts_probe, out=offsets[1:])
+                    return pa.ListArray.from_arrays(pa.array(offsets),
+                                                    flat)
+            elif flat is None:
+                flat = self._flat_slot_values(st, slot_path, max_size,
+                                              counts=counts_probe)
             if flat is not None:
                 if counts_probe is None:
                     # constant-size OCCURS: uniform offsets, zero copies
@@ -1297,9 +1381,9 @@ class ArrowBatchBuilder:
                 offsets = np.zeros(n + 1, dtype=np.int32)
                 np.cumsum(counts, out=offsets[1:])
                 return pa.ListArray.from_arrays(pa.array(offsets), values)
-        # what only the slots can do: strings, nested groups or arrays in
-        # the element, wide or dynamic-scale decimals, truncated tails,
-        # host-fallback columns
+        # what only the slots can do: an OCCURS of strings, nested groups
+        # or arrays in the element, wide or dynamic-scale decimals, a row
+        # that ends inside an element it shows, host-fallback columns
         with Stage("assemble.list.slots", self.stats):
             return self._slot_major_list(st, slot_path, counts_probe)
 

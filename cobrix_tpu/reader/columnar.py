@@ -41,6 +41,7 @@ from .. import native
 from ..obs import context as obs_context
 from ..obs import fieldcost
 from ..ops import batch_np
+from ..ops import expand as odo
 from ..profiling import Stage, annotate
 from ..plan.cache import cached_code_page_lut, cached_compile_plan
 from ..plan.compiler import (Codec, ColumnSpec, FieldPlan,
@@ -196,7 +197,8 @@ def _scatter_outputs(outputs: Dict[int, dict], mask: np.ndarray,
     result = {}
     for col, out in outputs.items():
         plane = out.get("plane")
-        of_plane = () if plane is None else ("plane", "values", "valid")
+        of_plane = ("char_plane",) if plane is None else (
+            "plane", "values", "valid")
         full = {k: _scatter_rows(np.asarray(v), mask, n)
                 for k, v in out.items() if k not in of_plane}
         if plane is not None:
@@ -485,9 +487,14 @@ class DecodedBatch:
     def __init__(self, decoder: "ColumnarDecoder", data: np.ndarray,
                  outputs: Dict[int, dict],
                  lengths: Optional[np.ndarray] = None,
-                 raw_source: Optional[tuple] = None):
+                 raw_source: Optional[tuple] = None,
+                 compact: bool = False):
         self.decoder = decoder
-        self.data = data
+        # `compact`: the rows are as the file holds them and the device
+        # laid them out (variable regions): the host's copy is expanded
+        # when something reads it (truncated tails, host-fallback columns)
+        self._data = data
+        self._compact = compact
         self.n_records = data.shape[0]
         # col index -> {"values","valid","plane","dot_scale","bytes"}
         self._out = outputs
@@ -523,6 +530,14 @@ class DecodedBatch:
         # stages (arrow_out) count on it after the read returned
         self.stage_stats = ctx.device_stats if ctx is not None else None
 
+    @property
+    def data(self) -> np.ndarray:
+        """The packed [n, extent] rows in the plan's static layout."""
+        if self._compact:
+            self._data = self.decoder.expand_host(self._data)[0]
+            self._compact = False
+        return self._data
+
     # -- vectorized access -------------------------------------------------
 
     def column_arrays(self, col: int) -> dict:
@@ -546,7 +561,7 @@ class DecodedBatch:
                 (key, _scatter_rows(np.asarray(arr), part.mask,
                                     self.n_records))
                 for key, arr in part.columns(self.decoder)[col].items()
-                if key != "plane")
+                if key not in ("plane", "char_plane"))
         return out
 
     # -- subset planes (device launches partitioned by redefine) -----------
@@ -679,12 +694,14 @@ class DecodedBatch:
                 slab = self._gather_slab(g)
                 chars = batch_np.transcode_ebcdic(slab, dec.lut)
             for pos, c in enumerate(g.columns):
-                self._out[c.index] = {"bytes": chars[:, pos]}
+                self._out[c.index] = {"bytes": chars[:, pos],
+                                      "char_plane": (chars, pos)}
         else:  # ASCII
             slab = self._gather_slab(g)
             masked = batch_np.mask_ascii(slab)
             for pos, c in enumerate(g.columns):
-                self._out[c.index] = {"bytes": masked[:, pos]}
+                self._out[c.index] = {"bytes": masked[:, pos],
+                                      "char_plane": (masked, pos)}
 
     def _gather_slab(self, g: "_KernelGroup") -> np.ndarray:
         """[n, ncols, width] byte slab for a group, from the packed batch or
@@ -1166,6 +1183,17 @@ DEVICE_BACKENDS = ("jax", "pallas")
 # bounded whatever the shard size and a big read compiles one shape
 DEVICE_BLOCK_BYTES = 128 * 1024 * 1024
 
+# a program that lays variable-size OCCURS records to the static layout
+# (ops/expand.py) holds a launch's rows several times over on the chip:
+# as they came, with the bytes behind each array shifted, expanded, and
+# as the planes the kernel reads. XLA keeps such intermediates in the
+# chip's fast memory beside the kernel's own 96 MiB, and a launch of
+# 65,536 rows of 1,153 B (75 MB a copy) never came back on a TPU v5e
+# where one of 16,384 took 3.2 ms and the two halves alone, at 65,536,
+# 2.3 and 10.6 ms (PERF.md section 6, PR 32): its launches read this
+# share of DEVICE_BLOCK_BYTES
+EXPAND_BLOCK_SHARE = 4
+
 # a device decode_raw with segment row masks launches by redefine where
 # that spares the link at least this many bytes a row, averaged over the
 # batch: (the plan's extent and fetched bytes, less the set's own) times
@@ -1195,7 +1223,8 @@ def validate_backend(backend: str) -> str:
 def decoder_for_segment(cache: Dict[str, "ColumnarDecoder"],
                         copybook: Copybook, active: str,
                         backend: str,
-                        select: Optional[Sequence[str]] = None
+                        select: Optional[Sequence[str]] = None,
+                        variable_size_occurs: bool = False
                         ) -> "ColumnarDecoder":
     """Shared per-(active segment, backend) decoder cache used by both the
     fixed-length and variable-length readers. Locked: the indexed parallel
@@ -1204,6 +1233,8 @@ def decoder_for_segment(cache: Dict[str, "ColumnarDecoder"],
     from ..plan.cache import note_decoder
 
     key = f"{active}|{backend}|{','.join(select) if select else ''}"
+    if variable_size_occurs:
+        key += "|odo"
     dec = cache.get(key)
     if dec is None:
         with _decoder_build_lock:
@@ -1212,7 +1243,8 @@ def decoder_for_segment(cache: Dict[str, "ColumnarDecoder"],
                 note_decoder(hit=False)
                 dec = ColumnarDecoder(
                     copybook, active_segment=active or None, backend=backend,
-                    select=select)
+                    select=select,
+                    variable_size_occurs=variable_size_occurs)
                 cache[key] = dec
                 return dec
     note_decoder(hit=True)
@@ -1223,11 +1255,16 @@ class ColumnarDecoder:
     def __init__(self, copybook: Copybook,
                  active_segment: Optional[str] = None,
                  backend: str = "numpy",
-                 select: Optional[Sequence[str]] = None):
+                 select: Optional[Sequence[str]] = None,
+                 variable_size_occurs: bool = False):
+        """`variable_size_occurs`: the rows this decoder is given hold
+        each DEPENDING ON array at its count's size (`plan.regions`); it
+        lays them to the static layout before it decodes them."""
         self.copybook = copybook
         self.select = tuple(select) if select else None
-        self.plan: FieldPlan = cached_compile_plan(copybook, active_segment,
-                                                   select=self.select)
+        self.plan: FieldPlan = cached_compile_plan(
+            copybook, active_segment, select=self.select,
+            variable_size_occurs=variable_size_occurs)
         self.backend = validate_backend(backend)
         self.options = DecodeOptions.from_copybook(copybook)
         self.non_standard_ascii_charset = (
@@ -1279,6 +1316,17 @@ class ColumnarDecoder:
         self._jax_fn = None
         # programs of group subsets (_program_for), by the groups' ids
         self._set_programs: Dict[tuple, object] = {}
+        # the plan's variable regions (compiler.VariableRegion): rows
+        # given to this decoder are laid to the static layout first
+        self.regions = self.plan.regions
+
+    def expand_host(self, arr: np.ndarray):
+        """(`arr` with every variable region at its maximum size, the
+        counts [n, regions]) by the host kernels: `backend="numpy"`'s
+        pass, and what a device batch's host copy takes when it is read
+        (DecodedBatch.data)."""
+        with Stage("expand"):
+            return odo.expand_rows(np, batch_np, arr, self.regions)
 
     # ------------------------------------------------------------------
 
@@ -1306,12 +1354,40 @@ class ColumnarDecoder:
                                       dtype=np.uint8)
                     padded[:, :arr.shape[1]] = arr
                 arr = padded
-        if self.backend in DEVICE_BACKENDS:
+        counts = None
+        compact = False
+        if self.backend in DEVICE_BACKENDS and self.regions:
+            outputs, counts = self._decode_launches(arr)
+            compact = True
+        elif self.backend in DEVICE_BACKENDS:
             outputs = self._decode_jax(arr)
         else:
+            if self.regions:
+                arr, counts = self.expand_host(arr)
             outputs = self._decode_numpy(arr)
-        self._decode_host_fallback(arr, outputs)
-        return DecodedBatch(self, arr, outputs, lengths=lengths)
+        if counts is not None:
+            lengths = self._note_expansion(arr, counts, lengths)
+        batch = DecodedBatch(self, arr, outputs, lengths=lengths,
+                             compact=compact)
+        if any(g.codec is Codec.HOST_FALLBACK for g in self.kernel_groups):
+            self._decode_host_fallback(batch.data, outputs)
+        return batch
+
+    def _note_expansion(self, arr: np.ndarray, counts: np.ndarray,
+                        lengths: Optional[np.ndarray]):
+        """The rows' lengths in the expanded layout (None stays None:
+        every row reaches its end), and the read's odo counters."""
+        n, extent = arr.shape
+        with Stage("expand"):
+            full = (np.full(n, extent, dtype=np.int64) if lengths is None
+                    else lengths)
+            moved, shifted = odo.expanded_lengths(
+                np, full, counts, self.regions, extent)
+        ctx = obs_context.current()
+        if ctx is not None and ctx.device_stats is not None:
+            ctx.device_stats.note_odo(regions=len(self.regions), records=n,
+                                      shifted_bytes=int(shifted.sum()))
+        return None if lengths is None else moved
 
     def decode_raw(self, data, rec_offsets, rec_lengths,
                    start_offset: int = 0,
@@ -1325,7 +1401,11 @@ class ColumnarDecoder:
         narrow prefix covering the remaining groups is packed. A device
         backend packs every record to the plan's extent and decodes the
         matrix (`decode`), unless the masks let it launch by redefine
-        (below); so does the numpy backend without the native library.
+        (below); so does the numpy backend without the native library,
+        and every backend for a plan with variable regions: the rows
+        are packed compact, each record from its first byte, and
+        `decode` lays them to the static layout (on a device backend
+        the program does, so that they cross the link compact).
 
         `segment_row_masks`: segment-redefine name -> row-visibility mask
         (disjoint: a row has at most one active redefine). On the numpy
@@ -1362,6 +1442,9 @@ class ColumnarDecoder:
                                             start_offset=start_offset)
             return self.decode(batch, lengths=lengths)
 
+        if self.regions:
+            # compact rows to the extent, laid out by `decode`
+            return packed_fallback()
         if self.backend in DEVICE_BACKENDS and segment_row_masks:
             partitioned = self._decode_raw_partitioned(
                 data, rec_offsets, rec_lengths, start_offset,
@@ -1967,7 +2050,12 @@ class ColumnarDecoder:
         fields cover, each byte once however many redefines read it.
         `decode_all.device_groups` counts the groups by route, and
         under `fused_rows_in_lanes` the fused ones of the second
-        orientation. `mesh`: with a multi-device mesh the fused
+        orientation. A decoder of variable-size OCCURS records
+        (`self.regions`, the whole program only) first lays the rows to
+        the static layout under the scope `cobrix.expand`
+        (ops/expand.py), ahead of everything above, and hands the
+        regions' counts back as one more tuple behind the groups'.
+        `mesh`: with a multi-device mesh the fused
         pallas_calls are wrapped in shard_map over the ``data`` axis (GSPMD
         cannot partition a custom call — an unwrapped kernel would force
         an all-gather of the whole batch onto every chip); the non-fused
@@ -2070,8 +2158,17 @@ class ColumnarDecoder:
             return jax.named_scope(
                 "cobrix.group." + g.label.replace("/", "_"))
 
+        regions = self.regions if groups is None else ()
+
         def decode_all(data):
             n = data.shape[0]
+            counts = None
+            if regions:
+                # the rows came compact: every region to its maximum
+                # size first, then the one static program
+                with jax.named_scope("cobrix.expand"):
+                    data, counts = odo.expand_rows(jnp, batch_jax, data,
+                                                   regions)
             outs: List[tuple] = [
                 () if g.codec is Codec.HOST_FALLBACK else None
                 for g in kernel_groups]
@@ -2122,6 +2219,10 @@ class ColumnarDecoder:
                                      else jnp.concatenate(parts, axis=1))
                     outs[gi] = self._run_group_jax(g, slabs[gi], jnp,
                                                    batch_jax)
+            if counts is not None:
+                # behind the groups' tuples: the host reads the rows'
+                # expanded lengths off the counts the device used
+                outs.append((counts,))
             return outs
 
         # which route each group took, known when the program is built
@@ -2168,17 +2269,25 @@ class ColumnarDecoder:
 
     def _device_block(self, n: int, extent: int) -> int:
         """Rows per device launch: the jit bucket for `n`, capped so one
-        launch reads at most DEVICE_BLOCK_BYTES."""
+        launch reads at most DEVICE_BLOCK_BYTES (a share of it where the
+        program expands variable regions: EXPAND_BLOCK_SHARE)."""
+        limit = DEVICE_BLOCK_BYTES // (EXPAND_BLOCK_SHARE if self.regions
+                                       else 1)
         cap = 256
-        while cap * 2 * extent <= DEVICE_BLOCK_BYTES:
+        while cap * 2 * extent <= limit:
             cap *= 2
         return min(self._bucket_size(n), cap)
 
     def _decode_jax(self, arr: np.ndarray) -> Dict[int, dict]:
         """Every row of the packed [n, extent] matrix through the whole
-        program, in blocks of one bucket size (`_device_block`). What
-        decode_raw's row masks can spare the link never comes here: see
-        `_decode_raw_partitioned`."""
+        program (`_decode_launches`). What decode_raw's row masks can
+        spare the link never comes here: see `_decode_raw_partitioned`."""
+        return self._decode_launches(arr)[0]
+
+    def _decode_launches(self, arr: np.ndarray):
+        """Every row of the packed [n, extent] matrix through the whole
+        program, in blocks of one bucket size (`_device_block`): (the
+        columns' outputs, the regions' counts [n, regions] or None)."""
         program = self.device_program()
         n, extent = arr.shape
         block = self._device_block(n, extent)
@@ -2205,8 +2314,10 @@ class ColumnarDecoder:
         merged = self._merge_blocks(parts)
         with Stage("collect"):
             outputs = self.collect_outputs(merged, n)
+            counts = (np.asarray(merged[-1][0])[:n] if self.regions
+                      else None)
         self._commit_device_cost(fc, tok, n)
-        return outputs
+        return outputs, counts
 
     def _commit_device_cost(self, fc, tok, n: int) -> None:
         """The device decode of `n` rows, charged: jitted programs decode
@@ -2287,9 +2398,12 @@ class ColumnarDecoder:
             if g.codec is Codec.HOST_FALLBACK:
                 continue
             if g.codec in _STRING_CODECS:
+                # `char_plane`: the group's [n, columns, width] matrix and
+                # the column's place in it, as `plane` is for numerics
                 chars = np.asarray(out[0])[:n]
                 for pos, c in enumerate(g.columns):
-                    outputs[c.index] = {"bytes": chars[:, pos]}
+                    outputs[c.index] = {"bytes": chars[:, pos],
+                                        "char_plane": (chars, pos)}
             elif g.wide:
                 arrs = [np.asarray(o)[:n] for o in out]
                 self._store_wide(g, outputs, *arrs)

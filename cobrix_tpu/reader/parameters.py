@@ -255,14 +255,18 @@ class ReaderParameters:
     @property
     def supports_fast_framing(self) -> bool:
         """True when whole-shard vectorized RDW framing applies (no custom
-        extractors/parsers, no text mode, no length fields, no variable
-        OCCURS) — also the gate for pipeline auto-splitting, where split
-        granularity is pinned row-identical by the indexed-scan tests."""
+        extractors/parsers, no text mode, no length fields) — also the
+        gate for pipeline auto-splitting, where split granularity is
+        pinned row-identical by the indexed-scan tests. The RDW gives
+        every record's length, so `variable_size_occurs` does not stand
+        in its way: a file of variable-size OCCURS records is framed by
+        the native scanner and cut into index shards like any other RDW
+        file (without RDW its length is the walk: VarOccursRecordExtractor,
+        record by record)."""
         return bool(self.is_record_sequence
                     and not (self.record_extractor
                              or self.record_header_parser
-                             or self.is_text or self.length_field_name
-                             or self.variable_size_occurs))
+                             or self.is_text or self.length_field_name))
 
     @property
     def needs_var_len_reader(self) -> bool:

@@ -164,33 +164,55 @@ def _segment_level_ids_vectorized(segment_ids: Sequence[str],
     return SegLevelColumns(coded=coded), no_match_yet
 
 
-def _has_dynamic_occurs_layout(root: Group) -> bool:
-    """True when a variable-size OCCURS makes later field offsets
-    record-dependent: a DEPENDING ON array followed by any other field, or
-    nested inside another array. A single *trailing* depending array keeps
-    static element offsets and stays on the columnar path."""
-    state = {"after_var_array": False, "dynamic": False}
+def variable_occurs_route(copybook: Copybook,
+                          params: ReaderParameters) -> Optional[dict]:
+    """How a read decodes records whose DEPENDING ON arrays take their
+    count's size (`variable_size_occurs`); None where the option is off
+    or the copybook has no such array. `route` "batched": the plans name
+    the arrays as regions and the decoders lay the rows to the static
+    layout in batches (ops/expand.py), on every backend; "rows": the
+    host walks every record, and `reason` says why (plan/compiler.py
+    says which layouts lay out; the file has to be RDW-framed and not
+    hierarchical). `regions`: active segment redefine ("" for the rows
+    under none) -> its plan's regions."""
+    from ..plan.cache import cached_compile_plan
 
-    def walk(group: Group, in_array: bool) -> None:
-        for st in group.children:
-            if state["dynamic"]:
-                return
-            if state["after_var_array"]:
-                state["dynamic"] = True
-                return
-            is_dep_array = st.is_array and st.depending_on is not None
-            if is_dep_array and in_array:
-                state["dynamic"] = True
-                return
-            if isinstance(st, Group):
-                walk(st, in_array or st.is_array)
-                if state["dynamic"]:
-                    return
-            if is_dep_array:
-                state["after_var_array"] = True
-
-    walk(root, False)
-    return state["dynamic"]
+    if not params.variable_size_occurs or not any(
+            st.is_array and st.depending_on is not None
+            for st in copybook.ast.walk()):
+        return None
+    seg = params.multisegment
+    actives = {""} | set((seg.segment_id_redefine_map or {}).values()
+                         if seg else ())
+    plans = {active: cached_compile_plan(
+                 copybook, active or None, select=params.select,
+                 variable_size_occurs=True)
+             for active in sorted(actives)}
+    reason = None
+    if copybook.is_hierarchical:
+        reason = "a hierarchical copybook is assembled record by record"
+    elif not params.supports_fast_framing:
+        reason = ("without RDW headers (or with a custom framing) a "
+                  "record's length is only known by walking it")
+    else:
+        seg_field = resolve_segment_id_field(params, copybook)
+        for plan in plans.values():
+            if plan.row_path_reason is not None:
+                reason = plan.row_path_reason
+            elif seg_field is not None and any(
+                    r.start <= seg_field.binary_properties.offset
+                    for r in plan.regions):
+                reason = (f"the segment id field {seg_field.name} lies "
+                          "behind a variable array")
+            if reason is not None:
+                break
+    return {"route": "rows" if reason else "batched", "reason": reason,
+            "regions": {active: [
+                {"array": r.name,
+                 "depending_on": plan.columns[r.depend_col].name,
+                 "start": r.start, "element_size": r.element_size,
+                 "min": r.min_size, "max": r.max_size}
+                for r in plan.regions] for active, plan in plans.items()}}
 
 
 class VarLenReader:
@@ -220,13 +242,21 @@ class VarLenReader:
 
         self.pushdown = BoundFilter.build(params.filter, self.copybook,
                                           params)
-        # variable-size OCCURS that shift later fields make the static
-        # columnar plan inapplicable — those records decode on the host.
-        # Walked over the whole record (all 01-level roots in one pass): a
-        # variable array at the end of one root shifts every later root.
-        self.dynamic_occurs_layout = (
-            params.variable_size_occurs
-            and _has_dynamic_occurs_layout(self.copybook.ast))
+        # variable-size OCCURS: the records hold each DEPENDING ON array
+        # at its count's size. The plans name the arrays as regions and
+        # the decoders lay the rows to the static layout in batches
+        # (ops/expand.py); `row_path_reason` says why a layout, or the
+        # way the file is framed, leaves the records to the host's walk
+        # (`dynamic_occurs_layout`)
+        route = variable_occurs_route(self.copybook, params)
+        self.variable_arrays = route is not None
+        self.row_path_reason = route["reason"] if route else None
+
+    @property
+    def dynamic_occurs_layout(self) -> bool:
+        """Whether the records' variable arrays leave them to the host's
+        record walk (`row_path_reason` says why)."""
+        return self.row_path_reason is not None
 
     # -- plumbing ----------------------------------------------------------
 
@@ -335,7 +365,7 @@ class VarLenReader:
         the file image + split arithmetic over the offset arrays instead of
         the per-record Python pass. Returns None when the configuration
         needs the generic generator (custom extractors/parsers, text mode,
-        length fields, variable OCCURS). Split semantics (including the
+        length fields). Split semantics (including the
         invalid-record counting and size-drift quirks) mirror
         sparse_index_generator exactly — pinned by tests against it."""
         from .. import native
@@ -792,15 +822,15 @@ class VarLenReader:
                              backend: str) -> ColumnarDecoder:
         return decoder_for_segment(self._decoders, self.copybook,
                                    active_segment, backend,
-                                   select=self.params.select)
+                                   select=self.params.select,
+                                   variable_size_occurs=self.variable_arrays)
 
     # -- vectorized fast framing (native scan) ------------------------------
 
     @property
     def supports_fast_framing(self) -> bool:
         """True when whole-shard vectorized RDW framing applies (no custom
-        extractors/parsers, no text mode, no length fields, no variable
-        OCCURS)."""
+        extractors/parsers, no text mode, no length fields)."""
         return self.params.supports_fast_framing
 
     def _frame_fast(self, stream: SimpleStream, ledger=None,
@@ -951,7 +981,11 @@ class VarLenReader:
         # (masked groups subset-decode or defer into the fused native
         # assembly, which skips hidden rows in-kernel), so the wide
         # plan's columns never run over the narrow records' bytes.
-        if segment_ids is not None and self.segment_redefine_map:
+        # (not with variable regions: a redefine's region moves bytes
+        # the other redefines read, so each segment's rows go through the
+        # plan of their own redefine, below)
+        if segment_ids is not None and self.segment_redefine_map \
+                and not self.variable_arrays:
             full = self._decoder_for_segment("", backend)
             active_of_uniq = segment_ids.map_uniq(
                 self.segment_redefine_map)
@@ -1089,8 +1123,9 @@ class VarLenReader:
             corrupt_record_field=params.corrupt_record_column,
             diagnostics=ledger)
         if self.copybook.is_hierarchical or self.dynamic_occurs_layout:
-            # hierarchical nesting / per-record offset shifts have no
-            # static columnar plan (reference extractHierarchicalRecord,
+            # hierarchical nesting, and the variable layouts no plan lays
+            # out (`row_path_reason`), have no static columnar plan
+            # (reference extractHierarchicalRecord,
             # RecordExtractors.scala:211; VarOccursRecordExtractor) — but
             # hierarchical VALUES still come from batched kernels: the
             # decode-once batch feeds a span-based Arrow assembly (no
@@ -1134,6 +1169,11 @@ class VarLenReader:
                 ledger=ledger))
             result.rows = rows
             result.n_rows = len(rows)
+            obs = obs_current()
+            if self.variable_arrays and obs is not None \
+                    and obs.device_stats is not None:
+                # the read says what it left to the walk
+                obs.device_stats.note_odo(fallback_records=len(rows))
             if self.pushdown is not None:
                 self.pushdown.filter_result_generic(
                     result, self._output_schema())

@@ -406,3 +406,37 @@ def test_exp1_decode_pallas_whole_program(one_chip, mosaic):
     assert kernel_calls(text) == (0, 1)
     assert GATHER not in text
 
+
+
+def test_tpch_orders_program_with_the_expansion(one_chip, mosaic):
+    """The cell tpch_orders_odo_read's program at the batch a big read
+    launches (a quarter of the other programs' bytes a launch,
+    columnar.EXPAND_BLOCK_SHARE: 16,384 rows): the expansion of the
+    1,153 B rows (three static shifts of the bytes behind the array and
+    a select each), then the one static
+    program: numeric groups of 3 to 28 columns with the rows in the
+    lanes, the strings sliced. No byte is moved by a gather: the only
+    one is the count's own power-of-ten lookup, a value a row."""
+    from benchmark.generators import tpch_orders_nested
+
+    decoder = ColumnarDecoder(parse_copybook(tpch_orders_nested.COPYBOOK),
+                              backend="pallas", variable_size_occurs=True)
+    assert len(decoder.regions) == 1
+    assert decoder.plan.max_extent == tpch_orders_nested.MAX_RECORD == 1153
+    batch = full_block(decoder)
+    assert batch == 16384
+    fn = decoder.build_jax_decode_fn()
+    assert fn.device_groups == {"fused": 4, "fused_rows_in_lanes": 4,
+                                "sliced": 6, "gathered": 0}
+    widest = max(len(g.columns) for g in decoder.kernel_groups
+                 if _pallas_group_spec(g) is not None)
+    assert 16 <= widest < pallas_tpu.LANE_FILL_MIN
+    compiled = compile_on(one_chip, fn, batch, 1153)
+    text = compiled.as_text()
+    assert kernel_calls(text) == (0, 1)
+    assert not [line for line in text.splitlines()
+                if GATHER in line and " u8[" in line.split(GATHER)[0]]
+    mem = compiled.memory_analysis()
+    print(f"orders {batch}x1153: {mem.argument_size_in_bytes} B in, "
+          f"{mem.output_size_in_bytes} B out, "
+          f"{mem.temp_size_in_bytes} B of temporaries")
