@@ -492,8 +492,6 @@ def phase_sharded(device: dict, path: str, n_devices: int) -> None:
     ShardedColumnarDecoder over data_mesh(n_devices) — against the same
     over a one-device mesh: equal aggregates, equal decoded planes, and
     every device of the mesh holding its shard of the input."""
-    import jax
-
     from cobrix_tpu import native
     from cobrix_tpu.parallel import (DeviceAggregator,
                                      ShardedColumnarDecoder, data_mesh)
@@ -533,8 +531,13 @@ def phase_sharded(device: dict, path: str, n_devices: int) -> None:
                       program.interpreted)
         t0 = time.perf_counter()
         outs = compiled.executable(x)
-        planes = [np.asarray(leaf)[:n]
-                  for leaf in jax.tree_util.tree_leaves(outs)]
+        # the columns' arrays as the host reads them: the matrix of the
+        # strings' code points crosses in another shape on one device
+        # than over a mesh (columnar.POINTS_LANES)
+        columns = decoder.collect_outputs(outs, n, points=program.points)
+        planes = [arr for _, out in sorted(columns.items())
+                  for _, arr in sorted(out.items())
+                  if isinstance(arr, np.ndarray)]
         decode_s = time.perf_counter() - t0
         results[nd] = (got, planes)
         say(phase="sharded", mesh_devices=nd, backend="pallas",

@@ -490,13 +490,15 @@ def lookup_segments(lut: np.ndarray):
     return segments
 
 
-def transcode_ebcdic(data: jnp.ndarray, lut_u16: np.ndarray) -> jnp.ndarray:
-    """uint8 bytes -> uint16 code points through `lut_u16`, a table known
+def transcode_ebcdic(data: jnp.ndarray, lut_u16: np.ndarray,
+                     dtype=jnp.uint16) -> jnp.ndarray:
+    """uint8 bytes -> code points through `lut_u16`, a table known
     at trace time, without a gather (the TPU runs one element by
     element): one compare and one select per run of `lookup_segments`,
     on a constant that packs the run's slope and intercept, then one
     multiply-free finish. Element-wise, so XLA fuses it and GSPMD keeps
-    the batch axis; exact for any table of code points."""
+    the batch axis; exact for any table of code points that `dtype`
+    holds (uint8 where the table's largest fits a byte)."""
     x = data.astype(jnp.int32)
     # intercepts lie in [-255, 65535]: biased by 256, slope in bit 0
     packed = None
@@ -505,7 +507,7 @@ def transcode_ebcdic(data: jnp.ndarray, lut_u16: np.ndarray) -> jnp.ndarray:
         packed = const if packed is None else jnp.where(x >= lo, const,
                                                         packed)
     out = (packed >> 1) - 256 + jnp.where((packed & 1) != 0, x, 0)
-    return out.astype(jnp.uint16)
+    return out.astype(dtype)
 
 
 def mask_ascii(data: jnp.ndarray) -> jnp.ndarray:

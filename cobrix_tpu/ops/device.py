@@ -73,14 +73,18 @@ class DeviceProgram:
     shape. `interpreted` says whether the program's Pallas kernel runs
     through the interpreter (None: it has no Pallas kernel);
     `device_groups` how many of the decode's kernel groups took which
-    route ({"fused", "fused_rows_in_lanes", "sliced", "gathered"}:
-    build_jax_decode_fn)."""
+    route ({"fused", "fused_rows_in_lanes", "sliced", "gathered"}, and
+    "points_u8" for a read's program that returns a matrix of code
+    points: build_jax_decode_fn, ColumnarDecoder._read_program); `points` where the decode's EBCDIC string
+    columns lie in that matrix (columnar.StringPoints; None: the
+    program returns none)."""
 
     def __init__(self, fn, interpreted: Optional[bool] = None,
                  device_groups: Optional[Dict[str, int]] = None,
-                 **jit_options):
+                 points=None, **jit_options):
         self.interpreted = interpreted
         self.device_groups = device_groups
+        self.points = points
         self._jit = jax.jit(fn, **jit_options)
         self._lock = threading.Lock()
         self._compiled: Dict[Tuple, CompiledShape] = {}

@@ -59,7 +59,7 @@ from ..query.expr import And, Comparison, Expr, IsIn, Not, Or
 from ..query.pushdown import _inside_array
 from ..reader.columnar import (_FLOAT_CODECS, _NUMERIC_CODECS,
                                ColumnarDecoder, _dyn_scale,
-                               fixed_point_exponent, group_planes)
+                               fixed_point_exponent)
 from ..stats.aggregate import (AggSpec, Factor, average, key_order,
                                scaled, shape_result)
 from .mesh import batch_sharding, data_mesh, pad_batch_to_multiple
@@ -185,7 +185,7 @@ class DeviceAggregator:
                 vmax = jnp.asarray(-jnp.inf, dtype=jnp.float64)
                 for gi, poss in slots:
                     g = groups[gi]
-                    planes = group_planes(g, outs[gi])
+                    planes = decode_all.group_planes(outs, gi)
                     if len(poss) == len(g.columns):
                         sel = slice(None)  # whole group: skip the gather
                     else:
@@ -325,7 +325,7 @@ class DeviceAggregator:
             live = jnp.arange(data.shape[0], dtype=jnp.int32) < n
             col = {}
             for name, (gi, pos) in slots.items():
-                planes = group_planes(groups[gi], outs[gi])
+                planes = decode_all.group_planes(outs, gi)
                 col[name] = (planes.values[:, pos].astype(jnp.int64),
                              planes.valid[:, pos])
             with jax.named_scope("cobrix.filter"):

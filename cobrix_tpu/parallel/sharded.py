@@ -74,13 +74,9 @@ class ShardedColumnarDecoder(ColumnarDecoder):
         if self._jax_fn is None:
             with _decoder_build_lock:
                 if self._jax_fn is None:
-                    from ..ops.device import DeviceProgram
-
                     sharding = batch_sharding(self.mesh)
-                    fn = self.build_jax_decode_fn(mesh=self.mesh)
-                    self._jax_fn = DeviceProgram(
-                        fn, interpreted=fn.interpret,
-                        device_groups=fn.device_groups,
+                    self._jax_fn = self._read_program(
+                        self.build_jax_decode_fn(mesh=self.mesh),
                         in_shardings=sharding,
                         # every output's leading axis is the record axis;
                         # keep the results distributed — transfers gather
@@ -90,7 +86,8 @@ class ShardedColumnarDecoder(ColumnarDecoder):
 
     def _decode_jax(self, arr: np.ndarray) -> Dict[int, dict]:
         x, n = self.put(arr)
-        return self.collect_outputs(self.device_program()(x), n)
+        program = self.device_program()
+        return self.collect_outputs(program(x), n, points=program.points)
 
     def put(self, arr: np.ndarray):
         """Pad `arr` to the mesh bucket and transfer it H2D with the batch
@@ -129,11 +126,9 @@ class ShardedColumnarDecoder(ColumnarDecoder):
                 live = jnp.arange(data.shape[0], dtype=jnp.int32) < n
                 total_valid = jnp.zeros((), dtype=jnp.int32)
                 per_group = {}
-                for g, out in zip(groups, outs):
-                    # wide (uint128-limb) groups carry valid at index 3;
-                    # narrow numeric/float groups at index 1
-                    valid = (out[3] if g.wide and len(out) >= 4
-                             else out[1] if len(out) >= 2 else None)
+                for gi, g in enumerate(groups):
+                    # strings and host-fallback groups have no such plane
+                    valid = decode_all.group_planes(outs, gi).valid
                     if valid is not None and valid.dtype == jnp.bool_:
                         v = (valid & live[:, None]).sum(dtype=jnp.int32)
                         per_group[f"{g.codec.value}_w{g.width}"] = v

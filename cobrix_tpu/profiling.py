@@ -415,12 +415,16 @@ class DeviceStats:
         """Kernel groups by the route they took on the device (fused
         Pallas kernel, and how many of those with the batch's rows in
         the lanes; static slices; XLA gather), summed over the decoders
-        whose programs this read launched."""
+        whose programs this read launched; and, where one of them hands
+        back a matrix of EBCDIC code points, `points_u8`: how many do
+        in 8 bits a code point."""
         with self._lock:
             counted = list(self._program_groups.values())
+        routes = ["fused", "fused_rows_in_lanes", "sliced", "gathered"]
+        if any("points_u8" in groups for groups in counted):
+            routes.append("points_u8")
         return {route: sum(groups.get(route, 0) for groups in counted)
-                for route in ("fused", "fused_rows_in_lanes", "sliced",
-                              "gathered")}
+                for route in routes}
 
     def as_dict(self) -> dict:
         device_groups = self.device_groups

@@ -176,11 +176,13 @@ _LR_TRIM = " \t"
 
 
 def _string_from_codepoints(mat: np.ndarray, trimming: TrimPolicy):
-    """[n, w] code points (uint8 masked ASCII or uint16 LUT output) -> Arrow
-    string array. Requires every code point <= 0x7F so UTF-8 bytes == code
-    points (the caller falls back otherwise); the fixed-width matrix becomes
-    one zero-gather string buffer with uniform offsets, and trimming runs in
-    Arrow's C++ kernels."""
+    """[n, w] code points (masked ASCII bytes, or a LUT's output: uint8
+    where the code page's table fits a byte, uint16 otherwise) -> Arrow
+    string array. Requires every code point <= 0x7F, whatever the dtype,
+    so UTF-8 bytes == code points (the caller tests the values and falls
+    back otherwise); the fixed-width matrix becomes one zero-gather string
+    buffer with uniform offsets, and trimming runs in Arrow's C++
+    kernels."""
     import pyarrow.compute as pc
 
     pa = _pa()
@@ -962,8 +964,9 @@ class ArrowBatchBuilder:
             # whatever value they produce
             mat = mat.copy()
             mat[~relevant] = 0x20
-        if mat.dtype == np.uint16 and bool((mat > 0x7F).any()):
-            # non-ASCII code points need real UTF-8 encoding
+        if bool((mat > 0x7F).any()):
+            # non-ASCII code points need real UTF-8 encoding, 8-bit ones
+            # (Latin-1 from cp037, cp500, cp1047) as much as 16-bit ones
             return self._python_fallback(spec.index, pa_type, relevant)
         return _string_from_codepoints(mat, self.decoder.plan.trimming)
 
@@ -1157,17 +1160,23 @@ class ArrowBatchBuilder:
         planes = [batch.column_arrays(c).get("char_plane") for c in cols]
         if any(p is None for p in planes):
             return None
+        # the slots as evenly spaced runs of one [n, k] matrix of code
+        # points (a kernel group's own, or a device program's)
         chars, p0 = planes[0]
-        step = planes[1][1] - p0 if len(planes) > 1 else 1
+        width = spec0.width
+        step = planes[1][1] - p0 if len(planes) > 1 else width
         stop = p0 + len(planes) * step
-        if (step < 1 or any(p[0] is not chars for p in planes)
+        if (step < width or any(p[0] is not chars for p in planes)
                 or [p[1] for p in planes] != list(range(p0, stop, step))):
             return None
-        mat = chars[:, p0:stop:step].reshape(self.n * max_size,
-                                             spec0.width)
-        if keep is not None:
-            mat = mat[keep]
-        if mat.dtype == np.uint16 and bool((mat > 0x7F).any()):
+        row, item = chars.strides
+        slots = np.lib.stride_tricks.as_strided(
+            chars[:, p0:], (self.n, max_size, width),
+            (row, step * item, item), writeable=False)
+        # one copy either way: the elements kept, or all of them
+        mat = (slots.reshape(self.n * max_size, width) if keep is None
+               else slots[keep.reshape(self.n, max_size)])
+        if bool((mat > 0x7F).any()):
             return None  # real UTF-8 encoding: per value
         return _string_from_codepoints(mat, self.decoder.plan.trimming)
 

@@ -168,6 +168,13 @@ def _ascii_mask_lut() -> np.ndarray:
     return lut
 
 
+@functools.lru_cache(maxsize=1)
+def _identity_lut() -> np.ndarray:
+    """The table that leaves a code point as it is: what the native
+    string kernel takes for bytes a device has already looked up."""
+    return np.arange(256, dtype=np.uint16)
+
+
 def _scatter_rows(arr: np.ndarray, mask: np.ndarray, n: int) -> np.ndarray:
     """Expand a subset-row kernel output back to full batch length: rows
     outside `mask` are zeros (valid=False for bool planes)."""
@@ -416,15 +423,92 @@ def _groups_extent(groups) -> int:
                 for g in groups if len(g.columns)), default=1)
 
 
+def _group_pieces(g: _KernelGroup) -> Optional[list]:
+    """How a group on the XLA route reads its bytes: its columns as runs
+    in arithmetic progression, each one static slice (`width` of them
+    for a run with other bytes between its columns), or None where that
+    takes more than SLICE_PIECES_MAX slices and the gather stays."""
+    pieces = _slice_pieces(g.offsets, g.width)
+    slices = sum(1 if _dense(piece, g.width) else g.width
+                 for piece in pieces)
+    return pieces if slices <= SLICE_PIECES_MAX else None
+
+
+class StringPoints(NamedTuple):
+    """Where the EBCDIC string columns of one device program lie in
+    `points`, the one [rows, width] matrix of code points the program
+    hands back for them all (build_jax_decode_fn): first `spans`, the
+    record's byte ranges that groups of adjacent columns cover, merged
+    where fields touch or overlap (each byte once however many redefines
+    read it) and laid side by side; behind them `blocks`, one
+    (group index, run of columns or None for a gathered slab) a group
+    with other bytes between its columns, the group's columns in their
+    order. All of it is known from the plan."""
+
+    spans: list
+    blocks: list
+    # group index -> [(position, columns)] runs, in the group's order
+    reads: dict
+    # column index -> (position of its first code point, width)
+    columns: dict
+    width: int
+    # of a code point (ColumnarDecoder.points_dtype)
+    dtype: type
+    # which of the program's tuples holds the matrix
+    index: int
+
+    @property
+    def row_bytes(self) -> int:
+        return self.width * np.dtype(self.dtype).itemsize
+
+
+def _string_points(groups, dtype) -> Optional[StringPoints]:
+    """The StringPoints of a program over `groups` (None: it has no
+    EBCDIC string column). A group whose columns all lie side by side
+    goes into the merged spans; one with other bytes between columns
+    keeps its columns together, in their order, behind the spans: the
+    slots of an OCCURS are then evenly spaced in `points` as they were
+    in the group's own slab."""
+    pieces_of = {gi: _group_pieces(g) for gi, g in enumerate(groups)
+                 if g.codec is Codec.EBCDIC_STRING}
+    if not pieces_of:
+        return None
+    spanned = {gi for gi, pieces in pieces_of.items() if pieces
+               and all(_dense(piece, groups[gi].width) for piece in pieces)}
+    spans = _merged_spans(
+        (piece[0], piece[0] + piece[1] * groups[gi].width)
+        for gi in spanned for piece in pieces_of[gi])
+    cursor = sum(hi - lo for lo, hi in spans)
+    blocks, reads, columns = [], {}, {}
+    for gi, pieces in pieces_of.items():
+        g = groups[gi]
+        reads[gi] = []
+        for piece in pieces or [None]:
+            count = len(g.columns) if piece is None else piece[1]
+            if gi in spanned:
+                at = packed_position(spans, piece[0])
+            else:
+                at = cursor
+                blocks.append((gi, piece))
+                cursor += count * g.width
+            reads[gi].append((at, count))
+        starts = (first + k * g.width for first, count in reads[gi]
+                  for k in range(count))
+        for c, start in zip(g.columns, starts):
+            columns[c.index] = (start, g.width)
+    return StringPoints(spans, blocks, reads, columns, cursor, dtype,
+                        len(groups))
+
+
 def _fetched_bytes(g: _KernelGroup) -> int:
-    """Bytes a row that the device program hands back for `g`, from the
-    plan alone (the dtypes of `_run_group_jax`'s tuples)."""
-    if g.codec is Codec.HOST_FALLBACK:
+    """Bytes a row that the device program hands back in `g`'s own
+    tuple, from the plan alone (the dtypes of `_run_group_jax`'s
+    tuples). EBCDIC strings come back in the program's one matrix
+    (StringPoints.row_bytes), not here."""
+    if g.codec is Codec.HOST_FALLBACK or g.codec is Codec.EBCDIC_STRING:
         return 0
     if g.codec in _STRING_CODECS:
-        # EBCDIC comes back as uint16 code points
-        return len(g.columns) * g.width * (
-            2 if g.codec is Codec.EBCDIC_STRING else 1)
+        return len(g.columns) * g.width
     if g.wide:
         cell = 8 + 8 + 1 + 1
     elif g.codec in (Codec.DOUBLE_IBM, Codec.DOUBLE_IEEE):
@@ -501,7 +585,9 @@ class DecodedBatch:
         self._str_cache: Dict[int, List[str]] = {}
         self._col_cache: Dict[int, list] = {}
         self._maker_cache: Dict[tuple, object] = {}
-        self._arrow_str_cache: Dict[int, tuple] = {}  # id(group) -> (masks, buffers)
+        # id(group) -> (masks, buffers); (id(matrix), slots) -> (columns,
+        # masks, buffers) for a device's code points (_point_string_buffers)
+        self._arrow_str_cache: Dict[object, tuple] = {}
         self._arrow_dec_cache: Dict[int, dict] = {}   # id(group) -> {col: Array|None}
         # fused native assembly caches (arrow_out): scalar col -> pa.Array,
         # and (id(statement), slot_path) -> flat OCCURS values array
@@ -693,15 +779,14 @@ class DecodedBatch:
             if chars is None:  # no native library: numpy gather + LUT
                 slab = self._gather_slab(g)
                 chars = batch_np.transcode_ebcdic(slab, dec.lut)
-            for pos, c in enumerate(g.columns):
-                self._out[c.index] = {"bytes": chars[:, pos],
-                                      "char_plane": (chars, pos)}
         else:  # ASCII
-            slab = self._gather_slab(g)
-            masked = batch_np.mask_ascii(slab)
-            for pos, c in enumerate(g.columns):
-                self._out[c.index] = {"bytes": masked[:, pos],
-                                      "char_plane": (masked, pos)}
+            chars = batch_np.mask_ascii(self._gather_slab(g))
+        # `char_plane`: the group's code points as one [n, columns x
+        # width] matrix and where the column starts in it
+        flat = chars.reshape(len(chars), len(g.columns) * g.width)
+        for pos, c in enumerate(g.columns):
+            self._out[c.index] = {"bytes": chars[:, pos],
+                                  "char_plane": (flat, pos * g.width)}
 
     def _gather_slab(self, g: "_KernelGroup") -> np.ndarray:
         """[n, ncols, width] byte slab for a group, from the packed batch or
@@ -725,7 +810,12 @@ class DecodedBatch:
         `relevant_of(spec)`: optional per-column row-visibility masks
         (decode-once batches skip rows hidden by a null parent struct)."""
         out = self._out.get(spec.index)
-        if out is None or "lazy_string" not in out or not native.available():
+        if out is None or not native.available():
+            return None
+        if "char_plane" in out and spec.codec is Codec.EBCDIC_STRING:
+            return self._point_string_buffers(spec, out["char_plane"][0],
+                                              relevant_of)
+        if "lazy_string" not in out:
             return None
         g, pos = out["lazy_string"]
         cached = self._arrow_str_cache.get(id(g))
@@ -800,6 +890,66 @@ class DecodedBatch:
                 [(g.names, g.width, self.n_records * g.width, g.label)
                  for g in gs],
                 fieldcost.PLANE_ASSEMBLE, self.n_records)
+
+    def _point_string_buffers(self, spec: ColumnSpec, matrix: np.ndarray,
+                              relevant_of=None):
+        """string_arrow_buffers for a column whose code points lie in
+        `matrix` (its `char_plane`), as a device program hands them
+        back (collect_outputs): the same native pass over the fetched
+        matrix, with the columns' places in
+        it for offsets and a table that maps a code point to itself,
+        since the chip has done the lookup: per row, per column, trim
+        and UTF-8 (a code point past 0x7F as two bytes) straight into
+        Arrow buffers, the rows a redefine mask hides as empty strings
+        without being read. One pass builds every scalar column of the
+        matrix, a second the slots of OCCURS arrays, if one is ever
+        asked for. None where the kernel cannot serve: 16-bit code
+        points (cp875 on a device, any page once the host kernels have
+        looked it up), a trim it does not know, a column whose UTF-8
+        outgrew the buffer."""
+        trim_mode = _NATIVE_TRIM_MODES.get(self.decoder.plan.trimming)
+        if matrix.dtype != np.uint8 or trim_mode is None:
+            return None
+        columns = self.decoder.plan.columns
+        slots = bool(spec.slot_path)
+        key = (id(matrix), slots)
+        entry = self._arrow_str_cache.get(key)  # (columns, masks, buffers)
+        cols = entry[0] if entry is not None else [
+            col for col, out in self._out.items()
+            if out.get("char_plane", (None,))[0] is matrix
+            and bool(columns[col].slot_path) == slots]
+        masks = None
+        if relevant_of is not None:
+            masks = [relevant_of(columns[c]) for c in cols]
+            if all(m is None for m in masks):
+                masks = None
+        if entry is None or not _masks_equal(entry[1], masks):
+            # (buffers trimmed for another set of masks are no use)
+            fc = self.field_costs
+            tok = fc.begin() if fc is not None else None
+            res = native.string_cols_arrow_packed(
+                matrix,
+                np.asarray([self._out[c]["char_plane"][1] for c in cols],
+                           dtype=np.int64),
+                np.asarray([columns[c].width for c in cols],
+                           dtype=np.int64),
+                _identity_lut(), trim_mode, col_masks=masks)
+            if res is None:
+                return None
+            if self.pass_counts is not None:
+                self.pass_counts.incr("point_strings")
+            if tok is not None:
+                plan = self.decoder.plan
+                fc.commit_weighted(
+                    tok,
+                    [((plan.cost_name(columns[c]),), columns[c].width,
+                      self.n_records * columns[c].width,
+                      f"{Codec.EBCDIC_STRING.value}/w{columns[c].width}")
+                     for c in cols],
+                    fieldcost.PLANE_ASSEMBLE, self.n_records)
+            entry = self._arrow_str_cache[key] = (
+                cols, masks, dict(zip(cols, res)))
+        return entry[2].get(spec.index)
 
     @staticmethod
     def _group_masks(g: "_KernelGroup", relevant_of):
@@ -1208,6 +1358,16 @@ EXPAND_BLOCK_SHARE = 4
 # 12 % faster; exp3 spares 24 KB
 PARTITION_MIN_SAVED_BYTES = 256
 
+# a program's matrix of code points (StringPoints) leaves the chip as
+# rows of this many: the same row-major bytes, the 128 lanes full. A TPU
+# hands a [rows, 64] uint8 matrix back with the rows minor, and the host
+# then transposes it (0.59 s for the 134 MB of a 2,097,152-row launch
+# beside the 0.19 s its transfer takes); whole rows of 128 arrive as
+# they lie, in the same 0.19 s, and so does a flat array, whose first
+# compile takes 27 s where this takes 6 (PERF.md section 6, PR 33).
+# Every launch bucket (a power of two from 256) divides into such rows
+POINTS_LANES = 128
+
 
 def validate_backend(backend: str) -> str:
     """`backend` if it names a decode backend, else ValueError. A name
@@ -1271,6 +1431,10 @@ class ColumnarDecoder:
             copybook.ascii_charset.lower().replace("_", "-")
             not in ("us-ascii", "ascii"))
         self.lut = cached_code_page_lut(copybook.ebcdic_code_page)
+        # a code point on the link: a byte where the table's largest
+        # fits one (every shipped page but cp875)
+        self.points_dtype = (np.uint8 if int(self.lut.max()) <= 0xFF
+                             else np.uint16)
         self._jax_fn = None
         self.rebuild_groups()
 
@@ -1316,9 +1480,20 @@ class ColumnarDecoder:
         self._jax_fn = None
         # programs of group subsets (_program_for), by the groups' ids
         self._set_programs: Dict[tuple, object] = {}
+        # where each program's EBCDIC strings lie in its matrix of code
+        # points (_points_of), by the groups' ids
+        self._points: Dict[tuple, Optional[StringPoints]] = {}
         # the plan's variable regions (compiler.VariableRegion): rows
         # given to this decoder are laid to the static layout first
         self.regions = self.plan.regions
+
+    def _points_of(self, groups) -> Optional[StringPoints]:
+        """The StringPoints of the program over `groups`, worked out
+        once (a batch with row masks asks for every set's)."""
+        key = tuple(id(g) for g in groups)
+        if key not in self._points:
+            self._points[key] = _string_points(groups, self.points_dtype)
+        return self._points[key]
 
     def expand_host(self, arr: np.ndarray):
         """(`arr` with every variable region at its maximum size, the
@@ -1606,7 +1781,9 @@ class ColumnarDecoder:
                 g for g in self.kernel_groups if g.segment not in masked]))
 
         def link_bytes(extent, groups):
-            return extent + sum(_fetched_bytes(g) for g in groups)
+            points = self._points_of(groups)
+            return (extent + sum(_fetched_bytes(g) for g in groups)
+                    + (points.row_bytes if points is not None else 0))
 
         whole = link_bytes(self.plan.max_extent, self.kernel_groups)
         saved = sum(len(rs.rows) * (whole - link_bytes(rs.extent, rs.groups))
@@ -1663,14 +1840,23 @@ class ColumnarDecoder:
         fc = fieldcost.current()
         tok = fc.begin() if fc is not None else None
         launched = [rs for rs in sets if len(rs.rows)]
+        programs = {rs.name: self._program_for(rs.groups) for rs in launched}
         with annotate("cobrix_decode"):
-            parts = [self._launch_blocks(self._program_for(rs.groups),
+            parts = [self._launch_blocks(programs[rs.name],
                                          packed_blocks(rs), stats)
                      for rs in launched]
-        # set name -> {id(group): its fetched tuple}
-        fetched = {rs.name: dict(zip(map(id, rs.groups),
-                                     self._merge_blocks(p)))
+        # set name -> its fetched tuples: the groups' in the set's order,
+        # behind them the matrix of its strings' code points, if any
+        fetched = {rs.name: self._merge_blocks(p)
                    for rs, p in zip(launched, parts)}
+
+        def tuples_of(rs: _RowSet, groups) -> list:
+            """`groups`' tuples of the set's launch as collect_outputs
+            reads them: the matrix's behind them."""
+            outs = fetched[rs.name]
+            at = {id(g): k for k, g in enumerate(rs.groups)}
+            return [outs[at[id(g)]] for g in groups] + outs[len(rs.groups):]
+
         outputs: Dict[int, dict] = {}
         with Stage("collect"):
             for rs in sets:
@@ -1679,9 +1865,9 @@ class ColumnarDecoder:
                        if g.codec is not Codec.HOST_FALLBACK]
                 sub = None
                 if len(rs.rows):
-                    outs = fetched[rs.name]
                     sub = self.collect_outputs(
-                        [outs[id(g)] for g in own], len(rs.rows), own)
+                        tuples_of(rs, own), len(rs.rows), own,
+                        points=programs[rs.name].points)
                 for g in own:
                     part = _SubsetPlanes(
                         rs.mask, g, None if sub is None else
@@ -1691,21 +1877,39 @@ class ColumnarDecoder:
             # every set decoded the groups no masked redefine owns: their
             # rows go back to their places (one set with every row: as is)
             common = [g for g in sets[0].groups if g not in sets[0].own]
-            whole = []
-            for g in common:
-                pieces = [(rs.rows, fetched[rs.name][id(g)])
+            if len(launched) == 1:
+                (rs,) = launched
+                outputs.update(self.collect_outputs(
+                    tuples_of(rs, common), n, common,
+                    points=programs[rs.name].points))
+            else:
+                by_set = [(rs.rows, tuples_of(rs, common))
                           for rs in launched]
-                if len(pieces) == 1:
-                    whole.append(pieces[0][1])
-                    continue
-                arrays = []
-                for j, first in enumerate(pieces[0][1]):
-                    full = np.empty((n,) + first.shape[1:], first.dtype)
-                    for rows, outs in pieces:
-                        full[rows] = outs[j][:len(rows)]
-                    arrays.append(full)
-                whole.append(tuple(arrays))
-            outputs.update(self.collect_outputs(whole, n, common))
+                whole = []
+                for k in range(len(common)):
+                    arrays = []
+                    for j, first in enumerate(by_set[0][1][k]):
+                        full = np.empty((n,) + first.shape[1:], first.dtype)
+                        for rows, outs in by_set:
+                            full[rows] = outs[k][j][:len(rows)]
+                        arrays.append(full)
+                    whole.append(tuple(arrays))
+                # the common strings' code points, each column from its
+                # place in its set's matrix to its place in one matrix
+                # of the common groups alone
+                points = self._points_of(common)
+                if points is not None:
+                    full = np.empty((n, points.width), points.dtype)
+                    for rs in launched:
+                        matrix = fetched[rs.name][len(rs.groups)][0]
+                        held = programs[rs.name].points.columns
+                        for col, (at, width) in points.columns.items():
+                            src = held[col][0]
+                            full[rs.rows, at:at + width] = matrix[
+                                :len(rs.rows), src:src + width]
+                    whole.append((full,))
+                outputs.update(self.collect_outputs(whole, n, common,
+                                                    points=points))
         self._commit_device_cost(fc, tok, n)
         # the packed matrix covers what the host decodes row by row
         # (HOST_FALLBACK columns); everything else reads the file image
@@ -2044,17 +2248,28 @@ class ColumnarDecoder:
         backend "jax" every group, takes the XLA route: its [n, columns,
         width] bytes are static slices chosen from its offsets (one for a
         run of adjacent columns, `width` strided ones for a run with other
-        bytes between; XLA's gather only past SLICE_PIECES_MAX slices),
-        and EBCDIC strings become code points in one element-wise lookup
+        bytes between; XLA's gather only past SLICE_PIECES_MAX slices).
+        EBCDIC strings become code points in one element-wise lookup
         a program (batch_jax.transcode_ebcdic) over the bytes their
-        fields cover, each byte once however many redefines read it.
+        fields cover, each byte once however many redefines read it,
+        and leave the program as the lookup's own matrix `points`
+        [rows, width] (as rows of POINTS_LANES of the same row-major
+        bytes where the batch divides into them, which a launch
+        bucket does), one more tuple `(points,)` behind the groups':
+        an EBCDIC string group's own tuple is empty, and
+        `decode_all.points` (StringPoints) says where each column lies
+        in the matrix. Its code points are uint8 where the code page's
+        table fits a byte (every shipped page but cp875), uint16
+        otherwise. A program round decode_all that reads a group inside
+        the program takes its planes from `decode_all.group_planes(outs,
+        gi)`, strings included.
         `decode_all.device_groups` counts the groups by route, and
         under `fused_rows_in_lanes` the fused ones of the second
         orientation. A decoder of variable-size OCCURS records
         (`self.regions`, the whole program only) first lays the rows to
         the static layout under the scope `cobrix.expand`
         (ops/expand.py), ahead of everything above, and hands the
-        regions' counts back as one more tuple behind the groups'.
+        regions' counts back as the last tuple, `(counts,)`.
         `mesh`: with a multi-device mesh the fused
         pallas_calls are wrapped in shard_map over the ``data`` axis (GSPMD
         cannot partition a custom call — an unwrapped kernel would force
@@ -2100,31 +2315,22 @@ class ColumnarDecoder:
                         check_vma=False)
 
         # the route of every group the kernel does not take, from what
-        # the plan knows: its columns as runs in arithmetic progression,
-        # each one static slice (None: too many runs, the gather stays)
-        pieces_of: Dict[int, Optional[list]] = {}
-        for gi, g in enumerate(kernel_groups):
-            if gi in fused_indices or g.codec is Codec.HOST_FALLBACK:
-                continue
-            pieces = _slice_pieces(g.offsets, g.width)
-            slices = sum(1 if _dense(piece, g.width) else g.width
-                         for piece in pieces)
-            pieces_of[gi] = pieces if slices <= SLICE_PIECES_MAX else None
+        # the plan knows (None: too many runs, the gather stays)
+        pieces_of: Dict[int, Optional[list]] = {
+            gi: _group_pieces(g) for gi, g in enumerate(kernel_groups)
+            if gi not in fused_indices
+            and g.codec is not Codec.HOST_FALLBACK}
+        # EBCDIC bytes become code points in one lookup a program, each
+        # byte once however many fields (redefines) read it, and leave
+        # the program as that one matrix
+        layout = self._points_of(kernel_groups)
+        # across a mesh every output's leading axis stays the record axis
+        lane_dense = mesh is None or mesh.devices.size == 1
         device_groups = {
             "fused": len(fused_indices),
             "fused_rows_in_lanes": rows_in_lanes,
             "sliced": sum(p is not None for p in pieces_of.values()),
             "gathered": sum(p is None for p in pieces_of.values())}
-        # EBCDIC bytes become code points in one lookup a program: first
-        # the maximal byte ranges that sliced string columns cover without
-        # a gap, each once however many fields (redefines) read it (the
-        # union is never more than their sum), then what single groups
-        # bring (runs of columns with other bytes between, gathered slabs)
-        spans = _merged_spans(
-            (piece[0], piece[0] + piece[1] * g.width)
-            for gi, g in enumerate(kernel_groups)
-            if g.codec is Codec.EBCDIC_STRING and pieces_of.get(gi)
-            for piece in pieces_of[gi] if _dense(piece, g.width))
 
         def piece_bytes(data, piece, width):
             """[n, columns, width] bytes of one run of columns, by one
@@ -2169,67 +2375,72 @@ class ColumnarDecoder:
                 with jax.named_scope("cobrix.expand"):
                     data, counts = odo.expand_rows(jnp, batch_jax, data,
                                                    regions)
-            outs: List[tuple] = [
-                () if g.codec is Codec.HOST_FALLBACK else None
-                for g in kernel_groups]
+            outs: List[tuple] = [()] * len(kernel_groups)
             if fused is not None:
                 for gi, pair in zip(fused_indices, fused(data)):
                     outs[gi] = pair
-            slabs = {}
-            # the lookup's input, [n, k] blocks side by side, and where
-            # each EBCDIC group reads its columns in it: (position, columns)
-            blocks = [jax.lax.slice_in_dim(data, lo, hi, axis=1)
-                      for lo, hi in spans]
-            cursor = sum(hi - lo for lo, hi in spans)
-            reads: Dict[int, list] = {}
             for gi, pieces in pieces_of.items():
                 g = kernel_groups[gi]
+                if g.codec is Codec.EBCDIC_STRING:
+                    # no tuple of its own (as a host-fallback group has
+                    # none): its code points leave in the matrix below
+                    continue
                 with group_scope(g):
-                    if g.codec is not Codec.EBCDIC_STRING:
-                        slabs[gi] = group_bytes(data, g, pieces)
-                        continue
-                    reads[gi] = []
-                    for piece in pieces or [None]:
-                        if piece is not None and _dense(piece, g.width):
-                            reads[gi].append(
-                                (packed_position(spans, piece[0]),
-                                 piece[1]))
-                            continue
+                    outs[gi] = self._run_group_jax(
+                        g, group_bytes(data, g, pieces), jnp, batch_jax)
+            if layout is not None:
+                # the lookup's input, [n, k] blocks side by side
+                blocks = [jax.lax.slice_in_dim(data, lo, hi, axis=1)
+                          for lo, hi in layout.spans]
+                for gi, piece in layout.blocks:
+                    g = kernel_groups[gi]
+                    with group_scope(g):
                         block = (group_bytes(data, g, None) if piece is None
                                  else piece_bytes(data, piece, g.width))
-                        count = block.shape[1]
-                        blocks.append(block.reshape(n, count * g.width))
-                        reads[gi].append((cursor, count))
-                        cursor += count * g.width
-            if blocks:
+                    blocks.append(block.reshape(
+                        n, block.shape[1] * g.width))
                 # the lookup's own scope: its operations are no one group's
                 with jax.named_scope("cobrix.lookup.ebcdic"):
                     points = batch_jax.transcode_ebcdic(
                         blocks[0] if len(blocks) == 1
-                        else jnp.concatenate(blocks, axis=1), lut)
-            for gi in pieces_of:
-                g = kernel_groups[gi]
-                with group_scope(g):
-                    if gi in reads:
-                        parts = [jax.lax.slice_in_dim(
-                            points, at, at + count * g.width,
-                            axis=1).reshape(n, count, g.width)
-                            for at, count in reads[gi]]
-                        slabs[gi] = (parts[0] if len(parts) == 1
-                                     else jnp.concatenate(parts, axis=1))
-                    outs[gi] = self._run_group_jax(g, slabs[gi], jnp,
-                                                   batch_jax)
+                        else jnp.concatenate(blocks, axis=1), lut,
+                        layout.dtype)
+                if lane_dense and (n * layout.width) % POINTS_LANES == 0:
+                    points = points.reshape(-1, POINTS_LANES)
+                outs.append((points,))
             if counts is not None:
                 # behind the groups' tuples: the host reads the rows'
                 # expanded lengths off the counts the device used
                 outs.append((counts,))
             return outs
 
+        def planes_of(outs, gi: int) -> GroupPlanes:
+            """Group `gi`'s planes by name from `outs`, what decode_all
+            returned: what a program built round decode_all (the device
+            aggregate, the sharded statistics) reads a group through.
+            An EBCDIC string group's code points are its columns cut out
+            of the matrix, [rows, columns, width]: slices that cost
+            nothing in the program, and nothing at all unless read."""
+            g = kernel_groups[gi]
+            if layout is None or gi not in layout.reads:
+                return group_planes(g, outs[gi])
+            points = outs[len(kernel_groups)][0].reshape(-1, layout.width)
+            parts = [jax.lax.slice_in_dim(
+                points, at, at + count * g.width,
+                axis=1).reshape(points.shape[0], count, g.width)
+                for at, count in layout.reads[gi]]
+            return GroupPlanes(parts[0] if len(parts) == 1
+                               else jnp.concatenate(parts, axis=1))
+
         # which route each group took, known when the program is built
         decode_all.device_groups = device_groups
         # whether the fused kernel goes through the Pallas interpreter;
         # None when the program holds no Pallas kernel at all
         decode_all.interpret = interpret
+        # where each EBCDIC string column lies in the matrix behind the
+        # groups' tuples (StringPoints); None: the program returns none
+        decode_all.points = layout
+        decode_all.group_planes = planes_of
         return decode_all
 
     def device_program(self):
@@ -2239,13 +2450,24 @@ class ColumnarDecoder:
             # ThreadPoolExecutor workers
             with _decoder_build_lock:
                 if self._jax_fn is None:
-                    from ..ops.device import DeviceProgram
-
-                    fn = self.build_jax_decode_fn()
-                    self._jax_fn = DeviceProgram(
-                        fn, interpreted=fn.interpret,
-                        device_groups=fn.device_groups)
+                    self._jax_fn = self._read_program(
+                        self.build_jax_decode_fn())
         return self._jax_fn
+
+    @staticmethod
+    def _read_program(fn, **jit_options):
+        """The DeviceProgram of a read round `fn` (build_jax_decode_fn):
+        everything `fn` returns is fetched, so its route counts say
+        under `points_u8` whether the matrix of code points, where it
+        returns one, crosses the link in 8 bits a code point."""
+        from ..ops.device import DeviceProgram
+
+        device_groups = dict(fn.device_groups)
+        if fn.points is not None:
+            device_groups["points_u8"] = int(fn.points.dtype is np.uint8)
+        return DeviceProgram(fn, interpreted=fn.interpret,
+                             device_groups=device_groups, points=fn.points,
+                             **jit_options)
 
     def _program_for(self, groups):
         """The DeviceProgram that decodes `groups` and no other (a set
@@ -2259,12 +2481,8 @@ class ColumnarDecoder:
             with _decoder_build_lock:
                 program = self._set_programs.get(key)
                 if program is None:
-                    from ..ops.device import DeviceProgram
-
-                    fn = self.build_jax_decode_fn(groups=groups)
-                    program = self._set_programs[key] = DeviceProgram(
-                        fn, interpreted=fn.interpret,
-                        device_groups=fn.device_groups)
+                    program = self._set_programs[key] = self._read_program(
+                        self.build_jax_decode_fn(groups=groups))
         return program
 
     def _device_block(self, n: int, extent: int) -> int:
@@ -2313,7 +2531,8 @@ class ColumnarDecoder:
             parts = self._launch_blocks(program, blocks(), stats)
         merged = self._merge_blocks(parts)
         with Stage("collect"):
-            outputs = self.collect_outputs(merged, n)
+            outputs = self.collect_outputs(merged, n,
+                                           points=program.points)
             counts = (np.asarray(merged[-1][0])[:n] if self.regions
                       else None)
         self._commit_device_cost(fc, tok, n)
@@ -2355,6 +2574,10 @@ class ColumnarDecoder:
         shape, m, h2d_bytes, device_outs, compiled, built, program = launched
         with Stage("d2h_wait"):
             host_outs = jax.device_get(device_outs)
+        if program.points is not None:
+            # the [rows, width] matrix, whatever shape it crossed in
+            at = program.points.index
+            host_outs[at] = (host_outs[at][0].reshape(shape[0], -1),)
         if stats is not None:
             leaves = jax.tree_util.tree_leaves(device_outs)
             stats.note_launch(
@@ -2386,24 +2609,42 @@ class ColumnarDecoder:
                           for k in range(len(group_outs)))
                     for gi, group_outs in enumerate(parts[0][0])]
 
-    def collect_outputs(self, device_outs, n: int,
-                        groups=None) -> Dict[int, dict]:
-        """Per-group program outputs (device arrays, or already fetched)
-        as host numpy column arrays, dropping batch padding (`n` = real
+    def collect_outputs(self, device_outs, n: int, groups=None,
+                        points: Optional[StringPoints] = None
+                        ) -> Dict[int, dict]:
+        """A program's outputs (device arrays, or already fetched) as
+        host numpy column arrays, dropping batch padding (`n` = real
         record count). `groups`: the groups the program was built from
-        (build_jax_decode_fn), default all."""
+        (build_jax_decode_fn), default all; `device_outs` holds their
+        tuples in that order and, where the program has EBCDIC strings
+        (`points`: its StringPoints), the tuple of its matrix of code
+        points behind them. The matrix is kept as it was fetched and
+        its columns are views of it, no copies: `bytes` a column's
+        [n, width] code points, `char_plane` the matrix and the
+        column's place in it (what `plane` is for numerics): what the
+        one native pass that builds every string column of a batch
+        reads (DecodedBatch.string_arrow_buffers), and the list
+        builder."""
         outputs: Dict[int, dict] = {}
-        for g, out in zip(self.kernel_groups if groups is None else groups,
-                          device_outs):
+        groups = self.kernel_groups if groups is None else groups
+        matrix = None
+        if points is not None:
+            matrix = np.asarray(device_outs[len(groups)][0]).reshape(
+                -1, points.width)[:n]
+        for g, out in zip(groups, device_outs):
             if g.codec is Codec.HOST_FALLBACK:
                 continue
-            if g.codec in _STRING_CODECS:
-                # `char_plane`: the group's [n, columns, width] matrix and
-                # the column's place in it, as `plane` is for numerics
+            if g.codec is Codec.EBCDIC_STRING:
+                for c in g.columns:
+                    at, width = points.columns[c.index]
+                    outputs[c.index] = {"bytes": matrix[:, at:at + width],
+                                        "char_plane": (matrix, at)}
+            elif g.codec in _STRING_CODECS:
                 chars = np.asarray(out[0])[:n]
+                flat = chars.reshape(len(chars), len(g.columns) * g.width)
                 for pos, c in enumerate(g.columns):
                     outputs[c.index] = {"bytes": chars[:, pos],
-                                        "char_plane": (chars, pos)}
+                                        "char_plane": (flat, pos * g.width)}
             elif g.wide:
                 arrs = [np.asarray(o)[:n] for o in out]
                 self._store_wide(g, outputs, *arrs)
@@ -2421,8 +2662,9 @@ class ColumnarDecoder:
 
     def zero_row_outputs(self, g: _KernelGroup) -> Dict[int, dict]:
         """The output dicts of `g`'s columns over no rows at all, in the
-        planes `collect_outputs` would hand out: what a batch without a
-        single row of `g`'s redefine scatters from."""
+        planes `collect_outputs` would hand out (an EBCDIC string's code
+        points as wide as the code page's table asks): what a batch
+        without a single row of `g`'s redefine scatters from."""
         outputs: Dict[int, dict] = {}
         shape = (0, len(g.columns))
         display = g.codec in (Codec.DISPLAY_NUM, Codec.DISPLAY_NUM_ASCII)
@@ -2430,7 +2672,8 @@ class ColumnarDecoder:
         valid = np.zeros(shape, dtype=bool)
         if g.codec in _STRING_CODECS:
             chars = np.zeros(shape + (g.width,), dtype=(
-                np.uint16 if g.codec is Codec.EBCDIC_STRING else np.uint8))
+                self.points_dtype if g.codec is Codec.EBCDIC_STRING
+                else np.uint8))
             for pos, c in enumerate(g.columns):
                 outputs[c.index] = {"bytes": chars[:, pos]}
         elif g.wide:
@@ -2443,8 +2686,9 @@ class ColumnarDecoder:
         return outputs
 
     def _run_group_jax(self, g: _KernelGroup, slab, jnp, batch_jax):
-        """One group's outputs from its [n, ncols, width] slab: bytes, or
-        for an EBCDIC string group the code points already looked up."""
+        """One group's outputs from the [n, ncols, width] slab of its
+        bytes (an EBCDIC string group never comes here: its code points
+        are the program's matrix)."""
         if g.codec is Codec.BINARY:
             signed, big_endian, fits32, wide = g.variant
             if wide:

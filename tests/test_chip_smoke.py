@@ -57,10 +57,14 @@ def test_one_chip_phases_rehearsal(cpu_device, tmp_path, capsys):
     # exp3 launches by redefine, two programs; exp1 brings no masks
     assert exp3["partitioned_batches"] >= 1 and not exp3["declined_batches"]
     assert sum(exp3["set_rows"].values()) == exp3["records"]
+    # (`points_u8`: both set programs, and exp1's one, hand their
+    # strings back as one matrix of 8-bit code points)
     assert exp3["device_groups"] == {"fused": 2, "fused_rows_in_lanes": 0,
-                                     "sliced": 10, "gathered": 0}
+                                     "sliced": 10, "gathered": 0,
+                                     "points_u8": 2}
     assert reads[("read_exp1", "pallas")]["device_groups"] == {
-        "fused": 61, "fused_rows_in_lanes": 61, "sliced": 4, "gathered": 0}
+        "fused": 61, "fused_rows_in_lanes": 61, "sliced": 4, "gathered": 0,
+        "points_u8": 1}
     assert not reads[("read_exp1", "pallas")]["set_rows"]
     assert reads[("read_exp1", "jax")]["interpreted"] is None
     parity = [line for line in lines if line.get("parity") == "ok"]

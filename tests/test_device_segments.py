@@ -195,9 +195,11 @@ def test_exp2_is_the_rules_other_side(backend, forced, tmp_path,
         assert list(stats["launches"]) == ["1024x64"]
 
 
-def test_the_rule_reads_the_plans_widths():
+def test_the_rule_reads_the_plans_widths(monkeypatch):
     """What a batch would spare the link, from the plan and the masks
-    alone: exp3 some 24 KB a row, exp2 about a hundred bytes."""
+    alone: exp3 some 24 KB a row, exp2 next to nothing since its
+    strings come back once, a byte a code point: a 'P' row spares the
+    4 B it is shorter, 4 code points and the COMP column's 5 B."""
     n = 3000
     company = np.arange(n) % 3 == 0
     masks = {"STATIC_DETAILS": company, "CONTACTS": ~company}
@@ -206,6 +208,13 @@ def test_the_rule_reads_the_plans_widths():
             "STATIC_DETAILS", "CONTACTS"]), backend="jax")
         for text in (EXP2_COPYBOOK, EXP3_COPYBOOK))
     assert exp2._segment_sets(masks, n) is None
+    # two rows in three are 'P' rows: 13 B each, 8.67 B a row (it was
+    # 101 B with a uint16 slab a kernel group)
+    monkeypatch.setattr(columnar, "PARTITION_MIN_SAVED_BYTES", 9)
+    assert exp2._segment_sets(masks, n) is None
+    monkeypatch.setattr(columnar, "PARTITION_MIN_SAVED_BYTES", 8)
+    assert len(exp2._segment_sets(masks, n)) == 2
+    monkeypatch.undo()
     by_name = {rs.name: rs for rs in exp3._segment_sets(masks, n)}
     assert by_name["STATIC_DETAILS"].extent == EXP3_EXTENT
     assert by_name["CONTACTS"].extent == EXP3_P_EXTENT

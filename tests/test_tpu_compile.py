@@ -191,11 +191,16 @@ def test_exp2_decode_pallas_full_block(one_chip, mosaic):
     fn = decoder.build_jax_decode_fn()
     assert fn.device_groups == {"fused": 1, "fused_rows_in_lanes": 1,
                                 "sliced": 8, "gathered": 0}
+    # the strings leave as one matrix of 8-bit code points, the union
+    # of both redefines' bytes: 64 + 5 B a row where eight uint16 slabs
+    # and the COMP column took 223
+    assert (fn.points.width, fn.points.dtype) == (64, np.uint8)
     compiled = compile_on(one_chip, fn, batch, 64)
     assert kernel_calls(compiled.as_text()) == (0, 1)
     assert GATHER not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < PARENT_EXP2_TEMP_BYTES
+    assert mem.output_size_in_bytes < batch * 223
     print(f"exp2 {batch}x64: {mem.argument_size_in_bytes} B in, "
           f"{mem.output_size_in_bytes} B out, "
           f"{mem.temp_size_in_bytes} B of temporaries")
@@ -431,6 +436,8 @@ def test_tpch_orders_program_with_the_expansion(one_chip, mosaic):
     widest = max(len(g.columns) for g in decoder.kernel_groups
                  if _pallas_group_spec(g) is not None)
     assert 16 <= widest < pallas_tpu.LANE_FILL_MIN
+    # every string byte of the expanded row, once, 8 bits a code point
+    assert (fn.points.width, fn.points.dtype) == (677, np.uint8)
     compiled = compile_on(one_chip, fn, batch, 1153)
     text = compiled.as_text()
     assert kernel_calls(text) == (0, 1)
