@@ -28,8 +28,8 @@ _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 # operator/test kill switch: every native entry point reports
 # unavailable, exercising the pure-Python fallbacks without touching the
-# .so on disk (tools/asmcheck.py and the in-bench parity assertion ride
-# this). Env var for subprocesses, set_disabled() for in-process tests.
+# .so on disk (tools/asmcheck.py rides this). Env var for subprocesses,
+# set_disabled() for in-process tests.
 # Truthy spellings only: COBRIX_NATIVE_DISABLE=0/false/off keeps native
 # dispatch ON (a bare bool() would silently disable it).
 _disabled = (os.environ.get("COBRIX_NATIVE_DISABLE", "").strip().lower()

@@ -23,6 +23,23 @@ and index counts). What stands in for it here, and on which clock:
   (c) the `StageTimes` busy sums, `ReadMetrics.timings_s` and the
   `obs.Tracer` span where those are attached — on `perf_counter`, whole
   (inclusive) durations, as before.
+- `d2h_wait.ready`, `d2h_wait.copy`: the two stages inside `d2h_wait`
+  at the one fetch site (`ColumnarDecoder._fetch_block`). The copies
+  home are queued first (`jax.copy_to_host_async`, in `d2h_wait`'s own
+  time, as `device_get` alone queues them: they start when the outputs
+  exist). `d2h_wait.ready`, a plain `Stage`, is `jax.block_until_ready`
+  and nothing else: the rest of the H2D copy, the program's run and the
+  waiting thread's wake-up. `d2h_wait.copy` (`LinkCopy`) is what is left
+  of the bytes' way home once the thread is awake. `LinkCopy` feeds the
+  link's own counts in `DeviceStats` besides (`d2h_copy_thread_s`,
+  `d2h_copy_busy_s`): what a fetching thread sat through and how long the
+  link was in use, which a share of the wall cannot say; beside them
+  `d2h_strided_bytes`, the fetched bytes that came home in another order
+  than C's and wait for a transposing copy on the host. `plan_index` has
+  two children the same way, plain stages: `plan_index.scan` (the
+  one-thread header scan of the whole file) and `plan_index.seg_ids`
+  (every record's segment id, for the cut at roots);
+  `VarLenReader.generate_index_fast`.
 - `annotate(name)`: a bare span on the profiler's clock under exactly
   `name` (``cobrix_decode`` round the launch loop: the benchmark's trace
   reduction reads it); ~free when no trace is on.
@@ -175,6 +192,30 @@ class PoolWait(Stage):
         pass
 
 
+class LinkCopy(Stage):
+    """The stage `d2h_wait.copy`: a thread brings a launch's outputs,
+    which exist on the device, home over the link: what is left of the
+    copies once it is awake. Its whole seconds, not split with the read's
+    other threads, go to `DeviceStats.d2h_copy_thread_s` too, and while
+    at least one thread of the read is in here `d2h_copy_busy_s` runs."""
+
+    __slots__ = ()
+
+    def __init__(self, stats=None):
+        super().__init__("d2h_wait.copy", stats)
+
+    def __enter__(self):
+        super().__enter__()
+        if self.stats is not None:
+            self.stats.copy_clock(self._t0, 1)
+        return self
+
+    def _record(self, t0, t1, self_s) -> None:
+        super()._record(t0, t1, self_s)
+        if self.stats is not None:
+            self.stats.copy_clock(t1, -1, t1 - t0)
+
+
 class StageTimes:
     """Per-stage busy-time accumulator shared by pipeline worker threads.
 
@@ -246,7 +287,8 @@ class PassCounters:
 class DeviceStats:
     """What the device decode plane did for one read: program launches by
     padded batch shape, the records they decoded (padding excluded),
-    bytes over the link each way, the seconds spent
+    bytes over the link each way, the link home by its own counts
+    (`d2h_*`, below), the seconds spent
     compiling (`compile_s`, of which `lower_s` tracing and lowering), the
     devices the outputs lived on, what kind of program ran (does it hold
     the fused kernel; was that kernel interpreted; how many kernel groups
@@ -268,6 +310,15 @@ class DeviceStats:
     (`odo_shifted_bytes`: with `h2d_bytes`, what an expansion on the host
     would have sent more), and the records the row path walked instead
     (`odo_fallback_records`).
+    The link home, noted where a launch is fetched (`ColumnarDecoder.
+    _fetch_block`; `LinkCopy`): `d2h_copy_thread_s`, the fetching
+    threads' own `perf_counter` seconds bringing ready outputs home,
+    summed over launches and threads (`d2h_bytes` over it is the rate a
+    fetching thread sees); `d2h_copy_busy_s`, the wall seconds in which
+    at least one thread was copying (the link's busy time; the thread
+    seconds over it, how many fetch at once); `d2h_strided_bytes`, the
+    fetched bytes that did not arrive C-contiguous, which the host has
+    still to transpose in `merge`, `collect` or an `assemble.*` stage.
     The record a caller needs to tell a read that used the chip from one
     that only says so. Shared like PassCounters: scan threads reach it
     through the ObsContext."""
@@ -278,6 +329,12 @@ class DeviceStats:
         self.records = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        # the link home (LinkCopy, note_launch)
+        self.d2h_copy_thread_s = 0.0
+        self.d2h_copy_busy_s = 0.0
+        self.d2h_strided_bytes = 0
+        self._copy_threads = 0
+        self._copy_clock_at = 0.0
         self.compile_s = 0.0
         self.lower_s = 0.0
         self.compiles = 0
@@ -333,14 +390,30 @@ class DeviceStats:
             self.stage_s[name] = self.stage_s.get(name, 0.0) + self_s
             self.stage_n[name] = self.stage_n.get(name, 0) + 1
 
+    def copy_clock(self, now: float, joined: int,
+                   thread_s: float = 0.0) -> None:
+        """`d2h_copy_busy_s` brought up to the instant `now`, at which a
+        thread starts (+1) copying a launch's outputs home or is done
+        (-1) after `thread_s` of it: the seconds in which at least one
+        was, by the arithmetic of `stage_clock`."""
+        with self._lock:
+            if self._copy_threads > 0:
+                self.d2h_copy_busy_s += now - self._copy_clock_at
+            self._copy_clock_at = now
+            self._copy_threads += joined
+            self.d2h_copy_thread_s += thread_s
+
     def note_launch(self, shape: tuple, records: int, h2d_bytes: int,
                     d2h_bytes: int, devices, program, built,
-                    interpreted, device_groups=None) -> None:
+                    interpreted, device_groups=None,
+                    d2h_strided_bytes: int = 0) -> None:
         """One program launch of `records` rows padded to `shape`.
         `program` is the ops.device.CompiledShape that ran, `built`
         whether this launch had to compile it, `device_groups` the route
-        counts of the DeviceProgram it belongs to."""
+        counts of the DeviceProgram it belongs to, `d2h_strided_bytes`
+        those of `d2h_bytes` that came home in another order than C's."""
         with self._lock:
+            self.d2h_strided_bytes += d2h_strided_bytes
             if device_groups is not None:
                 self._program_groups[id(device_groups)] = device_groups
             self.launches[shape] = self.launches.get(shape, 0) + 1
@@ -445,6 +518,9 @@ class DeviceStats:
                 "records": self.records,
                 "h2d_bytes": self.h2d_bytes,
                 "d2h_bytes": self.d2h_bytes,
+                "d2h_copy_thread_s": round(self.d2h_copy_thread_s, 9),
+                "d2h_copy_busy_s": round(self.d2h_copy_busy_s, 9),
+                "d2h_strided_bytes": self.d2h_strided_bytes,
                 "compiles": self.compiles,
                 "compile_s": round(self.compile_s, 3),
                 "lower_s": round(self.lower_s, 3),

@@ -42,7 +42,7 @@ from ..obs import context as obs_context
 from ..obs import fieldcost
 from ..ops import batch_np
 from ..ops import expand as odo
-from ..profiling import Stage, annotate
+from ..profiling import LinkCopy, Stage, annotate
 from ..plan.cache import cached_code_page_lut, cached_compile_plan
 from ..plan.compiler import (Codec, ColumnSpec, FieldPlan,
                              merged_spans as _merged_spans,
@@ -68,6 +68,16 @@ _FLOAT_CODECS = (Codec.FLOAT_IBM, Codec.FLOAT_IEEE, Codec.DOUBLE_IBM,
                  Codec.DOUBLE_IEEE)
 _STRING_CODECS = (Codec.EBCDIC_STRING, Codec.ASCII_STRING, Codec.UTF16_STRING,
                   Codec.HEX_STRING, Codec.RAW_BYTES)
+
+
+def strided_nbytes(leaves) -> int:
+    """The bytes of the fetched numpy `leaves` that did not arrive
+    C-contiguous (a narrow result comes home rows-minor: PERF.md, PR 33),
+    each such leaf whole: what the host has still to transpose before
+    Arrow can hold it. A leaf of one row or one column lies both ways,
+    and numpy says so."""
+    return sum(leaf.nbytes for leaf in leaves
+               if not leaf.flags.c_contiguous)
 
 
 def _is_wide(spec: ColumnSpec) -> bool:
@@ -2572,20 +2582,31 @@ class ColumnarDecoder:
         import jax
 
         shape, m, h2d_bytes, device_outs, compiled, built, program = launched
+        leaves = jax.tree_util.tree_leaves(device_outs)
         with Stage("d2h_wait"):
-            host_outs = jax.device_get(device_outs)
-        if program.points is not None:
-            # the [rows, width] matrix, whatever shape it crossed in
-            at = program.points.index
-            host_outs[at] = (host_outs[at][0].reshape(shape[0], -1),)
+            # the copies home are queued first, as `device_get` alone
+            # would queue them: they start when the outputs exist, not
+            # when this thread has the interpreter lock again. Then the
+            # wait for the outputs (the rest of the H2D copy, the
+            # program's run, this thread's wake-up) and what is left of
+            # the bytes' way home, apart
+            jax.copy_to_host_async(device_outs)
+            with Stage("d2h_wait.ready"):
+                jax.block_until_ready(device_outs)
+            with LinkCopy():
+                host_outs = jax.device_get(device_outs)
         if stats is not None:
-            leaves = jax.tree_util.tree_leaves(device_outs)
             stats.note_launch(
                 shape, m, h2d_bytes,
                 sum(leaf.nbytes for leaf in leaves),
                 {d for leaf in leaves for d in leaf.devices()},
                 compiled, built, program.interpreted,
-                program.device_groups)
+                program.device_groups,
+                strided_nbytes(jax.tree_util.tree_leaves(host_outs)))
+        if program.points is not None:
+            # the [rows, width] matrix, whatever shape it crossed in
+            at = program.points.index
+            host_outs[at] = (host_outs[at][0].reshape(shape[0], -1),)
         return host_outs, m
 
     @classmethod

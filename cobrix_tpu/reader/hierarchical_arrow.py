@@ -23,7 +23,6 @@ the last record at end of stream.
 """
 from __future__ import annotations
 
-import threading
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -31,29 +30,6 @@ import numpy as np
 from ..copybook.ast import Group, Primitive
 from ..copybook.datatypes import SchemaRetentionPolicy
 from .arrow_out import _pa
-
-
-# columnar-vs-row-path assembly counters (observability: the bail rate is
-# a BENCH metric — a silent fall-back to the Python row path would read
-# as "columnar" while costing 5-10x)
-ASSEMBLY_STATS = {"columnar": 0, "bail_multi_sid_parent": 0,
-                  "bail_odo_cross_segment": 0, "bail_schema_shape": 0}
-_STATS_LOCK = threading.Lock()  # bridge handler threads assemble concurrently
-
-
-def _count(key: str) -> None:
-    with _STATS_LOCK:
-        ASSEMBLY_STATS[key] += 1
-
-
-def assembly_stats(reset: bool = False) -> Dict[str, int]:
-    """Snapshot (optionally reset) the columnar/bail counters."""
-    with _STATS_LOCK:
-        out = dict(ASSEMBLY_STATS)
-        if reset:
-            for k in ASSEMBLY_STATS:
-                ASSEMBLY_STATS[k] = 0
-    return out
 
 
 def _depending_crosses_segment(copybook) -> bool:
@@ -127,14 +103,12 @@ def hierarchical_table(batch, segment_names,
         sids_per_name[g.name] = sids_per_name.get(g.name, 0) + 1
     for name, count in sids_per_name.items():
         if count > 1 and name not in root_names and name in parent_child_map:
-            _count("bail_multi_sid_parent")
             return None
 
     # DEPENDING ON arrays whose dependee lives in a different visibility
     # region (shared area vs a segment redefine overlay): bail to the row
     # path, which owns the oracle's cross-record dependee semantics
     if _depending_crosses_segment(copybook):
-        _count("bail_odo_cross_segment")
         return None
 
     # integer-coded segment names: every membership test below runs on an
@@ -349,10 +323,8 @@ def hierarchical_table(batch, segment_names,
 
     target = arrow_schema(output_schema.schema)
     if len(cols) != len(target):
-        _count("bail_schema_shape")
         return None  # shape mismatch: the row path owns it
     arrays = [c.cast(target.field(i).type)
               if c.type != target.field(i).type else c
               for i, c in enumerate(cols)]
-    _count("columnar")
     return pa.Table.from_arrays(arrays, schema=target)

@@ -376,21 +376,24 @@ class VarLenReader:
         adjustment = p.rdw_adjustment
         if p.is_rdw_part_of_record_length:
             adjustment -= 4
-        if p.is_permissive:
-            # same skip decisions as the shard scan so split offsets land
-            # on records the shard framers will actually find; the ledger
-            # here is a throwaway (the decode pass records the incidents)
-            from .recovery import rdw_scan_permissive
+        # every header of the file image read once, on this one thread
+        with Stage("plan_index.scan"):
+            if p.is_permissive:
+                # same skip decisions as the shard scan so split offsets
+                # land on records the shard framers will actually find;
+                # the ledger here is a throwaway (the decode pass records
+                # the incidents)
+                from .recovery import rdw_scan_permissive
 
-            offsets, lengths, _ = rdw_scan_permissive(
-                data, p.is_rdw_big_endian, adjustment,
-                p.file_start_offset, p.file_end_offset,
-                p.record_error_policy, p.resync_window_bytes,
-                p.new_diagnostics())
-        else:
-            offsets, lengths = native.rdw_scan(
-                data, p.is_rdw_big_endian, adjustment,
-                p.file_start_offset, p.file_end_offset)
+                offsets, lengths, _ = rdw_scan_permissive(
+                    data, p.is_rdw_big_endian, adjustment,
+                    p.file_start_offset, p.file_end_offset,
+                    p.record_error_policy, p.resync_window_bytes,
+                    p.new_diagnostics())
+            else:
+                offsets, lengths = native.rdw_scan(
+                    data, p.is_rdw_big_endian, adjustment,
+                    p.file_start_offset, p.file_end_offset)
         n = len(offsets)
         starts = offsets - 4  # RDW header precedes the payload
         # the file-header region is consumed as one counted invalid record
@@ -402,8 +405,9 @@ class VarLenReader:
         root_indices: Optional[np.ndarray] = None
         if is_hierarchical and seg_field is not None:
             root_ids = set(root_segment_id.split(","))
-            sids = self._segment_ids_vectorized(data, offsets, lengths,
-                                                seg_field)
+            with Stage("plan_index.seg_ids"):
+                sids = self._segment_ids_vectorized(data, offsets, lengths,
+                                                    seg_field)
             root_indices = np.nonzero(sids.mask_of(root_ids))[0]
 
         def next_root(i: int) -> Optional[int]:

@@ -11,14 +11,17 @@ import sys
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from cobrix_tpu import profiling, read_cobol
 from cobrix_tpu.obs import context as obs_context
-from cobrix_tpu.profiling import (DeviceStats, PoolWait, ReadMetrics, Stage,
-                                  StageTimes, stage, timed_stage)
+from cobrix_tpu.profiling import (DeviceStats, LinkCopy, PoolWait,
+                                  ReadMetrics, Stage, StageTimes, stage,
+                                  timed_stage)
 from cobrix_tpu.reader import columnar
-from cobrix_tpu.testing.generators import EXP3_COPYBOOK, generate_exp3
+from cobrix_tpu.testing.generators import (EXP2_COPYBOOK, EXP3_COPYBOOK,
+                                           generate_exp2, generate_exp3)
 
 from util import check_stage_record
 
@@ -34,8 +37,9 @@ EXP3_OPTIONS = dict(
 # launches more than one block
 EXP3_STAGES = {
     "parse_copybook", "plan_index", "scan", "read", "frame", "decode",
-    "pack", "h2d", "launch", "d2h_wait", "merge", "collect", "to_arrow",
-    "assemble.table", "assemble.list", "assemble.scalar", "assemble.string"}
+    "pack", "h2d", "launch", "d2h_wait", "d2h_wait.ready", "d2h_wait.copy",
+    "merge", "collect", "to_arrow", "assemble.table", "assemble.list",
+    "assemble.scalar", "assemble.string"}
 
 
 class FakeClock:
@@ -175,6 +179,95 @@ def test_a_thread_waiting_for_its_pool_takes_no_share(clock):
     assert sum(stats.stage_s.values()) == 6.0        # the wall
 
 
+def test_d2h_wait_with_its_children_is_what_it_was(clock):
+    """The fetch's two halves are stages beneath `d2h_wait`: the three
+    add up to the block (queuing the copies is the block's own time), and
+    the copy's whole seconds are the thread's."""
+    stats = DeviceStats()
+    with Stage("d2h_wait", stats):
+        clock.tick(0.25)
+        with Stage("d2h_wait.ready", stats):
+            clock.tick(2.0)
+        with LinkCopy(stats):
+            clock.tick(4.0)
+        clock.tick(0.5)
+    assert stats.stage_s == {"d2h_wait": 0.75, "d2h_wait.ready": 2.0,
+                             "d2h_wait.copy": 4.0}
+    assert stats.stage_n == {"d2h_wait": 1, "d2h_wait.ready": 1,
+                             "d2h_wait.copy": 1}
+    said = stats.as_dict()
+    assert (said["d2h_copy_thread_s"], said["d2h_copy_busy_s"],
+            said["d2h_strided_bytes"]) == (4.0, 4.0, 0)
+
+
+def test_two_threads_copying_at_once_keep_the_link_busy_once(clock):
+    stats = DeviceStats()
+    other = OtherThread()
+    mine, theirs = LinkCopy(stats), LinkCopy(stats)
+    with Stage("d2h_wait.ready", stats):             # t = 0
+        clock.tick(3.0)
+    mine.__enter__()                                 # t = 3
+    other(theirs.__enter__)
+    clock.tick(1.0)
+    other(lambda: theirs.__exit__(None, None, None))  # t = 4
+    mine.__exit__(None, None, None)
+    clock.tick(5.0)                                  # nobody copies
+    with LinkCopy(stats):                            # t = 9
+        clock.tick(0.5)
+    other.stop()
+    # 3..4 held two threads: two thread-seconds, one busy second
+    assert stats.d2h_copy_thread_s == 2.5
+    assert stats.d2h_copy_busy_s == 1.5
+    # the wait for the chip is a stage like any other: no count of its own
+    assert stats.stage_s["d2h_wait.ready"] == 3.0
+    # the stage clock split 3..4 between them, as it does any stage
+    assert stats.stage_s["d2h_wait.copy"] == 1.5
+
+
+def test_a_copy_that_raises_leaves_the_link_idle(clock):
+    stats = DeviceStats()
+    with pytest.raises(KeyError):
+        with LinkCopy(stats):
+            clock.tick(1.0)
+            raise KeyError("x")
+    clock.tick(8.0)
+    with LinkCopy(stats):
+        clock.tick(2.0)
+    assert stats.d2h_copy_busy_s == stats.d2h_copy_thread_s == 3.0
+
+
+def fortran(shape, dtype):
+    return np.asfortranarray(np.zeros(shape, dtype))
+
+
+@pytest.mark.parametrize("leaf,strided", [
+    (fortran((6, 4), np.int32), 6 * 4 * 4),          # rows-minor
+    (np.zeros((6, 4), np.int32), 0),                 # as C lays it
+    (fortran((6, 1), np.int64), 0),                  # one column
+    (fortran((1, 6), np.bool_), 0),                  # one row
+    (np.zeros((8, 6), np.uint8)[:, :4], 8 * 4),      # a cut of the rows
+    (np.zeros((), np.int32), 0),                     # a scalar
+], ids=["fortran", "c", "one_column", "one_row", "sliced", "scalar"])
+def test_strided_bytes_count_a_leaf_that_is_not_c_contiguous_whole(
+        leaf, strided):
+    assert columnar.strided_nbytes([leaf]) == strided
+    # a launch's count is the sum over its leaves
+    both = [leaf, fortran((3, 5), np.uint8)]
+    assert columnar.strided_nbytes(both) == strided + 15
+
+
+def test_note_launch_adds_the_strided_bytes_up():
+    class Program:
+        has_kernel = True
+
+    stats = DeviceStats()
+    for strided in (100, 0, 28):
+        stats.note_launch((256, 64), 200, 256 * 64, 512, (), Program(),
+                          False, False, d2h_strided_bytes=strided)
+    said = stats.as_dict()
+    assert (said["d2h_strided_bytes"], said["d2h_bytes"]) == (128, 1536)
+
+
 def test_concurrent_add_stage_loses_no_update():
     stats = DeviceStats()
     switch = sys.getswitchinterval()
@@ -183,6 +276,8 @@ def test_concurrent_add_stage_loses_no_update():
         def hammer():
             for _ in range(2000):
                 with Stage("h2d", stats):
+                    pass
+                with LinkCopy(stats):
                     pass
 
         threads = [threading.Thread(target=hammer) for _ in range(16)]
@@ -193,7 +288,10 @@ def test_concurrent_add_stage_loses_no_update():
         assert not any(t.is_alive() for t in threads)
     finally:
         sys.setswitchinterval(switch)
-    assert stats.stage_n == {"h2d": 32000}
+    assert stats.stage_n == {"h2d": 32000, "d2h_wait.copy": 32000}
+    # every thread that began a copy ended it: the busy clock stands
+    assert stats._copy_threads == 0
+    assert 0.0 < stats.d2h_copy_busy_s <= stats.d2h_copy_thread_s
 
 
 # enough 'C' records (a third of them) to fill more than one block of 256
@@ -251,6 +349,37 @@ def test_exp3_read_counts_every_stage(exp3_file, small_blocks):
     assert device["set_rows"]["STATIC_DETAILS"] > 256
 
 
+def test_a_fetch_queues_its_copies_then_waits_then_brings_them_home(
+        exp3_file, small_blocks, monkeypatch):
+    """The one fetch site's three calls into the runtime, each in the
+    stage that is meant to hold it: the copies home are queued in
+    `d2h_wait`'s own time, so that `d2h_wait.ready` is the wait for the
+    outputs and nothing else, and `d2h_wait.copy` what is left of their
+    way home."""
+    import jax
+
+    seen = []
+
+    def spy(name):
+        real = getattr(jax, name)
+
+        def call(*args, **kwargs):
+            stack = profiling._open.stack
+            seen.append((name, stack[-1].name if stack else None))
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(jax, name, call)
+
+    for name in ("copy_to_host_async", "block_until_ready", "device_get"):
+        spy(name)
+    data = read_cobol(exp3_file, backend="pallas", **EXP3_OPTIONS)
+    launches = sum(data.metrics.as_dict()["device"]["launches"].values())
+    assert launches >= 2
+    assert seen == [("copy_to_host_async", "d2h_wait"),
+                    ("block_until_ready", "d2h_wait.ready"),
+                    ("device_get", "d2h_wait.copy")] * launches
+
+
 def test_a_sharded_read_splits_the_wall_among_its_threads(exp3_file):
     """Shards scanned by a thread pool and tables built by one: every
     instant is split among the threads inside stages, the waiting caller
@@ -263,7 +392,12 @@ def test_a_sharded_read_splits_the_wall_among_its_threads(exp3_file):
     metrics = data.metrics.as_dict()
     assert table.num_rows == RECORDS and metrics["shards"] == 4
     device = metrics["device"]
-    check_stage_record(device, wall_s, EXP3_STAGES - {"merge"})
+    check_stage_record(device, wall_s,
+                       (EXP3_STAGES | {"plan_index.scan"}) - {"merge"})
+    # one header scan of the whole file cut the shards; no Seg_Id level
+    # asks for a cut at roots
+    assert device["stage_n"]["plan_index.scan"] == 1
+    assert "plan_index.seg_ids" not in device["stage_s"]
     assert device["stage_n"]["decode"] == 4
     assert device["stage_n"]["assemble.list"] == 4
     assert metrics["native_passes"]["plane_list"] == 2 * 4
@@ -288,10 +422,39 @@ def test_a_list_of_strings_is_built_slot_by_slot(tmp_path):
     assert device["stage_n"]["assemble.list.slots"] == 1
     assert device["stage_n"]["assemble.list"] == 1
     assert "plane_list" not in metrics["native_passes"]
+    # a fixed-length read plans no index
+    assert not any(name.startswith("plan_index") for name in
+                   device["stage_s"])
     # a fixed-length read brings no row masks: nothing to partition,
     # nothing declined
     assert (device["partitioned_batches"], device["declined_batches"],
             device["set_rows"]) == (0, 0, {})
+
+
+@pytest.mark.parametrize("backend", ["pallas", "numpy"])
+def test_plan_index_counts_its_header_scan_and_its_segment_ids(tmp_path,
+                                                               backend):
+    """A multisegment RDW file cut at roots: the header scan of the whole
+    file and the segment id of every record are stages beneath
+    `plan_index`, once a file, whatever kernels decode the shards."""
+    path = tmp_path / "companies.dat"
+    path.write_bytes(generate_exp2(1300, seed=34))
+    data = read_cobol(
+        str(path), backend=backend, copybook_contents=EXP2_COPYBOOK,
+        is_record_sequence="true", segment_field="SEGMENT-ID",
+        redefine_segment_id_map="STATIC-DETAILS => C",
+        redefine_segment_id_map_1="CONTACTS => P", segment_id_level0="C",
+        segment_id_level1="P", segment_id_prefix="A",
+        input_split_records="300")
+    assert data.metrics.shards >= 3
+    stats = data.metrics.device_stats
+    assert stats.stage_n["plan_index"] == 1
+    assert stats.stage_n["plan_index.scan"] == 1
+    assert stats.stage_n["plan_index.seg_ids"] == 1
+    # self time: the children are not counted in the parent again
+    beneath = sum(s for name, s in stats.stage_s.items()
+                  if name.startswith("plan_index"))
+    assert beneath <= data.metrics.timings_s["plan_index"] + 1e-6
 
 
 def test_a_pipelined_read_counts_on_its_stage_threads(exp3_file):
@@ -301,7 +464,8 @@ def test_a_pipelined_read_counts_on_its_stage_threads(exp3_file):
     metrics = data.metrics.as_dict()
     stage_s = metrics["device"]["stage_s"]
     assert {"read", "frame", "decode", "assemble", "assemble.table",
-            "assemble.list", "h2d", "launch", "d2h_wait"} <= set(stage_s)
+            "assemble.list", "h2d", "launch", "d2h_wait", "d2h_wait.ready",
+            "d2h_wait.copy"} <= set(stage_s)
     busy = metrics["stage_busy_s"]
     # busy seconds are whole durations, the counters self time
     assert busy["decode"] >= stage_s["decode"]
@@ -353,9 +517,16 @@ def test_spans_lie_on_the_profilers_clock(exp3_file, small_blocks, tmp_path):
                    for line, start, end in by_name[name])
 
     launches = sum(data.metrics.as_dict()["device"]["launches"].values())
-    for name in ("cobrix.h2d", "cobrix.launch", "cobrix.d2h_wait"):
+    for name in ("cobrix.h2d", "cobrix.launch", "cobrix.d2h_wait",
+                 "cobrix.d2h_wait.ready", "cobrix.d2h_wait.copy"):
         assert len(by_name[name]) == launches
         assert inside(name, "cobrix_decode")
+    for name in ("cobrix.d2h_wait.ready", "cobrix.d2h_wait.copy"):
+        assert inside(name, "cobrix.d2h_wait")
+    # the wait for the chip ends before the copy home begins
+    assert all(ready[2] <= copy[1] for ready, copy in zip(
+        sorted(by_name["cobrix.d2h_wait.ready"], key=lambda e: e[1]),
+        sorted(by_name["cobrix.d2h_wait.copy"], key=lambda e: e[1])))
     assert len(by_name["cobrix_decode"]) == 1
     assert inside("cobrix_decode", "cobrix.decode")
     assert inside("cobrix.decode", "cobrix.scan")
@@ -372,7 +543,6 @@ def test_spans_lie_on_the_profilers_clock(exp3_file, small_blocks, tmp_path):
 
 def test_lowered_exp3_program_carries_the_scopes(exp3_file):
     import jax
-    import numpy as np
 
     data = read_cobol(exp3_file, backend="pallas", **EXP3_OPTIONS)
     decoder = data._results[0].segments[0].batch.decoder
@@ -400,6 +570,9 @@ print(json.dumps({"jax": "jax" in sys.modules, "rows": table.num_rows,
 def test_a_host_kernel_read_never_imports_jax(exp3_file):
     options = {k: v for k, v in EXP3_OPTIONS.items()
                if k != "copybook_contents"}
+    # cut into shards, so that the index is planned: its stages too are
+    # no reason to import JAX
+    options["input_split_records"] = str(RECORDS // 4)
     proc = subprocess.run(
         [sys.executable, "-c", HOST_READ, REPO, exp3_file,
          json.dumps(options)], capture_output=True, text=True, timeout=120,
@@ -409,8 +582,9 @@ def test_a_host_kernel_read_never_imports_jax(exp3_file):
     assert said["jax"] is False
     assert said["rows"] > 0
     # the counters ran all the same; a host read shows no device record
-    assert {"read", "frame", "decode", "assemble.table"} <= set(
-        said["stages"])
+    assert {"read", "frame", "decode", "assemble.table", "plan_index",
+            "plan_index.scan"} <= set(said["stages"])
+    assert not any(name.startswith("d2h_wait") for name in said["stages"])
     assert said["device"] is None
 
 
@@ -432,7 +606,11 @@ def test_the_serve_trailer_carries_busy_seconds_and_stage_counters(exp3_file):
     busy = metrics["stage_busy_s"]
     assert {"read", "frame", "decode", "assemble"} <= set(busy)
     stage_s = metrics["device"]["stage_s"]
-    assert {"h2d", "launch", "d2h_wait", "assemble", "assemble.list"} <= set(
-        stage_s)
+    assert {"h2d", "launch", "d2h_wait", "d2h_wait.ready", "d2h_wait.copy",
+            "assemble", "assemble.list"} <= set(stage_s)
     assert busy["assemble"] >= stage_s["assemble"]
+    # the link's own counts ride the trailer with the bytes
+    device = metrics["device"]
+    assert 0.0 < device["d2h_copy_busy_s"] <= device["d2h_copy_thread_s"]
+    assert 0 <= device["d2h_strided_bytes"] <= device["d2h_bytes"]
     assert metrics["device"]["lower_s"] <= metrics["device"]["compile_s"]

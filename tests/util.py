@@ -112,6 +112,14 @@ def check_stage_record(device: dict, wall_s: float, stages: set) -> None:
     launches = sum(device["launches"].values())
     assert stage_n["launch"] == launches
     assert stage_n["h2d"] == launches and stage_n["d2h_wait"] == launches
+    # the fetch's two halves, once a launch: the wait for the chip, then
+    # the copy home
+    assert stage_n["d2h_wait.ready"] == stage_n["d2h_wait.copy"] == launches
+    # the link's own counts: whole seconds of the fetching threads, and
+    # the wall in which at least one of them was copying
+    assert 0.0 < device["d2h_copy_busy_s"] <= device["d2h_copy_thread_s"]
+    assert device["d2h_copy_busy_s"] <= wall_s
+    assert 0 <= device["d2h_strided_bytes"] <= device["d2h_bytes"]
     # self time on one thread, each instant split among the threads of a
     # pool: the stages add up to at most the wall
     assert sum(stage_s.values()) <= wall_s
