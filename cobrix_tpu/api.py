@@ -853,20 +853,36 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
     index per file turns the sequential record stream into byte-range
     shards; shards decode concurrently (each from its own bounded stream,
     Record_Id seeded from the index entry) and results reassemble in
-    record order."""
+    record order.
+
+    Two routes, chosen by `reader.index.preframed_route` from each file's
+    record density and nothing a caller sets: the index is planned whole
+    and every shard then frames its own byte range; or, for a dense RDW
+    file, the index pass is the file's one framing and a shard starts,
+    with its slice of the pass's tables, as soon as its cut is found
+    (`engine.chunks.preframed_var_len_chunks`). Same shards, same
+    tables."""
+    from .engine.chunks import preframed_var_len_chunks
     from .obs.context import activate as obs_activate
     from .obs.context import current as obs_current
 
     obs = obs_current()
     tracer = obs.tracer if obs is not None else None
     progress = obs.progress if obs is not None else None
-    with stage(metrics, "plan_index"):
-        shards = _plan_var_len_shards(reader, files, params, retry,
-                                      on_retry, io)
-    if metrics is not None:
-        metrics.shards = len(shards)
-    if progress is not None:
-        progress.set_plan(chunks_total=len(shards))
+
+    def planned(n_shards: int) -> None:
+        if metrics is not None:
+            metrics.shards = n_shards
+        if progress is not None:
+            progress.set_plan(chunks_total=n_shards)
+
+    preframed = preframed_var_len_chunks(reader, files, params, retry,
+                                         on_retry, io)
+    if preframed is None:
+        with stage(metrics, "plan_index"):
+            shards = _plan_var_len_shards(reader, files, params, retry,
+                                          on_retry, io)
+        planned(len(shards))
     shard_times = None
     if tracer is not None or (metrics is not None
                               and metrics.field_costs_acc is not None):
@@ -884,7 +900,7 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
         if progress is not None and progress.stage_times is None:
             progress.stage_times = shard_times
 
-    def scan(shard) -> "FileResult":
+    def scan(shard, framed) -> "FileResult":
         max_bytes = (0 if shard.offset_to < 0
                      else shard.offset_to - shard.offset_from)
         with open_stream(shard.file_path, start_offset=shard.offset_from,
@@ -895,10 +911,12 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
                 segment_id_prefix=prefix,
                 start_record_id=shard.record_index,
                 starting_file_offset=shard.offset_from,
-                stage_times=shard_times)
+                stage_times=shard_times, framed=framed)
 
-    def run_shard(indexed) -> "FileResult":
+    def run_shard(indexed, framed=None) -> "FileResult":
         seq, shard = indexed
+        if metrics is not None:
+            metrics.device_stats.note_shard(preframed=framed is not None)
         # re-activate the read's ObsContext: pool threads must attribute
         # cache events and spans to this read, not to nothing
         with obs_activate(obs):
@@ -910,9 +928,9 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
                                        "file": shard.file_path,
                                        "offset_from": shard.offset_from,
                                        "offset_to": shard.offset_to}):
-                    result = scan(shard)
+                    result = scan(shard, framed)
             else:
-                result = scan(shard)
+                result = scan(shard, framed)
         if progress is not None:
             from .engine.chunks import shard_progress_bytes
 
@@ -920,12 +938,34 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
                                 records=result.n_rows)
         return result
 
+    from concurrent.futures import ThreadPoolExecutor
+
+    if preframed is not None:
+        if parallelism <= 1:
+            with stage(metrics, "plan_index"):
+                handed = list(preframed)
+            planned(len(handed))
+            return [run_shard((seq, shard), framed)
+                    for seq, (shard, framed) in enumerate(handed)]
+        # a worker starts as a shard is handed over and an idle one is
+        # taken first: `parallelism` bounds the pool, the shards in
+        # flight size it
+        ex = ThreadPoolExecutor(max_workers=parallelism)
+        try:
+            with stage(metrics, "plan_index"):
+                futures = [ex.submit(run_shard, (seq, shard), framed)
+                           for seq, (shard, framed) in enumerate(preframed)]
+            planned(len(futures))
+            with PoolWait():
+                return [future.result() for future in futures]
+        finally:
+            # an error of the pass or of a shard: nothing new starts
+            ex.shutdown(wait=True, cancel_futures=True)
+
     # <= 1: zone-map skipping can leave no shard at all, and a pool of
     # zero workers is a ValueError
     if len(shards) <= 1 or parallelism <= 1:
         return [run_shard(s) for s in enumerate(shards)]
-    from concurrent.futures import ThreadPoolExecutor
-
     with ThreadPoolExecutor(max_workers=min(parallelism, len(shards))) as ex:
         with PoolWait():
             return list(ex.map(run_shard, enumerate(shards)))

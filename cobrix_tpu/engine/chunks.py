@@ -26,7 +26,11 @@ import os
 from dataclasses import dataclass
 from typing import List, Optional
 
-from ..reader.index import file_index_entries
+from ..reader.index import (
+    file_index_entries,
+    preframed_entries,
+    preframed_route,
+)
 from ..reader.parameters import DEFAULT_FILE_RECORD_ID_INCREMENT
 from ..reader.stream import RetryPolicy, path_scheme, source_size
 
@@ -135,31 +139,72 @@ def plan_var_len_chunks(reader, files, params,
     without a useful index become one whole-file shard. Shared by the
     in-process threaded scan, the pipelined executor, and the multi-host
     (process) executor."""
+    shards: List["WorkShard"] = []
+    for file_order, file_path in enumerate(files):
+        shards.extend(_file_shards(reader, file_path, file_order, params,
+                                   retry, on_retry, io))
+    return shards
+
+
+def _file_shards(reader, file_path: str, file_order: int, params,
+                 retry, on_retry, io) -> List["WorkShard"]:
+    """One file's shards of `plan_var_len_chunks`."""
     from ..parallel.planner import WorkShard
 
     skipper = getattr(reader, "chunk_skipper", None)
-    shards: List[WorkShard] = []
-    for file_order, file_path in enumerate(files):
-        base = file_order * DEFAULT_FILE_RECORD_ID_INCREMENT
-        entries = None
-        if params.is_index_generation_needed:
-            entries = file_index_entries(reader, file_path, file_order,
-                                         params, retry, on_retry, io=io)
-        if entries is not None and len(entries) > 1:
-            # an open-ended last entry (-1) flows into the shard unchanged:
-            # streams bound it to the file end themselves, so no extra
-            # size round trip is needed for registry-backed storage
-            for e in entries:
-                if skipper is not None and skipper.should_skip(
-                        file_path, e.offset_from, e.offset_to):
-                    continue
-                shards.append(WorkShard(file_path, file_order,
-                                        e.offset_from, e.offset_to,
-                                        base + e.record_index))
-        elif skipper is None \
-                or not skipper.should_skip(file_path, 0, -1):
-            shards.append(WorkShard(file_path, file_order, 0, -1, base))
-    return shards
+    base = file_order * DEFAULT_FILE_RECORD_ID_INCREMENT
+    entries = None
+    if params.is_index_generation_needed:
+        entries = file_index_entries(reader, file_path, file_order,
+                                     params, retry, on_retry, io=io)
+    if entries is not None and len(entries) > 1:
+        # an open-ended last entry (-1) flows into the shard unchanged:
+        # streams bound it to the file end themselves, so no extra
+        # size round trip is needed for registry-backed storage
+        return [WorkShard(file_path, file_order, e.offset_from, e.offset_to,
+                          base + e.record_index)
+                for e in entries
+                if skipper is None or not skipper.should_skip(
+                    file_path, e.offset_from, e.offset_to)]
+    if skipper is None or not skipper.should_skip(file_path, 0, -1):
+        return [WorkShard(file_path, file_order, 0, -1, base)]
+    return []
+
+
+def preframed_var_len_chunks(reader, files, params,
+                             retry: Optional[RetryPolicy] = None,
+                             on_retry=None, io=None):
+    """`plan_var_len_chunks` for the in-process threaded scan where some
+    file is dense enough for its index pass to be its one framing
+    (`reader.index.preframed_route`: the rule, read off each file): an
+    iterator of (WorkShard, FramedRecords or None), the same shards in
+    the same order, each of such a file with its slice of the pass's
+    tables and as soon as its cut is found, so it can be scanned while
+    the pass walks on. None where no file is: the caller plans as ever.
+    The pipelined engine and the multihost executor, whose shards cross
+    threads of stages and processes, take `plan_var_len_chunks`' list."""
+    from ..parallel.planner import WorkShard
+
+    if not params.is_index_generation_needed:
+        return None
+    dense = [preframed_route(reader, path, params, io) for path in files]
+    if not any(dense):
+        return None
+
+    def shards():
+        for file_order, file_path in enumerate(files):
+            if not dense[file_order]:
+                for shard in _file_shards(reader, file_path, file_order,
+                                          params, retry, on_retry, io):
+                    yield shard, None
+                continue
+            base = file_order * DEFAULT_FILE_RECORD_ID_INCREMENT
+            for e, framed in preframed_entries(reader, file_path,
+                                               file_order):
+                yield WorkShard(file_path, file_order, e.offset_from,
+                                e.offset_to, base + e.record_index), framed
+
+    return shards()
 
 
 def auto_split_mb(params) -> Optional[int]:

@@ -36,10 +36,17 @@ and index counts). What stands in for it here, and on which clock:
   link was in use, which a share of the wall cannot say; beside them
   `d2h_strided_bytes`, the fetched bytes that came home in another order
   than C's and wait for a transposing copy on the host. `plan_index` has
-  two children the same way, plain stages: `plan_index.scan` (the
-  one-thread header scan of the whole file) and `plan_index.seg_ids`
-  (every record's segment id, for the cut at roots);
-  `VarLenReader.generate_index_fast`.
+  two children the same way, plain stages, on the thread that cuts the
+  index: `plan_index.scan` (the header scan) and `plan_index.seg_ids`
+  (every record's segment id). On the route of most files
+  (`VarLenReader.generate_index_fast`) they run once a file, over the
+  whole image and before any shard starts, `.seg_ids` only for a cut at
+  roots. On the route of a dense RDW file (`frame_index_fast`, chosen by
+  `reader.index.preframed_route`) the pass is the file's one framing:
+  the two run once a window, `.scan` is the fused walk that a shard's
+  `frame` stage would have made, `.seg_ids` decodes the ids the shards
+  need, and shards run while `plan_index` is still open, so the three
+  split the stage clock with them.
 - `annotate(name)`: a bare span on the profiler's clock under exactly
   `name` (``cobrix_decode`` round the launch loop: the benchmark's trace
   reduction reads it); ~free when no trace is on.
@@ -309,7 +316,10 @@ class DeviceStats:
     (`odo_records`), the bytes their shifts moved them by in all
     (`odo_shifted_bytes`: with `h2d_bytes`, what an expansion on the host
     would have sent more), and the records the row path walked instead
-    (`odo_fallback_records`).
+    (`odo_fallback_records`); and for the threaded indexed scan (present
+    only where it ran; api._scan_var_len): the shards that got their
+    records' tables from the index pass (`preframed_shards`) and those
+    that framed themselves (`self_framed_shards`).
     The link home, noted where a launch is fetched (`ColumnarDecoder.
     _fetch_block`; `LinkCopy`): `d2h_copy_thread_s`, the fetching
     threads' own `perf_counter` seconds bringing ready outputs home,
@@ -358,6 +368,9 @@ class DeviceStats:
         self.odo_records = 0
         self.odo_fallback_records = 0
         self.odo_shifted_bytes = 0
+        # index shards of the threaded scan (api._scan_var_len)
+        self.preframed_shards = 0
+        self.self_framed_shards = 0
         # route counts of every decode program launched, by identity: a
         # read launches one decoder's program many times
         self._program_groups: Dict[int, Dict[str, int]] = {}
@@ -471,6 +484,15 @@ class DeviceStats:
             self.odo_fallback_records += fallback_records
             self.odo_shifted_bytes += shifted_bytes
 
+    def note_shard(self, preframed: bool) -> None:
+        """One index shard of the threaded scan: `preframed`, it got its
+        records' tables from the index pass; else it framed itself."""
+        with self._lock:
+            if preframed:
+                self.preframed_shards += 1
+            else:
+                self.self_framed_shards += 1
+
     @property
     def odo(self) -> Dict[str, int]:
         """The `odo_*` counts, or {} for a read that met no variable-size
@@ -509,9 +531,14 @@ class DeviceStats:
                 "query_rows_scanned": self.query_rows_scanned,
                 "query_rows_passed": self.query_rows_passed,
                 "query_groups": self.query_groups}
+            counted = self.preframed_shards + self.self_framed_shards
+            shards = {} if not counted else {
+                "preframed_shards": self.preframed_shards,
+                "self_framed_shards": self.self_framed_shards}
             return {
                 **query,
                 **odo,
+                **shards,
                 "device_groups": device_groups,
                 "launches": {f"{b}x{e}": n for (b, e), n
                              in sorted(self.launches.items())},

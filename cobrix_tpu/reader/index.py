@@ -45,6 +45,99 @@ class SparseIndexEntry:
     record_index: int
 
 
+@dataclass(frozen=True)
+class FramedRecords:
+    """An index entry's records as the index pass walked them: what the
+    RDW scan of `VarLenReader._frame_fast` finds in the entry's byte
+    range. `offsets` are payload offsets from the entry's first byte;
+    `seg_bytes` is the [n, width] matrix of the records' segment id
+    bytes, None where no field is resolved or the fused native walk is
+    not there (the shard gathers them then)."""
+    offsets: object         # numpy int64, one a record
+    lengths: object
+    seg_bytes: Optional[object]
+
+
+# A file whose records average fewer payload bytes than this over its first
+# PREFRAMED_PROBE_BYTES is framed once, by its index pass (`preframed_route`).
+# The constant rests on two points of the benchmark's ledger and nothing
+# finer: at 65-70 B a record (exp2_read: 8.2 million records a 512 MiB file)
+# the one framing gave +46.6 %, at 722 B (tpch_orders_odo_read: 743 k) and
+# at 5.4 KB (exp3_read: 99 k) it gave nothing the runs resolve, or lost
+# (PERF_LEDGER.jsonl, PR 35). Speed only: both routes give the same tables.
+PREFRAMED_MAX_MEAN_RECORD = 256
+PREFRAMED_PROBE_BYTES = MEGABYTE
+
+
+def _one_shard_covers(size: int, params) -> bool:
+    """A file too small to index: empty (nothing to index, and mmap
+    rejects empty files), or within one split with no explicit split
+    option — the whole file is one shard anyway."""
+    if size == 0:
+        return True
+    explicit = (params.input_split_records is not None
+                or params.input_split_size_mb is not None)
+    split_mb = params.input_split_size_mb or DEFAULT_INDEX_ENTRY_SIZE_MB
+    return not explicit and size <= split_mb * MEGABYTE
+
+
+def preframed_route(reader, file_path: str, params, io=None) -> bool:
+    """THE rule of the indexed scan's second route: True where the index
+    pass of `file_path` should be the file's one framing
+    (`preframed_entries`), each shard receiving its slice of the pass's
+    tables instead of scanning its byte range again. Read off the file:
+    the mean record length over the RDW headers of its first
+    PREFRAMED_PROBE_BYTES, under PREFRAMED_MAX_MEAN_RECORD. False, and
+    `file_index_entries` as ever, for everything the pass does not
+    serve to the letter: a permissive policy (the pass's ledger is a
+    throwaway, the shards' is the read's), the index store (entries are
+    loaded, or saved: no pass may have run), zone-map skipping, framing
+    the native scan does not do, layouts that do not reach
+    `_frame_fast`'s tables, storage that is not a plain local file, and
+    a file one shard covers."""
+    from ..io.compress import active_codec, compressed_chunkable
+    from .stream import path_scheme
+
+    if (params.is_permissive
+            or (io is not None and io.cache_enabled)
+            or getattr(reader, "chunk_skipper", None) is not None
+            or not reader.supports_fast_framing
+            or reader.copybook.is_hierarchical
+            or reader.dynamic_occurs_layout):
+        return False
+    if (path_scheme(file_path) not in (None, "file")
+            or active_codec(file_path, io) is not None
+            or not compressed_chunkable(file_path, io)):
+        return False
+    size = os.path.getsize(file_path)
+    if _one_shard_covers(size, params):
+        return False
+    with open(file_path, "rb") as f:
+        head = f.read(PREFRAMED_PROBE_BYTES)
+    mean = reader.mean_record_length(head, whole=len(head) >= size)
+    return mean is not None and mean < PREFRAMED_MAX_MEAN_RECORD
+
+
+def preframed_entries(reader, file_path: str, file_order: int):
+    """The sparse index of one file on the route `preframed_route`
+    chose: `file_index_entries`' entries to the byte, each with its
+    records' tables (`FramedRecords`), yielded as its cut is found so
+    that its shard can start while the pass walks on."""
+    import mmap
+
+    with open(file_path, "rb") as f:
+        mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
+        try:
+            yield from reader.frame_index_fast(mm, file_order)
+        finally:
+            try:
+                mm.close()
+            except BufferError:
+                # as in file_index_entries: an error in flight still
+                # references the map through its traceback
+                pass
+
+
 def file_index_entries(reader, file_path: str, file_order: int, params,
                        retry=None, on_retry=None, io=None
                        ) -> Optional[List[SparseIndexEntry]]:
@@ -59,18 +152,7 @@ def file_index_entries(reader, file_path: str, file_order: int, params,
     store (cobrix_tpu.io.index_store) keyed by file fingerprint +
     framing-config fingerprint: the sequential indexing pass runs once
     per file version, and warm re-scans load the shard plan directly."""
-    from .parameters import DEFAULT_INDEX_ENTRY_SIZE_MB, MEGABYTE
     from .stream import open_stream, path_scheme
-
-    explicit = (params.input_split_records is not None
-                or params.input_split_size_mb is not None)
-    split_mb = params.input_split_size_mb or DEFAULT_INDEX_ENTRY_SIZE_MB
-
-    def too_small(size: int) -> bool:
-        if size == 0:
-            return True  # nothing to index (and mmap rejects empty files)
-        # the whole file is one shard anyway
-        return not explicit and size <= split_mb * MEGABYTE
 
     store = config_fp = io_stats = None
     if io is not None and io.cache_enabled:
@@ -109,7 +191,7 @@ def file_index_entries(reader, file_path: str, file_order: int, params,
         return None
     if path_scheme(file_path) in (None, "file") \
             and active_codec(file_path, io) is None:
-        if too_small(os.path.getsize(file_path)):
+        if _one_shard_covers(os.path.getsize(file_path), params):
             return None
         fingerprint = None
         if store is not None:
@@ -150,7 +232,7 @@ def file_index_entries(reader, file_path: str, file_order: int, params,
     # discovery inflate)
     with open_stream(file_path, retry=retry, on_retry=on_retry,
                      io=io) as stream:
-        if too_small(stream.size()):
+        if _one_shard_covers(stream.size(), params):
             return None
         fingerprint = None
         if store is not None:

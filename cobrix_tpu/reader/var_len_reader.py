@@ -37,7 +37,7 @@ from .header_parsers import (
     RecordHeaderParser,
     create_record_header_parser,
 )
-from .index import SparseIndexEntry, sparse_index_generator
+from .index import FramedRecords, SparseIndexEntry, sparse_index_generator
 from .parameters import (
     DEFAULT_FILE_RECORD_ID_INCREMENT,
     DEFAULT_INDEX_ENTRY_SIZE_MB,
@@ -101,6 +101,59 @@ class SegmentIdAccumulator:
 
 def default_segment_id_prefix() -> str:
     return time.strftime("%Y%m%d%H%M%S")
+
+
+# What a window of `VarLenReader.frame_index_fast` reads past the byte at
+# which its cut is due: room for the record that closes the entry, at
+# roots for the records up to the next root, and for the one record a
+# window gives up. A window that finds no cut is walked again, longer.
+INDEX_WINDOW_SLACK = MEGABYTE
+# The records whose ids that pass decodes at a time where it looks for the
+# root at which to cut: the rest of a window's ids wait for the shard.
+ROOT_SEARCH_RECORDS = 4096
+
+
+class _IndexCuts:
+    """The split arithmetic of the vectorized sparse index, a cut a call,
+    shared by `generate_index_fast` (the whole file's records at once)
+    and `frame_index_fast` (the records walked so far). Split semantics
+    (including the invalid-record counting and size-drift quirks) mirror
+    sparse_index_generator exactly — pinned by tests against it."""
+
+    def __init__(self, params: ReaderParameters):
+        self.per = params.input_split_records
+        self.mb = ((params.input_split_size_mb or DEFAULT_INDEX_ENTRY_SIZE_MB)
+                   * MEGABYTE)
+        # the file-header region is consumed as one counted invalid record
+        # (IndexGenerator.scala:117-120 counts unconditionally)
+        self.base = 1 if params.file_start_offset > 0 else 0
+        self.subtracted = 0
+        self.chunk_start_counted = 0
+        # a first-chunk split at record 0 is possible (header counted)
+        self.last = -1
+
+    def next_cut(self, starts: np.ndarray, first: int, next_root,
+                 last_candidate: int) -> Optional[int]:
+        """The record that opens the next entry, or None where none up
+        to `last_candidate` does. `starts[k]` is the byte at which
+        record `first + k` starts (its RDW header), `first` no later
+        than the last cut; `next_root(i)` is the first record from `i`
+        on that may open an entry (a root, where shards are cut at
+        roots), or None."""
+        if self.per is not None:
+            cand = self.chunk_start_counted + self.per - self.base
+        else:
+            target = self.subtracted + self.mb
+            cand = first + int(np.searchsorted(starts, target, side="left"))
+        split_at = next_root(max(cand, self.last + 1))
+        if split_at is None or split_at > last_candidate:
+            return None
+        if self.per is not None:
+            self.chunk_start_counted = split_at + self.base
+        else:
+            self.subtracted += self.mb
+        self.last = split_at
+        return split_at
 
 
 def _segment_level_ids_vectorized(segment_ids: Sequence[str],
@@ -373,9 +426,7 @@ class VarLenReader:
         if not self.supports_fast_framing:
             return None
         p = self.params
-        adjustment = p.rdw_adjustment
-        if p.is_rdw_part_of_record_length:
-            adjustment -= 4
+        adjustment = self._rdw_length_adjustment()
         # every header of the file image read once, on this one thread
         with Stage("plan_index.scan"):
             if p.is_permissive:
@@ -396,9 +447,6 @@ class VarLenReader:
                     p.file_start_offset, p.file_end_offset)
         n = len(offsets)
         starts = offsets - 4  # RDW header precedes the payload
-        # the file-header region is consumed as one counted invalid record
-        # (IndexGenerator.scala:117-120 counts unconditionally)
-        base = 1 if p.file_start_offset > 0 else 0
 
         is_hierarchical, root_segment_id = self._index_split_config()
         seg_field = resolve_segment_id_field(p, self.copybook)
@@ -418,41 +466,180 @@ class VarLenReader:
                 return None
             return int(root_indices[k])
 
+        cuts = _IndexCuts(p)
         entries = [SparseIndexEntry(0, -1, file_id, 0)]
-        if p.input_split_records is not None:
-            per = p.input_split_records
-        else:
-            per = None
-            mb = ((p.input_split_size_mb or DEFAULT_INDEX_ENTRY_SIZE_MB)
-                  * MEGABYTE)
-
         # processing the last record ends the stream before the split check
         # (IndexGenerator loop order) — unless a footer region follows it,
         # which is consumed as one more counted iteration
         last_candidate = n - 1 if p.file_end_offset > 0 else n - 2
-        subtracted = 0
-        chunk_start_counted = 0
-        i = -1  # a first-chunk split at record 0 is possible (header counted)
-        while True:
-            if per is not None:
-                cand = chunk_start_counted + per - base
-            else:
-                target = subtracted + mb
-                cand = int(np.searchsorted(starts, target, side="left"))
-            cand = max(cand, i + 1)
-            split_at = next_root(cand)
-            if split_at is None or split_at > last_candidate:
-                break
+        while (split_at := cuts.next_cut(starts, 0, next_root,
+                                         last_candidate)) is not None:
             entries[-1] = replace(entries[-1],
                                   offset_to=int(starts[split_at]))
             entries.append(SparseIndexEntry(
-                int(starts[split_at]), -1, file_id, split_at + base))
-            if per is not None:
-                chunk_start_counted = split_at + base
-            else:
-                subtracted += mb
-            i = split_at
+                int(starts[split_at]), -1, file_id, split_at + cuts.base))
         return entries
+
+    def _rdw_length_adjustment(self) -> int:
+        p = self.params
+        return p.rdw_adjustment - (4 if p.is_rdw_part_of_record_length
+                                   else 0)
+
+    def _rdw_walk(self, data, file_header: int, file_footer: int,
+                  seg_field: Optional[Primitive]):
+        """The strict RDW scan of a file image or a part of one that
+        starts at a record: (offsets, lengths, seg_bytes). With a segment
+        id field, the fused frame + segment-id gather: one native walk
+        emits the record table AND each record's id-field bytes,
+        replacing rdw_scan + a whole-file pack_records re-walk
+        (`seg_bytes` None = no field, or no native library)."""
+        from .. import native
+
+        p = self.params
+        adjustment = self._rdw_length_adjustment()
+        if seg_field is not None:
+            fused = native.rdw_scan_segids(
+                data, p.is_rdw_big_endian,
+                p.start_offset + seg_field.binary_properties.offset,
+                seg_field.binary_properties.actual_size,
+                adjustment, file_header, file_footer)
+            if fused is not None:
+                count_pass("fused_frame")
+                return fused
+        offsets, lengths = native.rdw_scan(
+            data, p.is_rdw_big_endian, adjustment, file_header, file_footer)
+        return offsets, lengths, None
+
+    def mean_record_length(self, head, whole: bool) -> Optional[float]:
+        """Mean payload length by the RDW headers of `head`, the first
+        bytes of a file (`whole`: all of them): the density that
+        `index.preframed_route` reads. None where the walk finds no whole
+        record or a header it cannot follow (the index pass says which)."""
+        from .. import native
+
+        p = self.params
+        try:
+            _, lengths = native.rdw_scan(
+                head, p.is_rdw_big_endian, self._rdw_length_adjustment(),
+                p.file_start_offset, p.file_end_offset if whole else 0)
+        except ValueError:
+            return None
+        if not whole:
+            lengths = lengths[:-1]  # cut short where the head ends
+        return float(lengths.mean()) if len(lengths) else None
+
+    def frame_index_fast(self, data, file_id: int
+                         ) -> Iterator[Tuple[SparseIndexEntry, FramedRecords]]:
+        """`generate_index_fast`'s entries, each with the tables of its
+        records, each yielded as soon as its cut is known: the index pass
+        as the one framing of a file (`index.preframed_route` says of
+        which). The file image is walked in windows by the scan that
+        `_frame_fast` gives a shard (one native walk for the record table
+        and the id bytes), each from the last cut to INDEX_WINDOW_SLACK
+        past where the next is due, so a table is a slice of one window's
+        arrays and what is walked twice is the slack. A read is as long
+        as this pass and its last shard, so the pass does only what a
+        cut waits for: the ids are decoded where a root is looked for,
+        ROOT_SEARCH_RECORDS at a time, and coded whole by the shard's
+        own thread. Not for a permissive policy, nor where
+        `supports_fast_framing` is False."""
+        from .. import native
+
+        p = self.params
+        buf = np.frombuffer(data, dtype=np.uint8)
+        size = buf.size
+        body_end = (size - p.file_end_offset
+                    if 0 < p.file_end_offset < size else size)
+        seg_field = resolve_segment_id_field(p, self.copybook)
+        is_hierarchical, root_segment_id = self._index_split_config()
+        root_ids = (set(root_segment_id.split(","))
+                    if is_hierarchical and seg_field is not None else None)
+        cuts = _IndexCuts(p)
+        # the open entry: its first byte, and its first record's number
+        # in the file and in the index
+        opened, first, record_index = 0, 0, 0
+        record_bytes = 0.0  # a record of the last window; none yet: 0
+        span = 0
+        while True:
+            if cuts.per is None:
+                due = cuts.subtracted + cuts.mb - opened
+            else:
+                due = (cuts.chunk_start_counted + cuts.per - cuts.base
+                       - first) * record_bytes
+            span = max(max(int(due), 0) + INDEX_WINDOW_SLACK, 2 * span)
+            final = opened + span >= body_end
+            window = buf[opened:] if final else buf[opened:opened + span]
+            header = p.file_start_offset if opened == 0 else 0
+            footer = p.file_end_offset if final else 0
+            try:
+                with Stage("plan_index.scan"):
+                    offsets, lengths, seg_bytes = self._rdw_walk(
+                        window, header, footer, seg_field)
+            except ValueError:
+                # a header the walk cannot follow, at a place counted from
+                # the window's start: the walk of the whole image, which
+                # is generate_index_fast's, meets it and says where
+                native.rdw_scan(data, p.is_rdw_big_endian,
+                                self._rdw_length_adjustment(),
+                                p.file_start_offset, p.file_end_offset)
+                raise
+            if not final:
+                # the window may end inside its last record: the next
+                # window, or this one walked again, reads it whole
+                offsets, lengths = offsets[:-1], lengths[:-1]
+                if seg_bytes is not None:
+                    seg_bytes = seg_bytes[:-1]
+            n = len(offsets)
+            starts = offsets + (opened - 4)  # RDW header precedes the payload
+            window_at, window_first = opened, first
+
+            def next_root(i: int) -> Optional[int]:
+                if root_ids is None:
+                    return i
+                for k in range(i - window_first, n, ROOT_SEARCH_RECORDS):
+                    to = k + ROOT_SEARCH_RECORDS
+                    with Stage("plan_index.seg_ids"):
+                        ids = self._segment_ids_vectorized(
+                            window, offsets[k:to], lengths[k:to], seg_field,
+                            field_bytes=(None if seg_bytes is None
+                                         else seg_bytes[k:to]))
+                    roots = np.nonzero(ids.mask_of(root_ids))[0]
+                    if len(roots):
+                        return window_first + k + int(roots[0])
+                return None
+
+            def framed(a: int, b: int) -> FramedRecords:
+                # offsets from the entry's first byte: the window's, or
+                # the header of the record that opened it
+                shift = int(starts[a]) - window_at if a else 0
+                return FramedRecords(
+                    offsets[a:b] - shift if shift else offsets[a:b],
+                    lengths[a:b],
+                    None if seg_bytes is None else seg_bytes[a:b])
+
+            # every record of a window that gave one up may open an
+            # entry; in the file's last window the rule of
+            # generate_index_fast holds
+            last_candidate = first + n - 1
+            if final and p.file_end_offset <= 0:
+                last_candidate -= 1
+            a = 0
+            while (split_at := cuts.next_cut(starts, first, next_root,
+                                             last_candidate)) is not None:
+                b = split_at - first
+                cut = int(starts[b])
+                yield (SparseIndexEntry(opened, cut, file_id, record_index),
+                       framed(a, b))
+                a, opened, record_index = b, cut, split_at + cuts.base
+            if final:
+                yield (SparseIndexEntry(opened, -1, file_id, record_index),
+                       framed(a, n))
+                return
+            first += a
+            if n > 1:
+                record_bytes = (int(starts[-1]) - int(starts[0])) / (n - 1)
+            if a:
+                span = 0  # the entry now open gets a window of its own
 
     # -- framing -----------------------------------------------------------
 
@@ -838,25 +1025,24 @@ class VarLenReader:
         return self.params.supports_fast_framing
 
     def _frame_fast(self, stream: SimpleStream, ledger=None,
-                    stage_times=None):
+                    stage_times=None,
+                    framed: Optional[FramedRecords] = None):
         """Whole-shard RDW framing via the native scanner. Returns
         (data, base_offset, offsets, lengths, segment_ids, corrupt_reasons)
         or None when the configuration needs the generic per-record
         reader. `corrupt_reasons` maps kept malformed record positions to
         reasons (permissive policy only; empty otherwise). `stage_times`:
         optional StageTimes — the bulk byte materialization is attributed
-        to "read", the header scan + segment-id decode to "frame"."""
-        from .. import native
-
+        to "read", the header scan + segment-id decode to "frame".
+        `framed`: the shard's record table and id bytes where the index
+        pass walked them (`frame_index_fast`): the scan is skipped, all
+        else is done."""
         if not self.supports_fast_framing:
             return None
         p = self.params
         base = stream.offset
         with timed_stage(stage_times, "read"):
             data = stream.next_view(stream.size() - base)
-        adjustment = p.rdw_adjustment
-        if p.is_rdw_part_of_record_length:
-            adjustment -= 4
         # the file-header region rule only applies at the file start, the
         # footer rule only when this shard reaches the file's true end (an
         # indexed shard ending mid-file has a data tail, not a footer)
@@ -867,34 +1053,22 @@ class VarLenReader:
         with timed_stage(stage_times, "frame"):
             seg_field = resolve_segment_id_field(p, self.copybook)
             seg_bytes = None
-            if p.is_permissive:
+            if framed is not None:
+                offsets, lengths, seg_bytes = (
+                    framed.offsets, framed.lengths, framed.seg_bytes)
+            elif p.is_permissive:
                 from .recovery import rdw_scan_permissive
 
                 offsets, lengths, corrupt_reasons = rdw_scan_permissive(
-                    data, p.is_rdw_big_endian, adjustment, file_header,
+                    data, p.is_rdw_big_endian,
+                    self._rdw_length_adjustment(), file_header,
                     file_footer, p.record_error_policy,
                     p.resync_window_bytes,
                     ledger if ledger is not None else p.new_diagnostics(),
                     file_name=stream.input_file_name, base_offset=base)
             else:
-                fused = None
-                if seg_field is not None:
-                    # fused frame + segment-id gather: one native walk
-                    # emits the record table AND each record's id-field
-                    # bytes, replacing rdw_scan + a whole-file
-                    # pack_records re-walk (None = no native library)
-                    fused = native.rdw_scan_segids(
-                        data, p.is_rdw_big_endian,
-                        p.start_offset + seg_field.binary_properties.offset,
-                        seg_field.binary_properties.actual_size,
-                        adjustment, file_header, file_footer)
-                if fused is not None:
-                    offsets, lengths, seg_bytes = fused
-                    count_pass("fused_frame")
-                else:
-                    offsets, lengths = native.rdw_scan(
-                        data, p.is_rdw_big_endian, adjustment, file_header,
-                        file_footer)
+                offsets, lengths, seg_bytes = self._rdw_walk(
+                    data, file_header, file_footer, seg_field)
             segment_ids: Optional[List[str]] = None
             if seg_field is not None:
                 segment_ids = self._segment_ids_vectorized(
@@ -1109,12 +1283,15 @@ class VarLenReader:
                              segment_id_prefix: Optional[str] = None,
                              start_record_id: int = 0,
                              starting_file_offset: int = 0,
-                             stage_times=None) -> FileResult:
+                             stage_times=None,
+                             framed: Optional[FramedRecords] = None
+                             ) -> FileResult:
         """Frame all records, pack per-active-segment padded batches, decode
         with the batched kernels; rows/Arrow are materialized lazily from
         the FileResult. `stage_times`: optional profiling.StageTimes —
         the pipeline engine passes it to attribute read/frame/decode busy
-        time."""
+        time. `framed`: the stream's records as the index pass framed
+        them (only on the route `index.preframed_route` chose)."""
         params = self.params
         ledger = params.new_diagnostics() if params.is_permissive else None
         result = FileResult(
@@ -1183,7 +1360,7 @@ class VarLenReader:
                     result, self._output_schema())
             return result
         fast = self._frame_fast(stream, ledger=ledger,
-                                stage_times=stage_times)
+                                stage_times=stage_times, framed=framed)
         if fast is not None:
             data, base, offsets, lengths, segment_ids, reasons = fast
             result.records_framed = len(offsets)

@@ -272,3 +272,387 @@ class TestShardFooterRule:
         reader = VarLenReader(MULTISEG_COPYBOOK, params)
         _, root_id = reader._index_split_config()
         assert set(root_id.split(",")) == {"C", "D"}
+
+
+# ---------------------------------------------------------------------------
+# The second route of the indexed scan: a dense RDW file is framed once, by
+# its index pass, and a shard starts with its slice of the pass's tables as
+# soon as its cut is found (reader.index.preframed_route,
+# VarLenReader.frame_index_fast, engine.chunks.preframed_var_len_chunks).
+# The parent's route, index whole and every shard framing itself, is the
+# reference: same entries, same tables.
+# ---------------------------------------------------------------------------
+import functools
+
+from cobrix_tpu import native
+from cobrix_tpu.api import parse_options
+from cobrix_tpu.engine import chunks as engine_chunks
+from cobrix_tpu.profiling import DeviceStats
+from cobrix_tpu.reader import index as reader_index
+from cobrix_tpu.reader import var_len_reader as vlr
+from cobrix_tpu.reader.stream import open_stream
+
+
+@functools.lru_cache(maxsize=None)
+def _rdw_file(n_roots: int, children: int, big_endian: bool = False,
+              stored_less: int = 0, header: bytes = b"",
+              footer: bytes = b"", tail_children: int = 0) -> bytes:
+    """`_multiseg_file` with the RDW written as the options under test
+    read it: the header holds the payload's length less `stored_less`;
+    `tail_children` more 'P' records follow the last root's own."""
+    def record(body: str) -> bytes:
+        value = (len(body) - stored_less).to_bytes(2, "big" if big_endian
+                                                   else "little")
+        rdw = value + b"\0\0" if big_endian else b"\0\0" + value
+        return rdw + ebcdic_encode(body)
+
+    out = [header]
+    for r in range(n_roots):
+        out.append(record(f"{'C' if r % 2 == 0 else 'D'}COMP{r:04d}"))
+        out += [record(f"PPHONE{r % 1000:03d}{c % 10:01d}")
+                for c in range(children)]
+    out += [record(f"PPHONE999{c % 10:01d}") for c in range(tail_children)]
+    return b"".join(out + [footer])
+
+
+NO_LEVELS = {k: v for k, v in MULTISEG_OPTS.items()
+             if not k.startswith("segment_id_level")}
+PLAIN = dict(is_record_sequence="true", generate_record_id="true",
+             schema_retention_policy="collapse_root")
+
+# id -> (arguments of _rdw_file, read_cobol options)
+PREFRAMED_CASES = {
+    "le_records_at_roots": ((40, 3), dict(MULTISEG_OPTS,
+                                          input_split_records="7")),
+    "be_records_at_roots": ((40, 3, True), dict(
+        MULTISEG_OPTS, input_split_records="7", is_rdw_big_endian="true")),
+    "rdw_adjustment": ((40, 3, False, 2), dict(
+        MULTISEG_OPTS, input_split_records="9", rdw_adjustment="2")),
+    "rdw_part_of_record_length": ((40, 3, True, -4), dict(
+        MULTISEG_OPTS, input_split_records="9", is_rdw_big_endian="true",
+        is_rdw_part_of_record_length="true")),
+    "file_header_and_footer": ((40, 3, False, 0, b"HDRBYTES", b"FTRBYTES"),
+                               dict(MULTISEG_OPTS, input_split_records="7",
+                                    file_start_offset="8",
+                                    file_end_offset="8")),
+    "file_header_no_segments": ((40, 3, False, 0, b"HDRBYTES"), dict(
+        PLAIN, input_split_records="4", file_start_offset="8")),
+    "size_split_at_roots": ((70000, 3), dict(MULTISEG_OPTS,
+                                             input_split_size_mb="1")),
+    "size_split_no_segments": ((70000, 3), dict(PLAIN,
+                                                input_split_size_mb="1")),
+    "segment_ids_without_levels": ((40, 3), dict(NO_LEVELS,
+                                                 input_split_records="6")),
+    "segment_id_filter": ((40, 3), dict(MULTISEG_OPTS, segment_filter="P",
+                                        input_split_records="10")),
+    "a_root_exactly_at_each_cut": ((40, 3), dict(MULTISEG_OPTS,
+                                                 input_split_records="4")),
+    "a_tail_without_roots": ((12, 2, False, 0, b"", b"", 300), dict(
+        MULTISEG_OPTS, input_split_records="5")),
+    "one_entry_for_the_file": ((40, 3), dict(MULTISEG_OPTS,
+                                             input_split_records="100000")),
+}
+
+
+def _case(name: str, tmp_path):
+    args, options = PREFRAMED_CASES[name]
+    path = tmp_path / f"{name}.bin"
+    path.write_bytes(_rdw_file(*args))
+    return str(path), options
+
+
+@pytest.fixture
+def parents_route(monkeypatch):
+    """A callable that sends every file down the parent's route from then
+    on: no record is short enough for the other."""
+    return lambda: monkeypatch.setattr(reader_index,
+                                       "PREFRAMED_MAX_MEAN_RECORD", 0)
+
+
+def _shard_counts(data):
+    stats = data.metrics.device_stats
+    return stats.preframed_shards, stats.self_framed_shards
+
+
+@pytest.mark.parametrize("slack", [48, 512, vlr.INDEX_WINDOW_SLACK],
+                         ids=["slack48", "slack512", "slack1m"])
+@pytest.mark.parametrize("name", sorted(PREFRAMED_CASES))
+def test_preframed_index_is_the_parents_index_with_each_shards_tables(
+        name, slack, tmp_path, monkeypatch):
+    """Entry for entry what `generate_index_fast` and the per-record
+    generator give, and for each entry the tables that `_frame_fast`
+    scans off the entry's own byte range; whatever the windows' length
+    (48 B of slack: windows that must grow; 1 MiB: the file in one)."""
+    monkeypatch.setattr(vlr, "INDEX_WINDOW_SLACK", slack)
+    path, options = _case(name, tmp_path)
+    params, _ = parse_options(dict(options))
+    reader = VarLenReader(MULTISEG_COPYBOOK, params)
+    image = open(path, "rb").read()
+    handed = list(reader.frame_index_fast(image, 7))
+    entries = [entry for entry, _ in handed]
+    assert entries == reader.generate_index_fast(image, 7)
+    assert entries == reader.generate_index(MemoryStream(image), 7)
+    for entry, framed in handed:
+        nbytes = (0 if entry.offset_to < 0
+                  else entry.offset_to - entry.offset_from)
+        with open_stream(path, start_offset=entry.offset_from,
+                         maximum_bytes=nbytes) as stream:
+            _, _, offsets, lengths, segment_ids, _ = reader._frame_fast(
+                stream)
+        assert np.array_equal(framed.offsets, offsets)
+        assert np.array_equal(framed.lengths, lengths)
+        # and handed to _frame_fast, the same tables and the same ids
+        with open_stream(path, start_offset=entry.offset_from,
+                         maximum_bytes=nbytes) as stream:
+            _, _, offsets2, lengths2, handed_ids, _ = reader._frame_fast(
+                stream, framed=framed)
+        assert offsets2 is framed.offsets and lengths2 is framed.lengths
+        if segment_ids is None:
+            assert framed.seg_bytes is None and handed_ids is None
+        else:
+            assert len(framed.seg_bytes) == len(offsets)
+            assert handed_ids.uniq == segment_ids.uniq
+            assert np.array_equal(handed_ids.codes, segment_ids.codes)
+    # every record of the file in exactly one entry
+    all_offsets, _ = native.rdw_scan(
+        image, params.is_rdw_big_endian, reader._rdw_length_adjustment(),
+        params.file_start_offset, params.file_end_offset)
+    assert sum(len(f.offsets) for _, f in handed) == len(all_offsets)
+
+
+@pytest.mark.parametrize("parallelism", ["1", "4"])
+@pytest.mark.parametrize("name", sorted(PREFRAMED_CASES))
+def test_preframed_read_equals_the_parents_read(name, parallelism, tmp_path,
+                                                parents_route):
+    path, options = _case(name, tmp_path)
+    new = read_cobol(path, copybook_contents=MULTISEG_COPYBOOK,
+                     parallelism=parallelism, **options)
+    table = new.to_arrow()
+    assert _shard_counts(new) == (new.metrics.shards, 0)
+    parents_route()
+    old = read_cobol(path, copybook_contents=MULTISEG_COPYBOOK,
+                     parallelism=parallelism, **options)
+    assert _shard_counts(old) == (0, old.metrics.shards)
+    assert old.metrics.shards == new.metrics.shards
+    assert table.equals(old.to_arrow())
+    assert new.to_rows() == old.to_rows()
+    options = {k: v for k, v in options.items()
+               if not k.startswith("input_split")}
+    whole = read_cobol(path, copybook_contents=MULTISEG_COPYBOOK,
+                       enable_indexes="false", **options)
+    if "file_start_offset" not in options:
+        # (a counted file header shifts an indexed read's Record_Ids:
+        # reference behaviour, IndexGenerator.scala:117-120)
+        assert table.equals(whole.to_arrow())
+
+
+def _sparse_file(n: int = 3000, width: int = 400) -> bytes:
+    body = "C" + "X" * (width - 1)
+    return b"".join(_rdw_le(width) + ebcdic_encode(body) for _ in range(n))
+
+
+SPARSE_COPYBOOK = """
+       01  RECORD.
+           05  SEG-ID        PIC X(1).
+           05  FILLER        PIC X(399).
+"""
+
+
+def _route(path, copybook=MULTISEG_COPYBOOK, io=None, **options):
+    params, _ = parse_options(dict(options))
+    reader = VarLenReader(copybook, params)
+    return reader_index.preframed_route(reader, str(path), params, io)
+
+
+class TestPreframedRule:
+    """The decisions of `preframed_route`, read off the file and the
+    read's configuration; nothing a caller sets chooses the route."""
+
+    def test_a_dense_file_is_framed_once(self, tmp_path):
+        path = tmp_path / "dense.bin"
+        path.write_bytes(_rdw_file(40, 3))
+        assert _route(path, input_split_records="7", **MULTISEG_OPTS)
+        data = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                          input_split_records="7", **MULTISEG_OPTS)
+        assert _shard_counts(data) == (data.metrics.shards, 0)
+        assert data.metrics.shards > 3
+
+    def test_a_sparse_file_is_not(self, tmp_path):
+        path = tmp_path / "sparse.bin"
+        path.write_bytes(_sparse_file())
+        options = dict(is_record_sequence="true", input_split_records="500")
+        assert not _route(path, SPARSE_COPYBOOK, **options)
+        data = read_cobol(str(path), copybook_contents=SPARSE_COPYBOOK,
+                          **options)
+        assert _shard_counts(data) == (0, data.metrics.shards)
+        assert data.metrics.shards == 6
+
+    def test_the_constant_lies_between_the_two_cells(self):
+        # exp2's records are 64-68 B, the orders' 722 B at the mean
+        assert 70 < reader_index.PREFRAMED_MAX_MEAN_RECORD < 700
+
+    def test_density_is_read_off_the_first_mebibyte_only(self, tmp_path):
+        dense_head = _rdw_file(70000, 3) + _sparse_file(200)
+        assert len(_rdw_file(70000, 3)) > reader_index.PREFRAMED_PROBE_BYTES
+        path = tmp_path / "dense_head.bin"
+        path.write_bytes(dense_head)
+        options = dict(is_record_sequence="true", input_split_size_mb="1")
+        assert _route(path, **options)
+        path.write_bytes(_sparse_file(3000) + _rdw_file(40, 3))
+        assert not _route(path, **options)
+
+    def test_a_head_shorter_than_the_probe_is_walked_whole(self, tmp_path):
+        path = tmp_path / "short.bin"
+        path.write_bytes(_rdw_file(40, 3, False, 0, b"HDRBYTES",
+                                   b"FTRBYTES"))
+        options = dict(MULTISEG_OPTS, input_split_records="7",
+                       file_start_offset="8", file_end_offset="8")
+        assert path.stat().st_size < reader_index.PREFRAMED_PROBE_BYTES
+        assert _route(path, **options)
+        # two records: the mean is theirs, the footer is not walked
+        path.write_bytes(_rdw_file(1, 1, False, 0, b"HDRBYTES", b"FTRBYTES"))
+        assert _route(path, **options)
+
+    def test_a_file_one_shard_covers_is_not(self, tmp_path):
+        path = tmp_path / "small.bin"
+        path.write_bytes(_rdw_file(40, 3))
+        assert not _route(path, **MULTISEG_OPTS)
+        path.write_bytes(b"")
+        assert not _route(path, input_split_records="7", **MULTISEG_OPTS)
+
+    def test_a_head_the_walk_cannot_follow_is_left_to_the_index_pass(
+            self, tmp_path):
+        path = tmp_path / "zero.bin"
+        path.write_bytes(b"\0\0\0\0" + _rdw_file(40, 3))
+        options = dict(MULTISEG_OPTS, input_split_records="7")
+        assert not _route(path, **options)
+        with pytest.raises(ValueError, match="zero size record at 0 "):
+            read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                       **options)
+
+    @pytest.mark.parametrize("policy", ["permissive", "drop_malformed"])
+    def test_a_permissive_policy_is_not(self, tmp_path, policy):
+        path = tmp_path / "dense.bin"
+        path.write_bytes(_rdw_file(40, 3))
+        options = dict(MULTISEG_OPTS, input_split_records="7",
+                       record_error_policy=policy)
+        assert not _route(path, **options)
+        data = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                          **options)
+        assert _shard_counts(data) == (0, data.metrics.shards)
+
+    def test_a_stored_index_is_not(self, tmp_path):
+        path = tmp_path / "dense.bin"
+        path.write_bytes(_rdw_file(40, 3))
+        options = dict(MULTISEG_OPTS, input_split_records="7",
+                       cache_dir=str(tmp_path / "cache"))
+        for _ in range(2):  # the pass that saves, the read that loads
+            data = read_cobol(str(path),
+                              copybook_contents=MULTISEG_COPYBOOK, **options)
+            assert _shard_counts(data) == (0, data.metrics.shards)
+            assert data.metrics.shards > 3
+        assert data.metrics.as_dict()["io"]["index_hits"] == 1
+
+    def test_framing_the_native_scan_does_not_do_is_not(self, tmp_path):
+        path = tmp_path / "text.bin"
+        path.write_bytes(_rdw_file(40, 3))
+        assert not _route(path, is_record_sequence="true", is_text="true",
+                          input_split_records="7")
+        hierarchical = dict(
+            is_record_sequence="true", segment_field="SEG-ID",
+            input_split_records="7",
+            **{"redefine-segment-id-map:1": "COMPANY => C,D",
+               "redefine-segment-id-map:2": "CONTACT => P",
+               "segment-children:1": "COMPANY => CONTACT"})
+        assert not _route(path, **hierarchical)
+
+    def test_other_planners_get_entries_only(self, tmp_path, monkeypatch):
+        """The pipelined engine and the multihost executor plan through
+        `plan_var_len_chunks`: a list of WorkShards that carry no table,
+        and the dense route's pass is never run for them."""
+        from cobrix_tpu.parallel.planner import WorkShard
+
+        path = tmp_path / "dense.bin"
+        path.write_bytes(_rdw_file(40, 3))
+        options = dict(MULTISEG_OPTS, input_split_records="7")
+        params, _ = parse_options(dict(options))
+        reader = VarLenReader(MULTISEG_COPYBOOK, params)
+        planned = engine_chunks.plan_var_len_chunks(reader, [str(path)],
+                                                    params)
+        assert isinstance(planned, list) and len(planned) > 3
+        assert all(type(shard) is WorkShard for shard in planned)
+        handed = list(engine_chunks.preframed_var_len_chunks(
+            reader, [str(path)], params))
+        assert [shard for shard, _ in handed] == planned
+        assert all(framed is not None for _, framed in handed)
+
+        def never(*args, **kwargs):
+            raise AssertionError("the dense route's pass ran")
+
+        monkeypatch.setattr(VarLenReader, "frame_index_fast", never)
+        whole = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                           enable_indexes="false", **MULTISEG_OPTS)
+        piped = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                           pipeline_workers="2", **options)
+        assert _shard_counts(piped) == (0, 0)
+        assert piped.to_arrow().equals(whole.to_arrow())
+        hosts = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                           hosts="2", **options)
+        assert _shard_counts(hosts) == (0, 0)
+        assert hosts.to_arrow().equals(whole.to_arrow())
+
+    def test_a_read_of_dense_and_sparse_files_routes_each(self, tmp_path):
+        (tmp_path / "in").mkdir()
+        wide = "C" + "W" * 299
+        (tmp_path / "in" / "a.bin").write_bytes(_rdw_file(40, 3))
+        (tmp_path / "in" / "b.bin").write_bytes(b"".join(
+            _rdw_le(300) + ebcdic_encode(wide) for _ in range(30)))
+        (tmp_path / "in" / "c.bin").write_bytes(_rdw_file(20, 2))
+        copybook = """
+       01  RECORD.
+           05  SEG-ID        PIC X(1).
+           05  COMPANY.
+               10  NAME      PIC X(8).
+           05  CONTACT REDEFINES COMPANY.
+               10  PHONE     PIC X(9).
+           05  FILLER        PIC X(290).
+"""
+        options = dict(MULTISEG_OPTS, input_split_records="8")
+        data = read_cobol(str(tmp_path / "in"), copybook_contents=copybook,
+                          **options)
+        table = data.to_arrow()
+        preframed, self_framed = _shard_counts(data)
+        assert preframed > 5 and self_framed > 2
+        assert preframed + self_framed == data.metrics.shards
+        whole = read_cobol(str(tmp_path / "in"), copybook_contents=copybook,
+                           enable_indexes="false", **MULTISEG_OPTS)
+        assert table.equals(whole.to_arrow())
+
+
+def test_the_shard_counts_ride_the_device_record():
+    stats = DeviceStats()
+    assert "preframed_shards" not in stats.as_dict()
+    stats.note_shard(preframed=True)
+    stats.note_shard(preframed=False)
+    stats.note_shard(preframed=True)
+    said = stats.as_dict()
+    assert (said["preframed_shards"], said["self_framed_shards"]) == (2, 1)
+
+
+def test_a_bad_header_past_the_first_window_is_named_where_it_lies(
+        tmp_path, monkeypatch):
+    """The pass walks windows; the error it raises counts from the file's
+    first byte, as the parent's pass counts."""
+    monkeypatch.setattr(vlr, "INDEX_WINDOW_SLACK", 64)
+    good = _rdw_file(40, 3)
+    path = tmp_path / "bad.bin"
+    path.write_bytes(good + b"\0\0\0\0" + _rdw_file(4, 1))
+    options = dict(MULTISEG_OPTS, input_split_records="7")
+    with pytest.raises(ValueError,
+                       match=f"zero size record at {len(good)} ") as new:
+        read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK, **options)
+    monkeypatch.setattr(reader_index, "PREFRAMED_MAX_MEAN_RECORD", 0)
+    with pytest.raises(ValueError) as old:
+        read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK, **options)
+    assert str(new.value) == str(old.value)
+    assert new.value.offset == old.value.offset == len(good)

@@ -431,12 +431,24 @@ def test_a_list_of_strings_is_built_slot_by_slot(tmp_path):
             device["set_rows"]) == (0, 0, {})
 
 
+@pytest.mark.parametrize("route", ["index_whole", "framed_once"])
 @pytest.mark.parametrize("backend", ["pallas", "numpy"])
-def test_plan_index_counts_its_header_scan_and_its_segment_ids(tmp_path,
-                                                               backend):
-    """A multisegment RDW file cut at roots: the header scan of the whole
-    file and the segment id of every record are stages beneath
-    `plan_index`, once a file, whatever kernels decode the shards."""
+def test_plan_index_counts_its_header_scan_and_its_segment_ids(
+        tmp_path, monkeypatch, backend, route):
+    """A multisegment RDW file cut at roots: the header scan and the
+    segment ids are stages beneath `plan_index` whatever kernels decode
+    the shards, and on both routes of the indexed scan: once a file where
+    the index is planned whole and every shard frames itself; once a
+    window, and the ids only where a root is looked for, where the pass
+    is the file's one framing (exp2's 64 B records choose that route
+    themselves; no record is short enough once the constant is 0)."""
+    from cobrix_tpu.reader import index, var_len_reader
+
+    if route == "index_whole":
+        monkeypatch.setattr(index, "PREFRAMED_MAX_MEAN_RECORD", 0)
+    else:
+        # windows of a few hundred records, not the file in one
+        monkeypatch.setattr(var_len_reader, "INDEX_WINDOW_SLACK", 512)
     path = tmp_path / "companies.dat"
     path.write_bytes(generate_exp2(1300, seed=34))
     data = read_cobol(
@@ -446,15 +458,32 @@ def test_plan_index_counts_its_header_scan_and_its_segment_ids(tmp_path,
         redefine_segment_id_map_1="CONTACTS => P", segment_id_level0="C",
         segment_id_level1="P", segment_id_prefix="A",
         input_split_records="300")
-    assert data.metrics.shards >= 3
+    shards = data.metrics.shards
+    assert shards >= 3
     stats = data.metrics.device_stats
     assert stats.stage_n["plan_index"] == 1
-    assert stats.stage_n["plan_index.scan"] == 1
-    assert stats.stage_n["plan_index.seg_ids"] == 1
-    # self time: the children are not counted in the parent again
+    if route == "index_whole":
+        assert stats.stage_n["plan_index.scan"] == 1
+        assert stats.stage_n["plan_index.seg_ids"] == 1
+        assert stats.stage_n["frame"] == shards
+        assert (stats.preframed_shards, stats.self_framed_shards) == (
+            0, shards)
+    else:
+        # a window an entry at the least, a root search a cut
+        assert stats.stage_n["plan_index.scan"] >= shards
+        assert stats.stage_n["plan_index.seg_ids"] >= shards - 1
+        # the shards still code their ids, in `frame`, off the pass
+        assert stats.stage_n["frame"] == shards
+        assert (stats.preframed_shards, stats.self_framed_shards) == (
+            shards, 0)
+    # self time: the children are not counted in the parent again, and
+    # beside running shards the three take no more than their wall
     beneath = sum(s for name, s in stats.stage_s.items()
                   if name.startswith("plan_index"))
     assert beneath <= data.metrics.timings_s["plan_index"] + 1e-6
+    assert set(name for name in stats.stage_s
+               if name.startswith("plan_index")) == {
+        "plan_index", "plan_index.scan", "plan_index.seg_ids"}
 
 
 def test_a_pipelined_read_counts_on_its_stage_threads(exp3_file):
