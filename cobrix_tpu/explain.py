@@ -308,8 +308,16 @@ def _execution_plan(params, files: List[str], total_bytes: int,
     if params.cache_dir:
         plan["cache_dir"] = params.cache_dir
     if copybook is not None and mode == "variable-length":
-        from .reader.var_len_reader import variable_occurs_route
+        from .reader.var_len_reader import (hierarchical_route,
+                                            variable_occurs_route)
 
+        # segment-children: assembled in columns, or by a record walk,
+        # and why
+        route = hierarchical_route(copybook, params)
+        if route is not None:
+            plan["hierarchical"] = route["route"]
+            if route["reason"]:
+                plan["hierarchical_reason"] = route["reason"]
         # variable_size_occurs: batched through the plan's regions, or
         # walked record by record, and why
         route = variable_occurs_route(copybook, params)

@@ -319,7 +319,14 @@ class DeviceStats:
     (`odo_fallback_records`); and for the threaded indexed scan (present
     only where it ran; api._scan_var_len): the shards that got their
     records' tables from the index pass (`preframed_shards`) and those
-    that framed themselves (`self_framed_shards`).
+    that framed themselves (`self_framed_shards`); and for a hierarchical
+    read (`hier_*`, present only then; reader/hierarchical_arrow.py): the
+    root rows `hierarchical_table` assembled (`hier_roots`), the records
+    it assembled them from (`hier_records`), the child structs it put
+    under a parent (`hier_children`), the child records that found none
+    (`hier_orphans`), the roots the record walk assembled because the
+    columnar assembly declined (`hier_row_path_roots`) and why the last
+    of them did (`hier_decline_reason`).
     The link home, noted where a launch is fetched (`ColumnarDecoder.
     _fetch_block`; `LinkCopy`): `d2h_copy_thread_s`, the fetching
     threads' own `perf_counter` seconds bringing ready outputs home,
@@ -371,6 +378,14 @@ class DeviceStats:
         # index shards of the threaded scan (api._scan_var_len)
         self.preframed_shards = 0
         self.self_framed_shards = 0
+        # hierarchical assembly (hierarchical_arrow.hierarchical_table,
+        # VarLenReader.read_result_columnar)
+        self.hier_roots = 0
+        self.hier_records = 0
+        self.hier_children = 0
+        self.hier_orphans = 0
+        self.hier_row_path_roots = 0
+        self.hier_decline_reason: Optional[str] = None
         # route counts of every decode program launched, by identity: a
         # read launches one decoder's program many times
         self._program_groups: Dict[int, Dict[str, int]] = {}
@@ -493,6 +508,39 @@ class DeviceStats:
             else:
                 self.self_framed_shards += 1
 
+    def note_hier(self, roots: int = 0, records: int = 0,
+                  children: int = 0, orphans: int = 0,
+                  row_path_roots: int = 0,
+                  reason: Optional[str] = None) -> None:
+        """One shard of a hierarchical read: `roots` rows assembled in
+        columns from `records` records, `children` child structs put
+        under a parent and `orphans` child records left under none; or
+        `row_path_roots` roots left to the record walk, for `reason`."""
+        with self._lock:
+            self.hier_roots += roots
+            self.hier_records += records
+            self.hier_children += children
+            self.hier_orphans += orphans
+            self.hier_row_path_roots += row_path_roots
+            if reason is not None:
+                self.hier_decline_reason = reason
+
+    @property
+    def hier(self) -> dict:
+        """The `hier_*` counts, or {} for a read that assembled no
+        hierarchical row."""
+        with self._lock:
+            if not (self.hier_records or self.hier_row_path_roots):
+                return {}
+            counts = {"hier_roots": self.hier_roots,
+                      "hier_records": self.hier_records,
+                      "hier_children": self.hier_children,
+                      "hier_orphans": self.hier_orphans,
+                      "hier_row_path_roots": self.hier_row_path_roots}
+            if self.hier_decline_reason is not None:
+                counts["hier_decline_reason"] = self.hier_decline_reason
+            return counts
+
     @property
     def odo(self) -> Dict[str, int]:
         """The `odo_*` counts, or {} for a read that met no variable-size
@@ -524,6 +572,7 @@ class DeviceStats:
     def as_dict(self) -> dict:
         device_groups = self.device_groups
         odo = self.odo
+        hier = self.hier
         with self._lock:
             query = {} if not self.query_chunks else {
                 "query_chunks": self.query_chunks,
@@ -538,6 +587,7 @@ class DeviceStats:
             return {
                 **query,
                 **odo,
+                **hier,
                 **shards,
                 "device_groups": device_groups,
                 "launches": {f"{b}x{e}": n for (b, e), n
@@ -819,6 +869,9 @@ class ReadMetrics:
             # on every backend: the host kernels and the row path launch
             # nothing and still say what they did with the regions
             out["odo"] = odo
+        hier = self.device_stats.hier
+        if hier:
+            out["hier"] = hier
         roof = self.roofline()
         if roof is not None:
             out["roofline"] = roof

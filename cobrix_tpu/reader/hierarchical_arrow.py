@@ -20,6 +20,14 @@ a different id, double-attaching their children) — such shapes bail to
 the row path. Record_Id parity: each assembled root row is stamped with
 the id of the record that TRIGGERS its flush — the next root, or one past
 the last record at end of stream.
+
+Stages (profiling.Stage, on the read's DeviceStats through the batch's
+captured reference): `assemble.hier` round a shard's whole assembly and,
+inside `segment_struct`, once a struct of the tree and never a record,
+`assemble.hier.assign` (positions by segment, child-to-parent assignment,
+list offsets) and `assemble.hier.leaves` (the struct's leaf builds and
+`take`s, with `assemble.string` / `.scalar` / `.decimal` nested where
+they fire). Counts: `DeviceStats.note_hier`.
 """
 from __future__ import annotations
 
@@ -29,6 +37,7 @@ import numpy as np
 
 from ..copybook.ast import Group, Primitive
 from ..copybook.datatypes import SchemaRetentionPolicy
+from ..profiling import Stage
 from .arrow_out import _pa
 
 
@@ -78,6 +87,30 @@ def _depending_crosses_segment(copybook) -> bool:
                if isinstance(root, Group))
 
 
+def decline_reason(copybook, sid_map: Dict[str, Group],
+                   parent_child_map: Dict[str, list],
+                   root_names: set) -> Optional[str]:
+    """Why `hierarchical_table` leaves this hierarchy to the row path, or
+    None where it assembles it. Read off the copybook and the maps alone,
+    so `explain` can say it before any data is read."""
+    # non-root parent types fed by multiple segment ids diverge from the
+    # oracle's sid-level break rule (see module docstring)
+    sids_per_name: Dict[str, int] = {}
+    for _sid, g in sid_map.items():
+        sids_per_name[g.name] = sids_per_name.get(g.name, 0) + 1
+    for name, count in sids_per_name.items():
+        if count > 1 and name not in root_names and name in parent_child_map:
+            return (f"the non-root parent segment {name} is mapped from "
+                    f"{count} segment ids")
+    # DEPENDING ON arrays whose dependee lives in a different visibility
+    # region (shared area vs a segment redefine overlay): the row path
+    # owns the oracle's cross-record dependee semantics
+    if _depending_crosses_segment(copybook):
+        return ("an OCCURS DEPENDING ON array inside a segment redefine "
+                "names a dependee outside that redefine")
+    return None
+
+
 def hierarchical_table(batch, segment_names,
                        copybook, output_schema,
                        sid_map: Dict[str, Group],
@@ -91,25 +124,23 @@ def hierarchical_table(batch, segment_names,
     unmapped ids) or the dictionary-coded pair (uniq_names, codes
     ndarray) straight from SegmentIds. Returns None when the shape needs
     the row path."""
+    if decline_reason(copybook, sid_map, parent_child_map,
+                      root_names) is not None:
+        return None
+    with Stage("assemble.hier", batch.stage_stats):
+        return _assemble(batch, segment_names, copybook, output_schema,
+                         sid_map, parent_child_map, root_names, file_id,
+                         start_record_id, input_file_name)
+
+
+def _assemble(batch, segment_names, copybook, output_schema, sid_map,
+              parent_child_map, root_names, file_id, start_record_id,
+              input_file_name):
     from .arrow_out import ArrowBatchBuilder, arrow_schema
 
     pa = _pa()
     n = batch.n_records
-
-    # non-root parent types fed by multiple segment ids diverge from the
-    # oracle's sid-level break rule (see module docstring)
-    sids_per_name: Dict[str, int] = {}
-    for _sid, g in sid_map.items():
-        sids_per_name[g.name] = sids_per_name.get(g.name, 0) + 1
-    for name, count in sids_per_name.items():
-        if count > 1 and name not in root_names and name in parent_child_map:
-            return None
-
-    # DEPENDING ON arrays whose dependee lives in a different visibility
-    # region (shared area vs a segment redefine overlay): bail to the row
-    # path, which owns the oracle's cross-record dependee semantics
-    if _depending_crosses_segment(copybook):
-        return None
+    stats = batch.stage_stats
 
     # integer-coded segment names: every membership test below runs on an
     # int32 code vector (object-dtype string compares/np.isin dominated
@@ -159,12 +190,14 @@ def hierarchical_table(batch, segment_names,
             cur = parent_of.get(cur)
         return out
 
-    positions_of = {name: np.nonzero(mask_of([name]))[0]
-                    for name in {g.name for g in sid_map.values()}}
-    root_pos_list = [positions_of.get(name, np.zeros(0, dtype=np.int64))
-                     for name in root_names]
-    roots = (np.sort(np.concatenate(root_pos_list)) if root_pos_list
-             else np.zeros(0, dtype=np.int64))
+    with Stage("assemble.hier.assign", stats):
+        positions_of = {name: np.nonzero(mask_of([name]))[0]
+                        for name in {g.name for g in sid_map.values()}}
+        root_pos_list = [
+            positions_of.get(name, np.zeros(0, dtype=np.int64))
+            for name in root_names]
+        roots = (np.sort(np.concatenate(root_pos_list)) if root_pos_list
+                 else np.zeros(0, dtype=np.int64))
     if roots.size == 0:
         return arrow_schema(output_schema.schema).empty_table()
 
@@ -236,50 +269,59 @@ def hierarchical_table(batch, segment_names,
         offsets[-1] = offsets_own[-1]
         return offsets
 
+    kept_children = 0
+
     def segment_struct(group: Group, positions: np.ndarray,
                        null_mask: Optional[np.ndarray] = None):
         """StructArray of `group` at `positions` (child segments nested as
         list<struct> fields, schema order). `null_mask`: True where the
         struct itself is null (rows of positions owned by a sibling
         redefine — their decoded bytes are garbage by design)."""
-        arrays, field_names = [], []
+        nonlocal kept_children
         owned = None if null_mask is None else ~null_mask
-        idx = pa.array(positions.astype(np.int64))
-        # all of this struct's string leaves in ONE subset kernel call
-        built_at = builder.leaf_strings_at(
-            [c for c in group.children
-             if isinstance(c, Primitive) and not c.is_filler
-             and not c.is_array], positions)
-        for child in group.children:
-            if child.is_filler:
+        # this struct's own fields, schema order; child segments are
+        # nested below them
+        fields = [c for c in group.children if not c.is_filler
+                  and not (isinstance(c, Group)
+                           and c.parent_segment is not None)]
+        leaves: Dict[int, object] = {}
+        with Stage("assemble.hier.leaves", stats):
+            idx = pa.array(positions.astype(np.int64))
+            # all of this struct's string leaves in ONE subset kernel call
+            built_at = builder.leaf_strings_at(
+                [c for c in fields
+                 if isinstance(c, Primitive) and not c.is_array], positions)
+            for child in fields:
+                if isinstance(child, Group) and child.is_segment_redefine:
+                    continue
+                arr = None
+                if isinstance(child, Primitive) and not child.is_array:
+                    # string/numeric leaves build straight at `positions`
+                    # (raw-image subset transcode / numpy gather) — no
+                    # full-length build, no take
+                    arr = built_at.get(id(child))
+                    if arr is None:
+                        arr = builder.leaf_numeric_at(child, positions)
+                leaves[id(child)] = (arr if arr is not None
+                                     else full_array(child).take(idx))
+        arrays, field_names = [], [c.name for c in fields]
+        for child in fields:
+            if id(child) in leaves:
+                arrays.append(leaves[id(child)])
                 continue
-            if isinstance(child, Group) and child.parent_segment is not None:
-                continue  # nested below in schema order
-            if isinstance(child, Group) and child.is_segment_redefine:
-                # a segment redefine nested below this group (the root
-                # case: the AST root holds the root redefines)
+            # a segment redefine nested below this group (the root case:
+            # the AST root holds the root redefines)
+            with Stage("assemble.hier.assign", stats):
                 child_owned = mask_of([child.name])[positions]
-                sub_mask = (None if bool(child_owned.all())
-                            else ~child_owned)
-                arrays.append(segment_struct(child, positions, sub_mask))
-                field_names.append(child.name)
-                continue
-            field_names.append(child.name)
-            arr = None
-            if isinstance(child, Primitive) and not child.is_array:
-                # string/numeric leaves build straight at `positions`
-                # (raw-image subset transcode / numpy gather) — no
-                # full-length build, no take
-                arr = built_at.get(id(child))
-                if arr is None:
-                    arr = builder.leaf_numeric_at(child, positions)
-            arrays.append(arr if arr is not None
-                          else full_array(child).take(idx))
+                sub_mask = None if bool(child_owned.all()) else ~child_owned
+            arrays.append(segment_struct(child, positions, sub_mask))
         for seg in child_segments_of(group):
-            par_pos = positions if owned is None else positions[owned]
-            ch_pos, offs_own = assign_children(seg, par_pos)
-            offsets = (offs_own if owned is None
-                       else expand_offsets(offs_own, owned))
+            with Stage("assemble.hier.assign", stats):
+                par_pos = positions if owned is None else positions[owned]
+                ch_pos, offs_own = assign_children(seg, par_pos)
+                offsets = (offs_own if owned is None
+                           else expand_offsets(offs_own, owned))
+            kept_children += len(ch_pos)
             field_names.append(seg.name)
             arrays.append(pa.ListArray.from_arrays(
                 pa.array(offsets), segment_struct(seg, ch_pos)))
@@ -327,4 +369,10 @@ def hierarchical_table(batch, segment_names,
     arrays = [c.cast(target.field(i).type)
               if c.type != target.field(i).type else c
               for i, c in enumerate(cols)]
+    if stats is not None:
+        # a child record is every mapped record that is no root; one that
+        # found no parent of its direct parent's type is under no row
+        mapped = sum(len(p) for p in positions_of.values())
+        stats.note_hier(roots=n_roots, records=n, children=kept_children,
+                        orphans=mapped - n_roots - kept_children)
     return pa.Table.from_arrays(arrays, schema=target)

@@ -268,6 +268,62 @@ def variable_occurs_route(copybook: Copybook,
                 for r in plan.regions] for active, plan in plans.items()}}
 
 
+def hierarchy_maps(copybook: Copybook, params: ReaderParameters):
+    """(segment id -> redefine group, parent -> child groups, root group
+    names) of a hierarchical read: one source for the scalar walk, the
+    columnar assembly and `hierarchical_route`, so that they cannot
+    disagree on the hierarchy."""
+    seg = params.multisegment
+    redefine_map = seg.segment_id_redefine_map if seg else {}
+    segment_redefines = {g.name: g
+                         for g in copybook.get_all_segment_redefines()}
+    sid_map = {sid: segment_redefines[name]
+               for sid, name in redefine_map.items()
+               if name in segment_redefines}
+    parent_child_map = copybook.get_parent_children_segment_map()
+    root_names = {g.name for g in segment_redefines.values()
+                  if g.parent_segment is None}
+    return sid_map, parent_child_map, root_names
+
+
+def hierarchical_route(copybook: Copybook,
+                       params: ReaderParameters) -> Optional[dict]:
+    """How a read assembles a hierarchical copybook's rows (`segment-
+    children`); None for any other copybook. `route` "columnar": one
+    decode-once batch a shard and `hierarchical_table`'s array assembly;
+    "batched_rows": the batch's values with the nesting walked record by
+    record (the table declined, `decline_reason`); "rows": the scalar
+    record walk, values and all (`_hierarchical_columnar_setup` declined).
+    `reason` says why a read is not "columnar". What the walks assemble is
+    counted in `hier_row_path_roots`."""
+    from .hierarchical_arrow import decline_reason
+
+    if not copybook.is_hierarchical:
+        return None
+    route, reason = "rows", None
+    if params.variable_size_occurs:
+        reason = "variable_size_occurs records are walked one by one"
+    elif resolve_segment_id_field(params, copybook) is None:
+        reason = "no segment id field is resolved"
+    elif params.select:
+        # the scalar oracle ignores column projection; a projected
+        # columnar decode would silently change hierarchical rows
+        reason = "a select= projection is applied by the record walk"
+    elif params.start_offset:
+        # the oracle reads CHILD records at the field's plain offset,
+        # without the record start offset (extract_children / reference
+        # extractChildren) — the uniform decode_raw shift cannot
+        # reproduce that
+        reason = "record_start_offset shifts root records only"
+    elif not params.supports_fast_framing:
+        reason = ("without RDW headers (or with a custom framing) a "
+                  "record's length is only known by walking it")
+    else:
+        reason = decline_reason(copybook, *hierarchy_maps(copybook, params))
+        route = "batched_rows" if reason else "columnar"
+    return {"route": route, "reason": reason}
+
+
 class VarLenReader:
     """Core variable-length reader bound to one copybook + parameters."""
 
@@ -304,6 +360,9 @@ class VarLenReader:
         route = variable_occurs_route(self.copybook, params)
         self.variable_arrays = route is not None
         self.row_path_reason = route["reason"] if route else None
+        # a hierarchical copybook: assembled in columns, or left to one
+        # of the record walks, and why (`hier_row_path_roots`)
+        self.hier_route = hierarchical_route(self.copybook, params)
 
     @property
     def dynamic_occurs_layout(self) -> bool:
@@ -728,20 +787,6 @@ class VarLenReader:
                 input_file_name=stream.input_file_name,
                 options=options)
 
-    def _hierarchy_maps(self):
-        """(segment id -> redefine group, parent -> child groups, root
-        group names) — shared by the scalar and columnar hierarchical
-        paths so they cannot disagree on the hierarchy."""
-        segment_redefines = {g.name: g
-                             for g in self.copybook.get_all_segment_redefines()}
-        sid_map = {sid: segment_redefines[name]
-                   for sid, name in self.segment_redefine_map.items()
-                   if name in segment_redefines}
-        parent_child_map = self.copybook.get_parent_children_segment_map()
-        root_names = {g.name for g in segment_redefines.values()
-                      if g.parent_segment is None}
-        return sid_map, parent_child_map, root_names
-
     def _iter_rows_hierarchical(self, stream: SimpleStream, file_id: int,
                                 start_record_id: int,
                                 starting_file_offset: int,
@@ -750,7 +795,7 @@ class VarLenReader:
         (reference VarLenHierarchicalIterator.fetchNext :99)."""
         params = self.params
         segment_id_redefine_map, parent_child_map, root_names = \
-            self._hierarchy_maps()
+            hierarchy_maps(self.copybook, params)
         options = DecodeOptions.from_copybook(self.copybook)
         generate_input_file = bool(params.input_file_name_column)
 
@@ -801,30 +846,22 @@ class VarLenReader:
                                      stage_times=None) -> Optional[dict]:
         """Frame + decode-once setup shared by the hierarchical row and
         Arrow paths. Returns None when the configuration needs the
-        generic scalar path — every bail happens BEFORE framing consumes
-        the stream, so the caller's fallback can still read it."""
-        params = self.params
-        if resolve_segment_id_field(params, self.copybook) is None:
-            return None
-        if params.select:
-            # the scalar oracle ignores column projection; a projected
-            # columnar decode would silently change hierarchical rows
-            return None
-        if params.start_offset:
-            # the oracle reads CHILD records at the field's plain offset,
-            # without the record start offset (extract_children /
-            # reference extractChildren) — the uniform decode_raw shift
-            # cannot reproduce that
+        generic scalar path (`hierarchical_route` says why) — every bail
+        happens BEFORE framing consumes the stream, so the caller's
+        fallback can still read it."""
+        if self.hier_route["route"] == "rows":
             return None
         fast = self._frame_fast(stream, ledger=ledger,
                                 stage_times=stage_times)
-        if fast is None:
-            return None
+        # both guaranteed by the route: it leaves a read without fast
+        # framing or without a segment id field to the scalar walk
+        assert fast is not None
         data, _base, offsets, rec_lengths, segment_ids, _reasons = fast
-        assert segment_ids is not None  # guaranteed by the seg-field guard
+        assert segment_ids is not None
         n = len(offsets)
 
-        sid_map, parent_child_map, root_names = self._hierarchy_maps()
+        sid_map, parent_child_map, root_names = hierarchy_maps(
+            self.copybook, self.params)
         name_of_sid = {sid: g.name for sid, g in sid_map.items()}
         # per-redefine row masks: a redefine's columns are read only on
         # its own segment's records, so whole-column materialization (and
@@ -854,6 +891,34 @@ class VarLenReader:
                     root_names=root_names, seg_masks=seg_masks,
                     decoder=decoder, n=n, n_roots=n_roots,
                     input_file_name=stream.input_file_name)
+
+    def _hierarchical_table(self, ctx: dict, output_schema, file_id: int,
+                            start_record_id: int):
+        """A shard's Arrow table from its decode-once batch
+        (`hierarchical_table`), or None where that declines and the
+        nesting is walked record by record over the batch's values
+        (`FileResult.to_arrow` then asks the rows factory): counted. The
+        table guards itself with the rule the route asked ahead of the
+        data (`decline_reason`), so on a "batched_rows" route it declines
+        for the route's reason, and on a "columnar" one only where its
+        columns do not fit the output schema."""
+        from .hierarchical_arrow import hierarchical_table
+
+        if not ctx["n"]:
+            return None
+        table = hierarchical_table(
+            ctx["batch"], ctx["segment_names"], self.copybook,
+            output_schema, ctx["sid_map"], ctx["parent_child_map"],
+            ctx["root_names"], file_id=file_id,
+            start_record_id=start_record_id,
+            input_file_name=ctx["input_file_name"])
+        stats = ctx["batch"].stage_stats
+        if table is None and stats is not None:
+            stats.note_hier(
+                row_path_roots=ctx["n_roots"],
+                reason=self.hier_route["reason"]
+                or "the assembled columns do not fit the output schema")
+        return table
 
     def _read_rows_hierarchical_columnar(self, ctx: dict, file_id: int,
                                          start_record_id: int
@@ -1312,29 +1377,18 @@ class VarLenReader:
             # decode-once batch feeds a span-based Arrow assembly (no
             # Python rows) and a lazy nesting walk for the row path
             ctx = None
-            if (self.copybook.is_hierarchical
-                    and not self.dynamic_occurs_layout
-                    and not params.variable_size_occurs):
+            if self.copybook.is_hierarchical:
                 ctx = self._hierarchical_columnar_setup(
                     stream, backend, ledger=ledger,
                     stage_times=stage_times)
             if ctx is not None:
-                from .hierarchical_arrow import hierarchical_table
-
                 result.n_rows = ctx["n_roots"]
                 result.rows_factory = (
                     lambda: self._read_rows_hierarchical_columnar(
                         ctx, file_id, start_record_id))
                 result.arrow_factory = (
-                    lambda output_schema: hierarchical_table(
-                        ctx["batch"], ctx["segment_names"],
-                        self.copybook, output_schema,
-                        ctx["sid_map"],
-                        ctx["parent_child_map"], ctx["root_names"],
-                        file_id=file_id,
-                        start_record_id=start_record_id,
-                        input_file_name=ctx["input_file_name"])
-                    if ctx["n"] else None)
+                    lambda output_schema: self._hierarchical_table(
+                        ctx, output_schema, file_id, start_record_id))
                 if self.pushdown is not None:
                     # no static columnar plan -> the whole filter runs
                     # post-decode on the assembled table (correct,
@@ -1351,10 +1405,14 @@ class VarLenReader:
             result.rows = rows
             result.n_rows = len(rows)
             obs = obs_current()
-            if self.variable_arrays and obs is not None \
-                    and obs.device_stats is not None:
+            if obs is not None and obs.device_stats is not None:
                 # the read says what it left to the walk
-                obs.device_stats.note_odo(fallback_records=len(rows))
+                if self.variable_arrays:
+                    obs.device_stats.note_odo(fallback_records=len(rows))
+                if self.hier_route is not None:
+                    obs.device_stats.note_hier(
+                        row_path_roots=len(rows),
+                        reason=self.hier_route["reason"])
             if self.pushdown is not None:
                 self.pushdown.filter_result_generic(
                     result, self._output_schema())

@@ -31,3 +31,28 @@ def pytest_configure(config):
     # the same placement rule as the product: JAX_COMPILATION_CACHE_DIR if
     # the caller set it, <checkout>/.jax_cache otherwise
     ensure_compile_cache()
+
+
+# tests/benchmark/ belongs to the benchmark: no later PR may edit a file
+# of it, and a PR's new `per_layer` entries go to the END of the list (the
+# driver reads one put first or in the middle as a change to what was
+# there). Two lines of PR 36's test assert that its own metric IS the
+# last of that list, which the next appended metric (PR 38) made false and
+# no later PR can make true again. What the test's other lines assert is
+# held by test_benchmark_rehearsal_hier.py::test_pr36s_entry_stays_as_its_
+# own_test_pinned_it, beside the hash of the parent's whole manifest. The
+# two positional lines are a `benchmark` PR's to drop (PERF.md, section
+# 7); strict, so that the marker fails and goes the day they are dropped
+PINNED_AS_LAST = ("tests/benchmark/test_benchmark_preframed_share.py"
+                  "::test_the_manifest_declares_the_metric")
+
+
+def pytest_collection_modifyitems(config, items):
+    import pytest
+
+    for item in items:
+        if item.nodeid.endswith(PINNED_AS_LAST):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins preframed_shard_share as the last per_layer "
+                       "metric; metrics were appended behind it",
+                raises=AssertionError, strict=True))

@@ -447,3 +447,35 @@ def test_tpch_orders_program_with_the_expansion(one_chip, mosaic):
     print(f"orders {batch}x1153: {mem.argument_size_in_bytes} B in, "
           f"{mem.output_size_in_bytes} B out, "
           f"{mem.temp_size_in_bytes} B of temporaries")
+
+
+def test_hier_decode_pallas_full_block(one_chip, mosaic):
+    """upstream's hierarchical copybook (seven segment redefines of one
+    107 B area) as one decode-once program at the largest batch the
+    decoder launches: 1,048,576 rows of 108 B, the block cap, two to a
+    100 MiB shard of 1.56 million records. Six fused groups of one or two
+    columns, the narrowest the rows-in-lanes kernel is given, in one
+    kernel call; sixteen sliced string groups whose code points leave as
+    one 8-bit matrix of the 107 B behind the id byte; no gather."""
+    from benchmark.generators import hier_companies
+
+    decoder = ColumnarDecoder(
+        parse_copybook(hier_companies.COPYBOOK,
+                       segment_redefines=list(hier_companies.SEGMENTS)),
+        backend="pallas")
+    assert decoder.plan.max_extent == 108
+    batch = full_block(decoder)
+    assert batch == 1_048_576
+    fn = decoder.build_jax_decode_fn()
+    assert fn.device_groups == {"fused": 6, "fused_rows_in_lanes": 6,
+                                "sliced": 16, "gathered": 0}
+    assert (fn.points.width, fn.points.dtype) == (107, np.uint8)
+    compiled = compile_on(one_chip, fn, batch, 108)
+    assert kernel_calls(compiled.as_text()) == (0, 1)
+    assert GATHER not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # 157 B a launched row come home: the matrix and the narrow planes
+    assert mem.output_size_in_bytes < batch * 192
+    print(f"hier {batch}x108: {mem.argument_size_in_bytes} B in, "
+          f"{mem.output_size_in_bytes} B out, "
+          f"{mem.temp_size_in_bytes} B of temporaries")
