@@ -833,14 +833,21 @@ def _total_input_bytes(files: Sequence[str], io_stats=None,
 
 def _plan_var_len_shards(reader, files, params,
                          retry: Optional[RetryPolicy] = None,
-                         on_retry=None, io=None) -> List["WorkShard"]:
+                         on_retry=None, io=None,
+                         split_mbs=None) -> List["WorkShard"]:
     """Byte-range shard plan for a variable-length read (the sparse-index
     chunk planner, engine/chunks.py). Shared by the in-process threaded
     scan, the pipelined executor, and the multi-host (process) executor."""
     from .engine.chunks import plan_var_len_chunks
 
     return plan_var_len_chunks(reader, files, params, retry, on_retry,
-                               io=io)
+                               io=io, split_mbs=split_mbs)
+
+
+def read_parallelism(opts) -> int:
+    """Local concurrency for the indexed shard scan (the analogue of the
+    reference's executor count; not a reference option)."""
+    return opts.get_int("parallelism", 0) or min(16, os.cpu_count() or 1)
 
 
 def _scan_var_len(reader, files, params, backend: str, prefix: str,
@@ -861,27 +868,38 @@ def _scan_var_len(reader, files, params, backend: str, prefix: str,
     file, the index pass is the file's one framing and a shard starts,
     with its slice of the pass's tables, as soon as its cut is found
     (`engine.chunks.preframed_var_len_chunks`). Same shards, same
-    tables."""
+    tables. The split is `reader.index.index_split`'s, a file at a time:
+    a dense file smaller than the pool's work is cut to the pool, every
+    other file at the read's split. Same tables again."""
     from .engine.chunks import preframed_var_len_chunks
     from .obs.context import activate as obs_activate
     from .obs.context import current as obs_current
+    from .reader.index import index_split
 
     obs = obs_current()
     tracer = obs.tracer if obs is not None else None
     progress = obs.progress if obs is not None else None
 
+    split_mbs = None
+    if params.is_index_generation_needed:
+        splits = [index_split(reader, path, params, parallelism, io)
+                  for path in files]
+        split_mbs = [s.mb if s.why == "pool" else None for s in splits]
+
     def planned(n_shards: int) -> None:
         if metrics is not None:
             metrics.shards = n_shards
+            metrics.device_stats.note_plan(
+                n_shards, sum(mb is not None for mb in split_mbs or ()))
         if progress is not None:
             progress.set_plan(chunks_total=n_shards)
 
     preframed = preframed_var_len_chunks(reader, files, params, retry,
-                                         on_retry, io)
+                                         on_retry, io, split_mbs)
     if preframed is None:
         with stage(metrics, "plan_index"):
             shards = _plan_var_len_shards(reader, files, params, retry,
-                                          on_retry, io)
+                                          on_retry, io, split_mbs)
         planned(len(shards))
     shard_times = None
     if tracer is not None or (metrics is not None
@@ -1069,10 +1087,7 @@ def read_cobol(path=None,
 
         params = _dc_replace(params, field_costs=True)
     debug_ignore_file_size = opts.get_bool("debug_ignore_file_size")
-    # local concurrency for the indexed shard scan (the analogue of the
-    # reference's executor count; not a reference option)
-    parallelism = opts.get_int("parallelism", 0) or min(
-        16, os.cpu_count() or 1)
+    parallelism = read_parallelism(opts)
     # hosts > 1: fork one worker process per host and run the shard plan
     # there (parallel/hosts.py — the executor-process analogue); the
     # result is Arrow-backed
@@ -1177,7 +1192,8 @@ def read_cobol(path=None,
         from .explain import build_scan_report
 
         return build_scan_report(params, files=files, data=data,
-                                 backend=backend)
+                                 backend=backend,
+                                 copybook_contents=copybook_contents)
     return data
 
 

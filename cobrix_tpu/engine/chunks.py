@@ -133,21 +133,25 @@ def plan_fixed_chunks(reader, files, params, chunk_bytes: int,
 
 def plan_var_len_chunks(reader, files, params,
                         retry: Optional[RetryPolicy] = None,
-                        on_retry=None, io=None) -> List["WorkShard"]:
+                        on_retry=None, io=None,
+                        split_mbs=None) -> List["WorkShard"]:
     """Byte-range shard plan for a variable-length read: the sparse index
     per file turns the sequential record stream into shards; files
     without a useful index become one whole-file shard. Shared by the
     in-process threaded scan, the pipelined executor, and the multi-host
-    (process) executor."""
+    (process) executor. `split_mbs`: a file's split where the threaded
+    scan's `index_split` set one, else None."""
     shards: List["WorkShard"] = []
     for file_order, file_path in enumerate(files):
         shards.extend(_file_shards(reader, file_path, file_order, params,
-                                   retry, on_retry, io))
+                                   retry, on_retry, io,
+                                   split_mbs[file_order] if split_mbs
+                                   else None))
     return shards
 
 
 def _file_shards(reader, file_path: str, file_order: int, params,
-                 retry, on_retry, io) -> List["WorkShard"]:
+                 retry, on_retry, io, split_mb=None) -> List["WorkShard"]:
     """One file's shards of `plan_var_len_chunks`."""
     from ..parallel.planner import WorkShard
 
@@ -156,7 +160,8 @@ def _file_shards(reader, file_path: str, file_order: int, params,
     entries = None
     if params.is_index_generation_needed:
         entries = file_index_entries(reader, file_path, file_order,
-                                     params, retry, on_retry, io=io)
+                                     params, retry, on_retry, io=io,
+                                     split_mb=split_mb)
     if entries is not None and len(entries) > 1:
         # an open-ended last entry (-1) flows into the shard unchanged:
         # streams bound it to the file end themselves, so no extra
@@ -173,7 +178,7 @@ def _file_shards(reader, file_path: str, file_order: int, params,
 
 def preframed_var_len_chunks(reader, files, params,
                              retry: Optional[RetryPolicy] = None,
-                             on_retry=None, io=None):
+                             on_retry=None, io=None, split_mbs=None):
     """`plan_var_len_chunks` for the in-process threaded scan where some
     file is dense enough for its index pass to be its one framing
     (`reader.index.preframed_route`: the rule, read off each file): an
@@ -182,12 +187,15 @@ def preframed_var_len_chunks(reader, files, params,
     tables and as soon as its cut is found, so it can be scanned while
     the pass walks on. None where no file is: the caller plans as ever.
     The pipelined engine and the multihost executor, whose shards cross
-    threads of stages and processes, take `plan_var_len_chunks`' list."""
+    threads of stages and processes, take `plan_var_len_chunks`' list.
+    `split_mbs` as there."""
     from ..parallel.planner import WorkShard
 
     if not params.is_index_generation_needed:
         return None
-    dense = [preframed_route(reader, path, params, io) for path in files]
+    split_mbs = split_mbs or [None] * len(files)
+    dense = [preframed_route(reader, path, params, io, split_mb)
+             for path, split_mb in zip(files, split_mbs)]
     if not any(dense):
         return None
 
@@ -195,12 +203,14 @@ def preframed_var_len_chunks(reader, files, params,
         for file_order, file_path in enumerate(files):
             if not dense[file_order]:
                 for shard in _file_shards(reader, file_path, file_order,
-                                          params, retry, on_retry, io):
+                                          params, retry, on_retry, io,
+                                          split_mbs[file_order]):
                     yield shard, None
                 continue
             base = file_order * DEFAULT_FILE_RECORD_ID_INCREMENT
             for e, framed in preframed_entries(reader, file_path,
-                                               file_order):
+                                               file_order,
+                                               split_mbs[file_order]):
                 yield WorkShard(file_path, file_order, e.offset_from,
                                 e.offset_to, base + e.record_index), framed
 

@@ -281,8 +281,30 @@ def _copybook_summary(copybook, plan) -> dict:
     }
 
 
+def _index_splits(copybook_contents, params, files: List[str],
+                  backend: str, hosts: int, parallelism: int):
+    """Each file's split and why, by the function the threaded indexed
+    scan calls (`reader.index.index_split`), where the read takes that
+    scan (`api._scan_var_len`); else None."""
+    if (not files or copybook_contents is None
+            or not params.needs_var_len_reader
+            or not params.is_index_generation_needed
+            or backend == "host" or hosts > 1
+            or params.resolved_pipeline_workers() > 0):
+        return None
+    from .api import _io_config
+    from .reader.index import index_split
+    from .reader.var_len_reader import VarLenReader
+
+    reader = VarLenReader(copybook_contents, params)
+    io = _io_config(params)
+    return [index_split(reader, path, params, parallelism, io)._asdict()
+            for path in files]
+
+
 def _execution_plan(params, files: List[str], total_bytes: int,
-                    backend: str, hosts: int, copybook=None) -> dict:
+                    backend: str, hosts: int, copybook=None,
+                    index_splits=None) -> dict:
     mode = ("variable-length" if params.needs_var_len_reader
             else "fixed-length")
     plan = {
@@ -305,6 +327,8 @@ def _execution_plan(params, files: List[str], total_bytes: int,
         plan["est_chunks"] = max(1, -(-total_bytes // chunk_bytes))
     elif mode == "variable-length":
         plan["chunking"] = "sparse-index driven"
+    if index_splits is not None:
+        plan["index_split"] = index_splits
     if params.cache_dir:
         plan["cache_dir"] = params.cache_dir
     if copybook is not None and mode == "variable-length":
@@ -346,7 +370,12 @@ def explain(copybook: Optional[str] = None,
     optional; when given, files are listed and sized for the plan).
     `calibrate=True` runs the roofline calibration if the machine has
     never calibrated (~1s, cached on disk)."""
-    from .api import _total_input_bytes, list_input_files, parse_options
+    from .api import (
+        _total_input_bytes,
+        list_input_files,
+        parse_options,
+        read_parallelism,
+    )
     from .plan.cache import (
         CacheStatsScope,
         activate_scope,
@@ -401,16 +430,20 @@ def explain(copybook: Optional[str] = None,
         copybook_summary=_copybook_summary(copybook_obj, plan),
         fields=plan.describe(),
         groups=plan.group_summary(),
-        plan=_execution_plan(params, files, total_bytes, backend, hosts,
-                             copybook=copybook_obj),
+        plan=_execution_plan(
+            params, files, total_bytes, backend, hosts,
+            copybook=copybook_obj,
+            index_splits=_index_splits(copybook_contents, params, files,
+                                       backend, hosts,
+                                       read_parallelism(opts))),
         cache_planes=_cache_planes(dict(scope.stats), None,
                                    params.cache_dir),
         pushdown=describe_pushdown(copybook_obj, params),
     )
 
 
-def build_scan_report(params, files: List[str], data,
-                      backend: str) -> ScanReport:
+def build_scan_report(params, files: List[str], data, backend: str,
+                      copybook_contents=None) -> ScanReport:
     """Post-scan report for `read_cobol(..., explain=True)`: the static
     plan description plus the read's measured metrics/costs."""
     from .plan.cache import cached_compile_plan
@@ -426,8 +459,12 @@ def build_scan_report(params, files: List[str], data,
         copybook_summary=_copybook_summary(copybook_obj, plan),
         fields=plan.describe(),
         groups=plan.group_summary(),
-        plan=_execution_plan(params, files, metrics.bytes_read, backend,
-                             metrics.hosts, copybook=copybook_obj),
+        plan=_execution_plan(
+            params, files, metrics.bytes_read, backend, metrics.hosts,
+            copybook=copybook_obj,
+            index_splits=_index_splits(copybook_contents, params, files,
+                                       backend, metrics.hosts,
+                                       data.parallelism)),
         cache_planes=_cache_planes(metrics.plan_cache, metrics.io,
                                    params.cache_dir),
         data=data,

@@ -317,7 +317,9 @@ class DeviceStats:
     (`odo_shifted_bytes`: with `h2d_bytes`, what an expansion on the host
     would have sent more), and the records the row path walked instead
     (`odo_fallback_records`); and for the threaded indexed scan (present
-    only where it ran; api._scan_var_len): the shards that got their
+    only where it ran; api._scan_var_len): the shards it planned
+    (`index_shards`), the files whose split `reader.index.index_split`
+    cut to the pool (`pool_split_files`), the shards that got their
     records' tables from the index pass (`preframed_shards`) and those
     that framed themselves (`self_framed_shards`); and for a hierarchical
     read (`hier_*`, present only then; reader/hierarchical_arrow.py): the
@@ -375,7 +377,10 @@ class DeviceStats:
         self.odo_records = 0
         self.odo_fallback_records = 0
         self.odo_shifted_bytes = 0
-        # index shards of the threaded scan (api._scan_var_len)
+        # index shards of the threaded scan (api._scan_var_len); None:
+        # the scan planned none
+        self.index_shards: Optional[int] = None
+        self.pool_split_files = 0
         self.preframed_shards = 0
         self.self_framed_shards = 0
         # hierarchical assembly (hierarchical_arrow.hierarchical_table,
@@ -499,6 +504,13 @@ class DeviceStats:
             self.odo_fallback_records += fallback_records
             self.odo_shifted_bytes += shifted_bytes
 
+    def note_plan(self, shards: int, pool_split_files: int) -> None:
+        """The threaded scan's plan: `shards` index shards, of files of
+        which `pool_split_files` were cut to the pool."""
+        with self._lock:
+            self.index_shards = shards
+            self.pool_split_files = pool_split_files
+
     def note_shard(self, preframed: bool) -> None:
         """One index shard of the threaded scan: `preframed`, it got its
         records' tables from the index pass; else it framed itself."""
@@ -580,8 +592,9 @@ class DeviceStats:
                 "query_rows_scanned": self.query_rows_scanned,
                 "query_rows_passed": self.query_rows_passed,
                 "query_groups": self.query_groups}
-            counted = self.preframed_shards + self.self_framed_shards
-            shards = {} if not counted else {
+            shards = {} if self.index_shards is None else {
+                "index_shards": self.index_shards,
+                "pool_split_files": self.pool_split_files,
                 "preframed_shards": self.preframed_shards,
                 "self_framed_shards": self.self_framed_shards}
             return {

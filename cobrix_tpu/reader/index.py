@@ -16,7 +16,7 @@ from __future__ import annotations
 import logging
 import os
 from dataclasses import dataclass, replace
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 from ..copybook.ast import Primitive
 from ..copybook.copybook import Copybook
@@ -59,66 +59,151 @@ class FramedRecords:
 
 
 # A file whose records average fewer payload bytes than this over its first
-# PREFRAMED_PROBE_BYTES is framed once, by its index pass (`preframed_route`).
-# The constant rests on two points of the benchmark's ledger and nothing
-# finer: at 65-70 B a record (exp2_read: 8.2 million records a 512 MiB file)
-# the one framing gave +46.6 %, at 722 B (tpch_orders_odo_read: 743 k) and
-# at 5.4 KB (exp3_read: 99 k) it gave nothing the runs resolve, or lost
-# (PERF_LEDGER.jsonl, PR 35). Speed only: both routes give the same tables.
-PREFRAMED_MAX_MEAN_RECORD = 256
-PREFRAMED_PROBE_BYTES = MEGABYTE
+# DENSE_PROBE_BYTES is dense, and two mechanisms of the threaded indexed
+# scan read that: it is framed once, by its index pass (`preframed_route`),
+# and a file smaller than the pool's work is cut into SHARDS_PER_THREAD
+# shards a pool thread (`index_split`). The constant rests on two points
+# of the benchmark's ledger and nothing finer: at 65-70 B a record
+# (exp2_read: 8.2 million records a 512 MiB file) the one framing gave
+# +46.6 %, at 722 B (tpch_orders_odo_read: 743 k) and at 5.4 KB
+# (exp3_read: 99 k) it gave nothing the runs resolve, or lost
+# (PERF_LEDGER.jsonl, PR 35); and shards of a wide-record file cut to the
+# pool would fill their launches worse (exp3's 'C' rows: about 1,300 a
+# 20 MiB shard in a bucket of 2,048, where 6,600 fill 8,192 at 100 MiB).
+# Speed only: every route and split gives the same tables.
+DENSE_MAX_MEAN_RECORD = 256
+DENSE_PROBE_BYTES = MEGABYTE
+# The least `index_split` cuts a dense file into: 16 MiB is about 250 k
+# records of 64-67 B (exp2_read, hier_companies_read), one launch of
+# 262,144 rows or more, so that what a shard costs whatever its size (its
+# stream, its launches' round trips, its Arrow table) stays small beside
+# its records. A file under two of it keeps the read's split.
+SPLIT_FLOOR = 16 * MEGABYTE
+# Shards `index_split` gives each thread of the pool: with one a thread
+# the threads pack, wait on the link and assemble all at once and the read
+# waits for its slowest shard; two shards of 20 MiB a thread read 5 %
+# over one of 40 MiB, at the same launches' fill, in hier_companies_read
+# on the chip's host of 13 cores (PERF.md section 6, PR 39, call 2).
+SHARDS_PER_THREAD = 2
 
 
-def _one_shard_covers(size: int, params) -> bool:
+def _one_shard_covers(size: int, params, split_mb=None) -> bool:
     """A file too small to index: empty (nothing to index, and mmap
     rejects empty files), or within one split with no explicit split
-    option — the whole file is one shard anyway."""
+    option nor `split_mb` from `index_split` — the whole file is one
+    shard anyway."""
     if size == 0:
         return True
     explicit = (params.input_split_records is not None
-                or params.input_split_size_mb is not None)
-    split_mb = params.input_split_size_mb or DEFAULT_INDEX_ENTRY_SIZE_MB
-    return not explicit and size <= split_mb * MEGABYTE
+                or params.input_split_size_mb is not None
+                or split_mb is not None)
+    return not explicit and size <= DEFAULT_INDEX_ENTRY_SIZE_MB * MEGABYTE
 
 
-def preframed_route(reader, file_path: str, params, io=None) -> bool:
+def _plain_local_file(file_path: str, io) -> bool:
+    """Storage whose bytes the density probe and the index pass read as
+    they lie: a local file, neither compressed nor behind a codec."""
+    from ..io.compress import active_codec, compressed_chunkable
+    from .stream import path_scheme
+
+    return (path_scheme(file_path) in (None, "file")
+            and active_codec(file_path, io) is None
+            and compressed_chunkable(file_path, io))
+
+
+def _mean_record(reader, file_path: str, size: int) -> Optional[float]:
+    """The density probe: the mean payload of the records whose RDW
+    headers lie in the file's first DENSE_PROBE_BYTES."""
+    with open(file_path, "rb") as f:
+        head = f.read(DENSE_PROBE_BYTES)
+    return reader.mean_record_length(head, whole=len(head) >= size)
+
+
+def preframed_route(reader, file_path: str, params, io=None,
+                    split_mb=None) -> bool:
     """THE rule of the indexed scan's second route: True where the index
     pass of `file_path` should be the file's one framing
     (`preframed_entries`), each shard receiving its slice of the pass's
     tables instead of scanning its byte range again. Read off the file:
     the mean record length over the RDW headers of its first
-    PREFRAMED_PROBE_BYTES, under PREFRAMED_MAX_MEAN_RECORD. False, and
+    DENSE_PROBE_BYTES, under DENSE_MAX_MEAN_RECORD. False, and
     `file_index_entries` as ever, for everything the pass does not
     serve to the letter: a permissive policy (the pass's ledger is a
     throwaway, the shards' is the read's), the index store (entries are
     loaded, or saved: no pass may have run), zone-map skipping, framing
     the native scan does not do, layouts that do not reach
     `_frame_fast`'s tables, storage that is not a plain local file, and
-    a file one shard covers."""
-    from ..io.compress import active_codec, compressed_chunkable
-    from .stream import path_scheme
-
+    a file one shard covers (at `split_mb`, where `index_split` set
+    one)."""
     if (params.is_permissive
             or (io is not None and io.cache_enabled)
             or getattr(reader, "chunk_skipper", None) is not None
             or not reader.supports_fast_framing
             or reader.copybook.is_hierarchical
-            or reader.dynamic_occurs_layout):
-        return False
-    if (path_scheme(file_path) not in (None, "file")
-            or active_codec(file_path, io) is not None
-            or not compressed_chunkable(file_path, io)):
+            or reader.dynamic_occurs_layout
+            or not _plain_local_file(file_path, io)):
         return False
     size = os.path.getsize(file_path)
-    if _one_shard_covers(size, params):
+    if _one_shard_covers(size, params, split_mb):
         return False
-    with open(file_path, "rb") as f:
-        head = f.read(PREFRAMED_PROBE_BYTES)
-    mean = reader.mean_record_length(head, whole=len(head) >= size)
-    return mean is not None and mean < PREFRAMED_MAX_MEAN_RECORD
+    mean = _mean_record(reader, file_path, size)
+    return mean is not None and mean < DENSE_MAX_MEAN_RECORD
 
 
-def preframed_entries(reader, file_path: str, file_order: int):
+class IndexSplit(NamedTuple):
+    """How one file's sparse index is cut (`index_split`): `why` is
+    "option" (the read names `input_split_size_mb` or
+    `input_split_records`), "pool" (the rule set `mb`), "wide_records"
+    (the pool would have, but the records are not dense) or "default";
+    `mb` the MiB a shard is cut at, None where records count."""
+    why: str
+    mb: Optional[int]
+
+
+def index_split(reader, file_path: str, params, parallelism: int,
+                io=None) -> IndexSplit:
+    """THE split of the threaded indexed scan (`api._scan_var_len`), one
+    file at a time: a dense file smaller than `parallelism` default
+    splits is cut into SHARDS_PER_THREAD shards a pool thread,
+    `max(SPLIT_FLOOR, size / (SHARDS_PER_THREAD * parallelism))` rounded
+    up to a whole MiB, so that every thread of the pool works and none
+    waits on a shard of 100 MiB. Every other file keeps the
+    read's split: explicit split options always win; a pool of one, a
+    permissive policy (the probe's walk is strict), the index store
+    (whose entries are keyed by the options), framing the native scan
+    does not do, a file header or footer (a counted header shifts an
+    indexed read's Record_Ids from those of a file one shard covers:
+    IndexGenerator.scala:117-120) and storage that is not a plain local
+    file keep the default, as does a file under two floors or of
+    `parallelism` default splits or more; a file whose records average
+    DENSE_MAX_MEAN_RECORD or more keeps it as "wide_records". Cuts stay
+    where the index pass makes them (at roots where shards are cut at
+    roots), so every split gives the same tables."""
+    if (params.input_split_records is not None
+            or params.input_split_size_mb is not None):
+        return IndexSplit("option", params.input_split_size_mb)
+    default = IndexSplit("default", DEFAULT_INDEX_ENTRY_SIZE_MB)
+    if (parallelism <= 1 or params.is_permissive
+            or (io is not None and io.cache_enabled)
+            or not reader.supports_fast_framing
+            or params.file_start_offset or params.file_end_offset
+            or not _plain_local_file(file_path, io)):
+        return default
+    size = os.path.getsize(file_path)
+    if not (2 * SPLIT_FLOOR <= size
+            < parallelism * DEFAULT_INDEX_ENTRY_SIZE_MB * MEGABYTE):
+        return default
+    mean = _mean_record(reader, file_path, size)
+    if mean is None:
+        return default
+    if mean >= DENSE_MAX_MEAN_RECORD:
+        return IndexSplit("wide_records", DEFAULT_INDEX_ENTRY_SIZE_MB)
+    split = max(SPLIT_FLOOR, -(-size // (SHARDS_PER_THREAD * parallelism)))
+    return IndexSplit("pool", -(-split // MEGABYTE))
+
+
+def preframed_entries(reader, file_path: str, file_order: int,
+                      split_mb=None):
     """The sparse index of one file on the route `preframed_route`
     chose: `file_index_entries`' entries to the byte, each with its
     records' tables (`FramedRecords`), yielded as its cut is found so
@@ -128,7 +213,7 @@ def preframed_entries(reader, file_path: str, file_order: int):
     with open(file_path, "rb") as f:
         mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
         try:
-            yield from reader.frame_index_fast(mm, file_order)
+            yield from reader.frame_index_fast(mm, file_order, split_mb)
         finally:
             try:
                 mm.close()
@@ -139,7 +224,7 @@ def preframed_entries(reader, file_path: str, file_order: int):
 
 
 def file_index_entries(reader, file_path: str, file_order: int, params,
-                       retry=None, on_retry=None, io=None
+                       retry=None, on_retry=None, io=None, split_mb=None
                        ) -> Optional[List[SparseIndexEntry]]:
     """Sparse index for one file, or None when a single shard suffices —
     the chunk-planning primitive shared by the threaded indexed scan, the
@@ -151,7 +236,9 @@ def file_index_entries(reader, file_path: str, file_order: int, params,
     With `io.cache_dir` set, computed entries persist in the sparse-index
     store (cobrix_tpu.io.index_store) keyed by file fingerprint +
     framing-config fingerprint: the sequential indexing pass runs once
-    per file version, and warm re-scans load the shard plan directly."""
+    per file version, and warm re-scans load the shard plan directly.
+    `split_mb`: the MiB a shard that `index_split` set for the file
+    (never with the store), None for the read's own split."""
     from .stream import open_stream, path_scheme
 
     store = config_fp = io_stats = None
@@ -191,7 +278,8 @@ def file_index_entries(reader, file_path: str, file_order: int, params,
         return None
     if path_scheme(file_path) in (None, "file") \
             and active_codec(file_path, io) is None:
-        if _one_shard_covers(os.path.getsize(file_path), params):
+        if _one_shard_covers(os.path.getsize(file_path), params,
+                             split_mb):
             return None
         fingerprint = None
         if store is not None:
@@ -210,7 +298,8 @@ def file_index_entries(reader, file_path: str, file_order: int, params,
             with open(file_path, "rb") as f:
                 mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
                 try:
-                    entries = reader.generate_index_fast(mm, file_order)
+                    entries = reader.generate_index_fast(mm, file_order,
+                                                         split_mb)
                 finally:
                     try:
                         mm.close()

@@ -118,12 +118,13 @@ class _IndexCuts:
     shared by `generate_index_fast` (the whole file's records at once)
     and `frame_index_fast` (the records walked so far). Split semantics
     (including the invalid-record counting and size-drift quirks) mirror
-    sparse_index_generator exactly — pinned by tests against it."""
+    sparse_index_generator exactly — pinned by tests against it.
+    `split_mb`: the split `index.index_split` set, over the options'."""
 
-    def __init__(self, params: ReaderParameters):
+    def __init__(self, params: ReaderParameters, split_mb=None):
         self.per = params.input_split_records
-        self.mb = ((params.input_split_size_mb or DEFAULT_INDEX_ENTRY_SIZE_MB)
-                   * MEGABYTE)
+        self.mb = ((split_mb or params.input_split_size_mb
+                    or DEFAULT_INDEX_ENTRY_SIZE_MB) * MEGABYTE)
         # the file-header region is consumed as one counted invalid record
         # (IndexGenerator.scala:117-120 counts unconditionally)
         self.base = 1 if params.file_start_offset > 0 else 0
@@ -471,7 +472,7 @@ class VarLenReader:
             record_error_policy=params.record_error_policy,
             resync_window_bytes=params.resync_window_bytes)
 
-    def generate_index_fast(self, data, file_id: int
+    def generate_index_fast(self, data, file_id: int, split_mb=None
                             ) -> Optional[List[SparseIndexEntry]]:
         """Vectorized sparse index for plain RDW files: one native scan of
         the file image + split arithmetic over the offset arrays instead of
@@ -479,7 +480,8 @@ class VarLenReader:
         needs the generic generator (custom extractors/parsers, text mode,
         length fields). Split semantics (including the
         invalid-record counting and size-drift quirks) mirror
-        sparse_index_generator exactly — pinned by tests against it."""
+        sparse_index_generator exactly — pinned by tests against it.
+        `split_mb`: the split `index.index_split` set, over the options'."""
         from .. import native
 
         if not self.supports_fast_framing:
@@ -525,7 +527,7 @@ class VarLenReader:
                 return None
             return int(root_indices[k])
 
-        cuts = _IndexCuts(p)
+        cuts = _IndexCuts(p, split_mb)
         entries = [SparseIndexEntry(0, -1, file_id, 0)]
         # processing the last record ends the stream before the split check
         # (IndexGenerator loop order) — unless a footer region follows it,
@@ -572,7 +574,8 @@ class VarLenReader:
     def mean_record_length(self, head, whole: bool) -> Optional[float]:
         """Mean payload length by the RDW headers of `head`, the first
         bytes of a file (`whole`: all of them): the density that
-        `index.preframed_route` reads. None where the walk finds no whole
+        `index.preframed_route` and `index.index_split` read. None where
+        the walk finds no whole
         record or a header it cannot follow (the index pass says which)."""
         from .. import native
 
@@ -587,7 +590,7 @@ class VarLenReader:
             lengths = lengths[:-1]  # cut short where the head ends
         return float(lengths.mean()) if len(lengths) else None
 
-    def frame_index_fast(self, data, file_id: int
+    def frame_index_fast(self, data, file_id: int, split_mb=None
                          ) -> Iterator[Tuple[SparseIndexEntry, FramedRecords]]:
         """`generate_index_fast`'s entries, each with the tables of its
         records, each yielded as soon as its cut is known: the index pass
@@ -601,7 +604,8 @@ class VarLenReader:
         cut waits for: the ids are decoded where a root is looked for,
         ROOT_SEARCH_RECORDS at a time, and coded whole by the shard's
         own thread. Not for a permissive policy, nor where
-        `supports_fast_framing` is False."""
+        `supports_fast_framing` is False. `split_mb` as in
+        `generate_index_fast`."""
         from .. import native
 
         p = self.params
@@ -613,7 +617,7 @@ class VarLenReader:
         is_hierarchical, root_segment_id = self._index_split_config()
         root_ids = (set(root_segment_id.split(","))
                     if is_hierarchical and seg_field is not None else None)
-        cuts = _IndexCuts(p)
+        cuts = _IndexCuts(p, split_mb)
         # the open entry: its first byte, and its first record's number
         # in the file and in the index
         opened, first, record_index = 0, 0, 0

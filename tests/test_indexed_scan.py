@@ -366,7 +366,7 @@ def parents_route(monkeypatch):
     """A callable that sends every file down the parent's route from then
     on: no record is short enough for the other."""
     return lambda: monkeypatch.setattr(reader_index,
-                                       "PREFRAMED_MAX_MEAN_RECORD", 0)
+                                       "DENSE_MAX_MEAN_RECORD", 0)
 
 
 def _shard_counts(data):
@@ -489,11 +489,11 @@ class TestPreframedRule:
 
     def test_the_constant_lies_between_the_two_cells(self):
         # exp2's records are 64-68 B, the orders' 722 B at the mean
-        assert 70 < reader_index.PREFRAMED_MAX_MEAN_RECORD < 700
+        assert 70 < reader_index.DENSE_MAX_MEAN_RECORD < 700
 
     def test_density_is_read_off_the_first_mebibyte_only(self, tmp_path):
         dense_head = _rdw_file(70000, 3) + _sparse_file(200)
-        assert len(_rdw_file(70000, 3)) > reader_index.PREFRAMED_PROBE_BYTES
+        assert len(_rdw_file(70000, 3)) > reader_index.DENSE_PROBE_BYTES
         path = tmp_path / "dense_head.bin"
         path.write_bytes(dense_head)
         options = dict(is_record_sequence="true", input_split_size_mb="1")
@@ -507,7 +507,7 @@ class TestPreframedRule:
                                    b"FTRBYTES"))
         options = dict(MULTISEG_OPTS, input_split_records="7",
                        file_start_offset="8", file_end_offset="8")
-        assert path.stat().st_size < reader_index.PREFRAMED_PROBE_BYTES
+        assert path.stat().st_size < reader_index.DENSE_PROBE_BYTES
         assert _route(path, **options)
         # two records: the mean is theirs, the footer is not walked
         path.write_bytes(_rdw_file(1, 1, False, 0, b"HDRBYTES", b"FTRBYTES"))
@@ -635,8 +635,10 @@ def test_the_shard_counts_ride_the_device_record():
     stats.note_shard(preframed=True)
     stats.note_shard(preframed=False)
     stats.note_shard(preframed=True)
+    stats.note_plan(3, 1)
     said = stats.as_dict()
     assert (said["preframed_shards"], said["self_framed_shards"]) == (2, 1)
+    assert (said["index_shards"], said["pool_split_files"]) == (3, 1)
 
 
 def test_a_bad_header_past_the_first_window_is_named_where_it_lies(
@@ -651,8 +653,208 @@ def test_a_bad_header_past_the_first_window_is_named_where_it_lies(
     with pytest.raises(ValueError,
                        match=f"zero size record at {len(good)} ") as new:
         read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK, **options)
-    monkeypatch.setattr(reader_index, "PREFRAMED_MAX_MEAN_RECORD", 0)
+    monkeypatch.setattr(reader_index, "DENSE_MAX_MEAN_RECORD", 0)
     with pytest.raises(ValueError) as old:
         read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK, **options)
     assert str(new.value) == str(old.value)
     assert new.value.offset == old.value.offset == len(good)
+
+
+# ---------------------------------------------------------------------------
+# The split follows the pool for a dense file (reader.index.index_split): a
+# file under `parallelism` default splits whose records average under
+# DENSE_MAX_MEAN_RECORD is cut into SHARDS_PER_THREAD shards a pool thread,
+# at whole MiB.
+# SPLIT_FLOOR is patched down to a few KiB so that files of a few MiB show
+# it. The read at the file's own split is the reference: same tables.
+# ---------------------------------------------------------------------------
+from benchmark.generators import hier_companies as hier
+from cobrix_tpu.api import _io_config
+from cobrix_tpu.explain import explain
+
+# 70,000 roots of 13 B and 210,000 children of 14 B: 3.67 MiB, so a pool
+# of four or two cuts it at 1 MiB (the whole MiB above 3.67 / 8 or 4)
+# into four shards
+DENSE_ARGS = (70000, 3)
+HIER_OPTIONS = dict(
+    is_record_sequence="true", segment_field="SEGMENT-ID",
+    generate_record_id="true",
+    **{f"redefine_segment_id_map:{i}": f"{name} => {i + 1}"
+       for i, name in enumerate(hier.SEGMENTS)},
+    **{f"segment-children:{i}": f"{parent} => {child}"
+       for i, (child, parent) in enumerate(hier.PARENT.items())})
+
+
+@pytest.fixture
+def low_floor(monkeypatch):
+    monkeypatch.setattr(reader_index, "SPLIT_FLOOR", 4096)
+
+
+def _split(path, parallelism, copybook=MULTISEG_COPYBOOK, **options):
+    params, _ = parse_options(dict(options))
+    reader = VarLenReader(copybook, params)
+    return reader_index.index_split(reader, str(path), params, parallelism,
+                                    _io_config(params))
+
+
+# id -> (file bytes, read_cobol options, parallelism, the split and why)
+SPLIT_CASES = {
+    "dense_under_the_pools_work": (
+        _rdw_file(*DENSE_ARGS), MULTISEG_OPTS, 4, ("pool", 1)),
+    "dense_without_segments": (
+        _rdw_file(*DENSE_ARGS), PLAIN, 4, ("pool", 1)),
+    "a_pool_of_two": (_rdw_file(*DENSE_ARGS), MULTISEG_OPTS, 2, ("pool", 1)),
+    "wide_records": (_sparse_file(), dict(is_record_sequence="true"), 4,
+                     ("wide_records", 100)),
+    "a_size_option": (_rdw_file(*DENSE_ARGS), dict(
+        MULTISEG_OPTS, input_split_size_mb="2"), 4, ("option", 2)),
+    "a_records_option": (_rdw_file(*DENSE_ARGS), dict(
+        MULTISEG_OPTS, input_split_records="7"), 4, ("option", None)),
+    "a_pool_of_one": (_rdw_file(*DENSE_ARGS), MULTISEG_OPTS, 1,
+                      ("default", 100)),
+    "under_two_floors": (_rdw_file(40, 3), MULTISEG_OPTS, 4,
+                         ("default", 100)),
+    "a_permissive_policy": (_rdw_file(*DENSE_ARGS), dict(
+        MULTISEG_OPTS, record_error_policy="permissive"), 4,
+        ("default", 100)),
+    "a_file_header": (_rdw_file(70000, 3, False, 0, b"HDRBYTES"), dict(
+        PLAIN, file_start_offset="8"), 4, ("default", 100)),
+    "text_framing": (_rdw_file(*DENSE_ARGS), dict(
+        is_record_sequence="true", is_text="true"), 4, ("default", 100)),
+    "a_head_the_walk_cannot_follow": (
+        b"\0\0\0\0" + _rdw_file(*DENSE_ARGS), MULTISEG_OPTS, 4,
+        ("default", 100)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SPLIT_CASES))
+def test_index_split_decides(name, tmp_path, low_floor):
+    data, options, parallelism, expected = SPLIT_CASES[name]
+    path = tmp_path / f"{name}.bin"
+    path.write_bytes(data)
+    copybook = (SPARSE_COPYBOOK if name == "wide_records"
+                else MULTISEG_COPYBOOK)
+    assert _split(path, parallelism, copybook, **options) == expected
+
+
+def test_a_file_of_the_pools_work_or_more_keeps_the_default(
+        tmp_path, low_floor, monkeypatch):
+    path = tmp_path / "dense.bin"
+    path.write_bytes(_rdw_file(*DENSE_ARGS))
+    assert _split(path, 4, **MULTISEG_OPTS).why == "pool"
+    # a default split of 1 MiB: four threads' work is 4 MiB, the file 3.67
+    monkeypatch.setattr(reader_index, "DEFAULT_INDEX_ENTRY_SIZE_MB", 1)
+    assert _split(path, 4, **MULTISEG_OPTS).why == "pool"
+    assert _split(path, 3, **MULTISEG_OPTS) == ("default", 1)
+
+
+def test_a_stored_index_keeps_the_default(tmp_path, low_floor):
+    path = tmp_path / "dense.bin"
+    path.write_bytes(_rdw_file(*DENSE_ARGS))
+    options = dict(MULTISEG_OPTS, cache_dir=str(tmp_path / "cache"))
+    assert _split(path, 4, **options) == ("default", 100)
+    data = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                      parallelism="4", **options)
+    said = data.metrics.device_stats.as_dict()
+    assert (said["index_shards"], said["pool_split_files"]) == (1, 0)
+
+
+def _hier_file():
+    return hier.generate(3000, 2147483777)[0]
+
+
+# id -> (file bytes, copybook, read_cobol options, whether the index pass
+# is the file's one framing)
+POOL_READ_CASES = {
+    "exp2_shaped_framed_once": (
+        lambda: _rdw_file(*DENSE_ARGS), MULTISEG_COPYBOOK, MULTISEG_OPTS,
+        True),
+    "no_segments": (lambda: _rdw_file(*DENSE_ARGS), MULTISEG_COPYBOOK,
+                    PLAIN, True),
+    "big_endian_ids_without_levels": (
+        lambda: _rdw_file(70000, 3, True), MULTISEG_COPYBOOK,
+        dict(NO_LEVELS, is_rdw_big_endian="true"), True),
+    "hierarchical_test17": (_hier_file, hier.COPYBOOK, HIER_OPTIONS, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(POOL_READ_CASES))
+def test_a_pool_split_read_equals_the_read_at_its_own_split(
+        name, tmp_path, monkeypatch):
+    """Table for table, Seg_Id and Record_Id and nested lists included:
+    the file cut into four shards for a pool of four against the same
+    file at the split it has without the rule (one shard: it is under
+    100 MiB)."""
+    make, copybook, options, framed_once = POOL_READ_CASES[name]
+    path = tmp_path / f"{name}.bin"
+    path.write_bytes(make())
+    monkeypatch.setattr(reader_index, "SPLIT_FLOOR", 4096)
+    pool = read_cobol(str(path), copybook_contents=copybook,
+                      parallelism="4", **options)
+    table = pool.to_arrow()
+    said = pool.metrics.device_stats.as_dict()
+    assert (said["index_shards"], said["pool_split_files"]) == (4, 1)
+    assert _shard_counts(pool) == ((4, 0) if framed_once else (0, 4))
+    monkeypatch.setattr(reader_index, "SPLIT_FLOOR", 1 << 40)
+    own = read_cobol(str(path), copybook_contents=copybook,
+                     parallelism="4", **options)
+    assert own.metrics.shards == 1
+    assert own.metrics.device_stats.as_dict()["pool_split_files"] == 0
+    assert table.equals(own.to_arrow())
+    if name == "exp2_shaped_framed_once":
+        assert {"Seg_Id0", "Seg_Id1", "Record_Id"} <= set(table.column_names)
+        assert pool.to_rows() == own.to_rows()
+
+
+def test_explicit_options_and_other_planners_keep_their_splits(
+        tmp_path, low_floor, monkeypatch):
+    path = tmp_path / "dense.bin"
+    path.write_bytes(_rdw_file(*DENSE_ARGS))
+    sized = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                       parallelism="4", input_split_size_mb="2",
+                       **MULTISEG_OPTS)
+    counted = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                         parallelism="4", input_split_records="100000",
+                         **MULTISEG_OPTS)
+    alone = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                       parallelism="1", **MULTISEG_OPTS)
+    for data, shards in ((sized, 2), (counted, 3), (alone, 1)):
+        said = data.metrics.device_stats.as_dict()
+        assert (said["index_shards"], said["pool_split_files"]) == \
+            (shards, 0)
+    whole = alone.to_arrow()
+    assert sized.to_arrow().equals(whole)
+    assert counted.to_arrow().equals(whole)
+
+    def never(*args, **kwargs):
+        raise AssertionError("the pool's split was asked for")
+
+    monkeypatch.setattr(reader_index, "index_split", never)
+    for options in (dict(pipeline_workers="2"), dict(hosts="2")):
+        data = read_cobol(str(path), copybook_contents=MULTISEG_COPYBOOK,
+                          parallelism="4", **options, **MULTISEG_OPTS)
+        assert "index_shards" not in data.metrics.device_stats.as_dict()
+        assert data.to_arrow().equals(whole)
+
+
+def test_explain_says_which_split_a_read_takes_and_why(tmp_path,
+                                                       low_floor):
+    (tmp_path / "in").mkdir()
+    (tmp_path / "in" / "a.bin").write_bytes(_rdw_file(*DENSE_ARGS))
+    (tmp_path / "in" / "b.bin").write_bytes(_rdw_file(40, 3))
+    path = str(tmp_path / "in")
+    said = [{"why": "pool", "mb": 1}, {"why": "default", "mb": 100}]
+    report = explain(copybook_contents=MULTISEG_COPYBOOK, path=path,
+                     parallelism="4", **MULTISEG_OPTS)
+    assert report.plan["index_split"] == said
+    data = read_cobol(path, copybook_contents=MULTISEG_COPYBOOK,
+                      parallelism="4", explain=True, **MULTISEG_OPTS)
+    assert data.plan["index_split"] == said
+    assert data.metrics.device_stats.as_dict()["pool_split_files"] == 1
+    sized = explain(copybook_contents=MULTISEG_COPYBOOK, path=path,
+                    parallelism="4", input_split_size_mb="2",
+                    **MULTISEG_OPTS)
+    assert sized.plan["index_split"] == [{"why": "option", "mb": 2}] * 2
+    piped = explain(copybook_contents=MULTISEG_COPYBOOK, path=path,
+                    parallelism="4", pipeline_workers="2", **MULTISEG_OPTS)
+    assert "index_split" not in piped.plan

@@ -445,7 +445,7 @@ def test_plan_index_counts_its_header_scan_and_its_segment_ids(
     from cobrix_tpu.reader import index, var_len_reader
 
     if route == "index_whole":
-        monkeypatch.setattr(index, "PREFRAMED_MAX_MEAN_RECORD", 0)
+        monkeypatch.setattr(index, "DENSE_MAX_MEAN_RECORD", 0)
     else:
         # windows of a few hundred records, not the file in one
         monkeypatch.setattr(var_len_reader, "INDEX_WINDOW_SLACK", 512)
