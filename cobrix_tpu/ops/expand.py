@@ -35,8 +35,23 @@ def region_counts(xp, kernels, rows, region):
     uint8, the regions before it already laid out): the dependee by
     `kernels`' decoder of its codec (ops.batch_np or ops.batch_jax),
     then the record walk's clamp."""
-    slab = rows[:, region.depend_offset:
-                region.depend_offset + region.depend_width]
+    values, valid = dependee_values(
+        kernels, rows[:, region.depend_offset:
+                      region.depend_offset + region.depend_width], region)
+    return clamped_counts(xp, values, valid, region)
+
+
+def clamped_counts(xp, values, valid, region):
+    """The record walk's clamp: a count outside the array's bounds, or
+    one that does not decode, takes the maximum."""
+    in_bounds = (valid & (values >= region.min_size)
+                 & (values <= region.max_size))
+    return xp.where(in_bounds, values, region.max_size).astype(xp.int32)
+
+
+def dependee_values(kernels, slab, region):
+    """(values, valid) of `region`'s dependee in the [n, width] bytes
+    `slab`, by `kernels`' decoder of its codec."""
     if region.depend_kind == "binary":
         values, valid = kernels.decode_binary(slab, region.signed,
                                               region.big_endian)
@@ -48,9 +63,7 @@ def region_counts(xp, kernels, rows, region):
                   else kernels.decode_display_ascii)
         values, valid, _ = decode(slab, region.signed, False,
                                   require_digits=True)
-    in_bounds = (valid & (values >= region.min_size)
-                 & (values <= region.max_size))
-    return xp.where(in_bounds, values, region.max_size).astype(xp.int32)
+    return values, valid
 
 
 def expand_rows(xp, kernels, rows, regions: Sequence):

@@ -300,7 +300,9 @@ def _clone_plan(plan: FieldPlan) -> FieldPlan:
 
 def cached_compile_plan(copybook, active_segment: Optional[str] = None,
                         select: Optional[Sequence[str]] = None,
-                        variable_size_occurs: bool = False) -> FieldPlan:
+                        variable_size_occurs: bool = False,
+                        rows_of: Optional[Tuple[str, str]] = None
+                        ) -> FieldPlan:
     """compile_plan with a bounded identity-keyed LRU. The key holds a
     strong reference to the copybook, so an id() can never be recycled
     into a false hit while the entry lives; with the parse cache deduping
@@ -308,7 +310,7 @@ def cached_compile_plan(copybook, active_segment: Optional[str] = None,
     key = (id(copybook),
            active_segment.upper() if active_segment else None,
            tuple(select) if select else None,
-           bool(variable_size_occurs))
+           bool(variable_size_occurs), rows_of)
     with _lock:
         entry = _PLAN_LRU.get(key)
         if entry is not None and entry[0] is copybook:
@@ -317,7 +319,8 @@ def cached_compile_plan(copybook, active_segment: Optional[str] = None,
             return _clone_plan(entry[1])
         _bump("plan_misses")
     plan = compile_plan(copybook, active_segment, select=select,
-                        variable_size_occurs=variable_size_occurs)
+                        variable_size_occurs=variable_size_occurs,
+                        rows_of=rows_of)
     with _lock:
         _PLAN_LRU[key] = (copybook, plan)
         while len(_PLAN_LRU) > _PLAN_CAP:

@@ -31,6 +31,14 @@ Variable layouts are handled statically where possible:
   under a plain REDEFINES, a dependee that is out of reach (another segment
   redefine, inside an array, behind its array), of a string codec or with
   handlers.
+- A variable array whose elements hold variable arrays of their own (two
+  levels, `array_of_arrays`): the whole record has no static layout worth
+  the name (40 slots of the largest element), so it is cut into two row
+  kinds, each with a plan of its own (`rows_of`): the record without the
+  array ("owner": what lies before it, then what lies behind it) and one
+  row an element ("element": the element as a record, its variable arrays
+  regions as above, behind the bytes of the record's prefix its arrays
+  depend on, if any). reader/element_rows.py frames and packs them.
 """
 from __future__ import annotations
 
@@ -348,13 +356,79 @@ def _region_dependee_fault(spec: "ColumnSpec", array_start: int,
     return None
 
 
+def _inside(st: Statement, group: Statement) -> bool:
+    node = st.parent
+    while node is not None:
+        if node is group:
+            return True
+        node = node.parent
+    return False
+
+
+def _variable(st: Statement) -> bool:
+    return st.is_array and st.depending_on is not None
+
+
+def array_of_arrays(copybook: Copybook
+                    ) -> Tuple[Optional[Group], Optional[str]]:
+    """(the variable array whose elements hold variable arrays, why its
+    records cannot be cut into element rows) under
+    `variable_size_occurs`; (None, None) where no variable array holds
+    another. Element rows take one such array, a field of the record's
+    one root group (no REDEFINES over it), beside which no other array
+    varies, and whose count the record holds before it."""
+    variable = [st for st in copybook.ast.walk() if _variable(st)]
+    outers = [st for st in variable if isinstance(st, Group)
+              and any(_variable(c) for c in st.walk())]
+    outers = [st for st in outers
+              if not any(_inside(st, o) for o in outers)]
+    if not outers:
+        return None, None
+    outer = outers[0]
+    roots = [r for r in copybook.ast.children if isinstance(r, Group)]
+    if len(outers) > 1:
+        return outer, (f"{outer.name} and {outers[1].name} are variable "
+                       "arrays of variable arrays")
+    node = outer
+    while node is not None:
+        if node.redefines is not None or node.is_redefined:
+            return outer, (f"{outer.name} is a variable array under a "
+                           "REDEFINES")
+        node = node.parent
+    if outer.parent not in roots:
+        return outer, (f"{outer.name} holds variable arrays and is not a "
+                       "field of the record itself")
+    if len(roots) > 1:
+        return outer, (f"{outer.name} holds variable arrays in a copybook "
+                       "of several records")
+    beside = [st for st in variable
+              if st is not outer and not _inside(st, outer)]
+    if beside:
+        return outer, (f"{beside[0].name} is a variable array beside "
+                       f"{outer.name}, which holds variable arrays")
+    return outer, None
+
+
+def _statement_named(copybook: Copybook, name: str) -> Statement:
+    return next(st for st in copybook.ast.walk() if st.name == name)
+
+
 def compile_plan(copybook: Copybook,
                  active_segment: Optional[str] = None,
                  select: Optional[Sequence[str]] = None,
-                 variable_size_occurs: bool = False) -> FieldPlan:
+                 variable_size_occurs: bool = False,
+                 rows_of: Optional[Tuple[str, str]] = None) -> FieldPlan:
     """Flatten the AST into columns. `active_segment`: compile only columns
     visible when that segment redefine is active (plus common columns);
     None compiles everything (single-segment / fixed-length files).
+
+    `rows_of` ("owner" or "element", the name of an `array_of_arrays`
+    array): the plan of one of the two row kinds its records are cut
+    into. "owner": the record without the array, what lies behind it
+    moved back by the array's static size. "element": one element from
+    its first byte, behind the record's prefix up to the last dependee
+    of the element's arrays that the element does not hold itself (none:
+    the element alone), with only those dependees' columns from it.
 
     `select`: column projection — only primitives whose name (or an
     enclosing group's name) is listed are compiled; everything else decodes
@@ -373,6 +447,9 @@ def compile_plan(copybook: Copybook,
     dependee_cols: Dict[str, int] = {}
     regions: List[VariableRegion] = []
     row_path_reasons: List[str] = []
+    # an element plan's walk of the record's prefix: the dependees its
+    # arrays need, and no other column
+    prefix_only: Optional[set] = None
 
     def note_variable_array(st: Statement, offset: int, in_array: bool,
                             overlaid: bool, segment: Optional[str],
@@ -424,6 +501,8 @@ def compile_plan(copybook: Copybook,
                 and st.name.upper() not in sel \
                 and not any(p.upper() in sel for p in path):
             return
+        if prefix_only is not None and st.name not in prefix_only:
+            return
         codec, params = _classify(st.dtype, fp_format)
         spec = ColumnSpec(
             index=len(columns),
@@ -446,9 +525,20 @@ def compile_plan(copybook: Copybook,
     def walk_children(group: Group, path: Tuple[str, ...], group_offset: int,
                       slot_path: Tuple[int, ...], gates: Tuple[Gate, ...],
                       segment: Optional[str], overlaid: bool = False,
-                      scope_end: Optional[int] = None) -> None:
+                      scope_end: Optional[int] = None,
+                      cut: Optional[Statement] = None,
+                      stop: Optional[Statement] = None) -> None:
+        # `cut`: a child left out, what lies behind it moved back by its
+        # static size; `stop`: the child before which the walk ends
+        moved = 0
         for st in group.children:
-            rel = st.binary_properties.offset - group.binary_properties.offset
+            if st is stop:
+                return
+            if st is cut:
+                moved = st.binary_properties.data_size * st.array_max_size
+                continue
+            rel = (st.binary_properties.offset
+                   - group.binary_properties.offset - moved)
             st_offset = group_offset + rel
             # the walk gives a member of a REDEFINES its static size,
             # whatever it holds: a variable array there moves nothing
@@ -504,7 +594,31 @@ def compile_plan(copybook: Copybook,
     # although the parsed offsets overlay — parity requires matching the
     # walk, not the parsed offsets.
     root_offset = 0
-    for root in copybook.ast.children:
+    record_size = copybook.record_size
+    if rows_of is not None:
+        # one root group holds the array (`array_of_arrays`)
+        part, name = rows_of
+        outer = _statement_named(copybook, name)
+        root = outer.parent
+        size = outer.binary_properties.data_size
+        if part == "owner":
+            walk_children(root, (root.name,), 0, (), (), None, cut=outer)
+            record_size -= size * outer.array_max_size
+        else:
+            held = {st.name for st in outer.walk()}
+            prefix_only = {st.depending_on for st in outer.walk()
+                           if _variable(st)} - held
+            prefix = 0
+            if prefix_only:
+                walk_children(root, (root.name,), 0, (), (), None,
+                              stop=outer)
+                prefix = max((c.offset + c.width for c in columns),
+                             default=0)
+            prefix_only = None
+            walk_children(outer, (root.name, outer.name), prefix, (), (),
+                          None)
+            record_size = prefix + size
+    for root in copybook.ast.children if rows_of is None else ():
         if isinstance(root, Group):
             walk_children(root, (root.name,), root_offset, (), (), None)
             # advance by the walked size (children sum x occurs), not
@@ -529,7 +643,7 @@ def compile_plan(copybook: Copybook,
         regions = [r for r in regions if r.scope_end is None
                    and columns[r.depend_col].segment is None]
     return FieldPlan(
-        record_size=copybook.record_size,
+        record_size=record_size,
         columns=columns,
         groups=list(group_map.values()),
         trimming=copybook.string_trimming_policy,

@@ -316,7 +316,10 @@ class DeviceStats:
     (`odo_records`), the bytes their shifts moved them by in all
     (`odo_shifted_bytes`: with `h2d_bytes`, what an expansion on the host
     would have sent more), and the records the row path walked instead
-    (`odo_fallback_records`); and for the threaded indexed scan (present
+    (`odo_fallback_records`); where an array of variable arrays is read
+    by element rows (reader/element_rows.py), the records cut into them
+    (`odo_nested_records`), the elements (`odo_elements`) and the
+    records the record walk took (`odo_nested_fallback_records`); and for the threaded indexed scan (present
     only where it ran; api._scan_var_len): the shards it planned
     (`index_shards`), the files whose split `reader.index.index_split`
     cut to the pool (`pool_split_files`), the shards that got their
@@ -377,6 +380,11 @@ class DeviceStats:
         self.odo_records = 0
         self.odo_fallback_records = 0
         self.odo_shifted_bytes = 0
+        # an array of variable arrays read by element rows
+        # (reader/element_rows.py)
+        self.odo_nested_records = 0
+        self.odo_elements = 0
+        self.odo_nested_fallback_records = 0
         # index shards of the threaded scan (api._scan_var_len); None:
         # the scan planned none
         self.index_shards: Optional[int] = None
@@ -504,6 +512,15 @@ class DeviceStats:
             self.odo_fallback_records += fallback_records
             self.odo_shifted_bytes += shifted_bytes
 
+    def note_nested(self, records: int, elements: int,
+                    fallback_records: int) -> None:
+        """One shard of an array of variable arrays: `records` cut into
+        element rows, `elements` of them; `fallback_records` walked."""
+        with self._lock:
+            self.odo_nested_records += records
+            self.odo_elements += elements
+            self.odo_nested_fallback_records += fallback_records
+
     def note_plan(self, shards: int, pool_split_files: int) -> None:
         """The threaded scan's plan: `shards` index shards, of files of
         which `pool_split_files` were cut to the pool."""
@@ -556,14 +573,22 @@ class DeviceStats:
     @property
     def odo(self) -> Dict[str, int]:
         """The `odo_*` counts, or {} for a read that met no variable-size
-        OCCURS record."""
+        OCCURS record; the three `odo_nested_*` / `odo_elements` counts
+        only for a read by element rows."""
         with self._lock:
-            if not (self.odo_records or self.odo_fallback_records):
+            nested = {} if not (self.odo_nested_records
+                                or self.odo_nested_fallback_records) else {
+                "odo_nested_records": self.odo_nested_records,
+                "odo_elements": self.odo_elements,
+                "odo_nested_fallback_records":
+                    self.odo_nested_fallback_records}
+            if not (self.odo_records or self.odo_fallback_records
+                    or nested):
                 return {}
             return {"odo_regions": self.odo_regions,
                     "odo_records": self.odo_records,
                     "odo_fallback_records": self.odo_fallback_records,
-                    "odo_shifted_bytes": self.odo_shifted_bytes}
+                    "odo_shifted_bytes": self.odo_shifted_bytes, **nested}
 
     @property
     def device_groups(self) -> Dict[str, int]:

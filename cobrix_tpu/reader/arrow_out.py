@@ -320,8 +320,12 @@ class ArrowBatchBuilder:
     parent struct masks its children by Arrow semantics)."""
 
     def __init__(self, batch: DecodedBatch, active: Optional[str],
-                 redefine_masks: Optional[dict] = None):
+                 redefine_masks: Optional[dict] = None,
+                 nested: Optional[dict] = None):
         self.batch = batch
+        # id(statement) -> its array, built elsewhere: an array of
+        # variable arrays, from rows of its own (`nested_list`)
+        self.nested = nested or {}
         self.decoder = batch.decoder
         self.active = active
         self.redefine_masks = redefine_masks
@@ -1465,6 +1469,8 @@ class ArrowBatchBuilder:
     def _statement_array(self, st: Statement, slot_path,
                          as_element: bool = False):
         pa = _pa()
+        if id(st) in self.nested:
+            return self.nested[id(st)]
         if st.is_array and not as_element:
             return self._list_array(st, slot_path)
         if isinstance(st, Group):
@@ -1512,13 +1518,15 @@ def segment_table(batch: DecodedBatch,
                   seg_level_ids: Optional[Sequence[Sequence[object]]],
                   input_file_name: str = "",
                   redefine_masks: Optional[dict] = None,
-                  corrupt_reasons: Optional[Sequence] = None):
+                  corrupt_reasons: Optional[Sequence] = None,
+                  nested: Optional[dict] = None):
     """One Arrow table for one decoded batch (single active segment, or a
     decode-once batch with per-row redefine masks), with generated columns
     prepended per the output schema. `corrupt_reasons`: per-row values of
-    the trailing corrupt-record debug column (None entries = clean)."""
+    the trailing corrupt-record debug column (None entries = clean).
+    `nested`: arrays the batch does not hold, by id(statement)."""
     pa = _pa()
-    builder = ArrowBatchBuilder(batch, active, redefine_masks)
+    builder = ArrowBatchBuilder(batch, active, redefine_masks, nested)
     n = batch.n_records
     schema = output_schema.schema
 
@@ -1601,6 +1609,19 @@ def segment_table(batch: DecodedBatch,
               if c.type != target.field(i).type else c
               for i, c in enumerate(cols)]
     return pa.Table.from_arrays(arrays, schema=target)
+
+
+def nested_list(batch: DecodedBatch, group: Group, counts: np.ndarray):
+    """The list column of an array of variable arrays whose elements are
+    the rows of `batch` (reader/element_rows.py), `counts` of them a
+    record, in order: the element struct built once over every element,
+    its own lists beneath it, wrapped by the counts' offsets."""
+    pa = _pa()
+    with Stage("assemble.list.nested", batch.stage_stats):
+        structs = ArrowBatchBuilder(batch, None)._struct_array(group, ())
+        offsets = np.zeros(len(counts) + 1, dtype=np.int32)
+        np.cumsum(counts, out=offsets[1:])
+        return pa.ListArray.from_arrays(pa.array(offsets), structs)
 
 
 def rows_to_table(rows: List[List[object]], struct: StructType):

@@ -1394,7 +1394,8 @@ def decoder_for_segment(cache: Dict[str, "ColumnarDecoder"],
                         copybook: Copybook, active: str,
                         backend: str,
                         select: Optional[Sequence[str]] = None,
-                        variable_size_occurs: bool = False
+                        variable_size_occurs: bool = False,
+                        rows_of: Optional[Tuple[str, str]] = None
                         ) -> "ColumnarDecoder":
     """Shared per-(active segment, backend) decoder cache used by both the
     fixed-length and variable-length readers. Locked: the indexed parallel
@@ -1405,6 +1406,8 @@ def decoder_for_segment(cache: Dict[str, "ColumnarDecoder"],
     key = f"{active}|{backend}|{','.join(select) if select else ''}"
     if variable_size_occurs:
         key += "|odo"
+    if rows_of is not None:
+        key += "|" + ":".join(rows_of)
     dec = cache.get(key)
     if dec is None:
         with _decoder_build_lock:
@@ -1414,7 +1417,8 @@ def decoder_for_segment(cache: Dict[str, "ColumnarDecoder"],
                 dec = ColumnarDecoder(
                     copybook, active_segment=active or None, backend=backend,
                     select=select,
-                    variable_size_occurs=variable_size_occurs)
+                    variable_size_occurs=variable_size_occurs,
+                    rows_of=rows_of)
                 cache[key] = dec
                 return dec
     note_decoder(hit=True)
@@ -1426,15 +1430,18 @@ class ColumnarDecoder:
                  active_segment: Optional[str] = None,
                  backend: str = "numpy",
                  select: Optional[Sequence[str]] = None,
-                 variable_size_occurs: bool = False):
+                 variable_size_occurs: bool = False,
+                 rows_of: Optional[Tuple[str, str]] = None):
         """`variable_size_occurs`: the rows this decoder is given hold
         each DEPENDING ON array at its count's size (`plan.regions`); it
-        lays them to the static layout before it decodes them."""
+        lays them to the static layout before it decodes them. `rows_of`:
+        the rows are one of the two kinds an array of variable arrays
+        cuts its records into (compiler.compile_plan)."""
         self.copybook = copybook
         self.select = tuple(select) if select else None
         self.plan: FieldPlan = cached_compile_plan(
             copybook, active_segment, select=self.select,
-            variable_size_occurs=variable_size_occurs)
+            variable_size_occurs=variable_size_occurs, rows_of=rows_of)
         self.backend = validate_backend(backend)
         self.options = DecodeOptions.from_copybook(copybook)
         self.non_standard_ascii_charset = (

@@ -227,14 +227,22 @@ def variable_occurs_route(copybook: Copybook,
     layout in batches (ops/expand.py), on every backend; "rows": the
     host walks every record, and `reason` says why (plan/compiler.py
     says which layouts lay out; the file has to be RDW-framed and not
-    hierarchical). `regions`: active segment redefine ("" for the rows
-    under none) -> its plan's regions."""
+    hierarchical); "elements": a variable array whose elements hold
+    variable arrays, its records cut into two row kinds
+    (reader/element_rows.py; `elements` the ElementRows). `regions`:
+    active segment redefine ("" for the rows under none; for "elements",
+    the array's name for its element's regions) -> its plan's regions."""
     from ..plan.cache import cached_compile_plan
+    from ..plan.compiler import array_of_arrays
+    from . import element_rows
 
     if not params.variable_size_occurs or not any(
             st.is_array and st.depending_on is not None
             for st in copybook.ast.walk()):
         return None
+    outer, reason = array_of_arrays(copybook)
+    if outer is not None:
+        return element_rows.route(copybook, params, reason)
     seg = params.multisegment
     actives = {""} | set((seg.segment_id_redefine_map or {}).values()
                          if seg else ())
@@ -261,6 +269,7 @@ def variable_occurs_route(copybook: Copybook,
             if reason is not None:
                 break
     return {"route": "rows" if reason else "batched", "reason": reason,
+            "elements": None,
             "regions": {active: [
                 {"array": r.name,
                  "depending_on": plan.columns[r.depend_col].name,
@@ -360,7 +369,12 @@ class VarLenReader:
         # (`dynamic_occurs_layout`)
         route = variable_occurs_route(self.copybook, params)
         self.variable_arrays = route is not None
-        self.row_path_reason = route["reason"] if route else None
+        self.row_path_reason = (route["reason"]
+                                if route and route["route"] == "rows"
+                                else None)
+        # an array of variable arrays: its records are cut into owner and
+        # element rows (reader/element_rows.py)
+        self.element_rows = route["elements"] if route else None
         # a hierarchical copybook: assembled in columns, or left to one
         # of the record walks, and why (`hier_row_path_roots`)
         self.hier_route = hierarchical_route(self.copybook, params)
@@ -1085,6 +1099,42 @@ class VarLenReader:
                                    select=self.params.select,
                                    variable_size_occurs=self.variable_arrays)
 
+    def _element_rows_decoder(self, kind: str,
+                              backend: str) -> ColumnarDecoder:
+        """The decoder of one of the two row kinds ("owner", "element")
+        of `element_rows`."""
+        return decoder_for_segment(self._decoders, self.copybook, "",
+                                   backend, variable_size_occurs=True,
+                                   rows_of=(kind,
+                                            self.element_rows.outer.name))
+
+    def _read_result_elements(self, result: "FileResult", data, offsets,
+                              lengths, file_id: int, backend: str,
+                              start_record_id: int,
+                              corrupt_reasons: Optional[dict]) -> None:
+        """A shard of an array of variable arrays, by element rows
+        (reader/element_rows.py): decoded now, assembled when asked."""
+        from . import element_rows
+
+        if corrupt_reasons:
+            result.corrupt_row_reasons = dict(corrupt_reasons)
+        shard = element_rows.decode_shard(self, data, offsets, lengths,
+                                          backend)
+        name = result.input_file_name
+        walked = element_rows.walk_rows(
+            self, data, offsets, lengths, shard.walked, file_id,
+            start_record_id, name)
+        reasons = (result.corrupt_row_reasons or {}) \
+            if result.corrupt_record_field else None
+        result.n_rows = len(offsets)
+        result.rows_factory = lambda: element_rows.walk_rows(
+            self, data, offsets, lengths, range(len(offsets)), file_id,
+            start_record_id, name)
+        result.arrow_factory = lambda output_schema: \
+            element_rows.elements_table(
+                self, shard, output_schema, walked, file_id,
+                start_record_id, name, reasons)
+
     # -- vectorized fast framing (native scan) ------------------------------
 
     @property
@@ -1427,11 +1477,16 @@ class VarLenReader:
             data, base, offsets, lengths, segment_ids, reasons = fast
             result.records_framed = len(offsets)
             with timed_stage(stage_times, "decode"):
-                self._read_result_fast(
-                    result, data, base, offsets, lengths, segment_ids,
-                    file_id, backend,
-                    segment_id_prefix or default_segment_id_prefix(),
-                    start_record_id, corrupt_reasons=reasons)
+                if self.element_rows is not None:
+                    self._read_result_elements(
+                        result, data, offsets, lengths, file_id, backend,
+                        start_record_id, reasons)
+                else:
+                    self._read_result_fast(
+                        result, data, base, offsets, lengths, segment_ids,
+                        file_id, backend,
+                        segment_id_prefix or default_segment_id_prefix(),
+                        start_record_id, corrupt_reasons=reasons)
             return result
         seg = params.multisegment
         prefix = segment_id_prefix or default_segment_id_prefix()

@@ -479,3 +479,36 @@ def test_hier_decode_pallas_full_block(one_chip, mosaic):
     print(f"hier {batch}x108: {mem.argument_size_in_bytes} B in, "
           f"{mem.output_size_in_bytes} B out, "
           f"{mem.temp_size_in_bytes} B of temporaries")
+
+
+def test_tpch_customers_programs_of_the_two_row_kinds(one_chip, mosaic):
+    """The cell tpch_customers_odo_read's two programs at the batches a
+    big read launches: the element rows' (an order less O-CUSTKEY, 1,149 B,
+    its lines a region: the expansion, then the orders' kind of static
+    program) at a quarter of the other programs' bytes a launch, 16,384
+    rows, and the owner rows' (the customer's columns and the comment
+    behind the orders, 224 B, no region) at its block cap. No byte is
+    moved by a gather."""
+    from benchmark.generators import tpch_customers_nested
+
+    copybook = parse_copybook(tpch_customers_nested.COPYBOOK)
+    shapes = {"element": (16384, 1149), "owner": (524288, 224)}
+    for kind, (rows, extent) in shapes.items():
+        decoder = ColumnarDecoder(copybook, backend="pallas",
+                                  variable_size_occurs=True,
+                                  rows_of=(kind, "C_ORDERS"))
+        assert len(decoder.regions) == (kind == "element")
+        assert decoder.plan.max_extent == extent
+        assert full_block(decoder) == rows
+        fn = decoder.build_jax_decode_fn()
+        assert fn.device_groups["gathered"] == 0
+        compiled = compile_on(one_chip, fn, rows, extent)
+        text = compiled.as_text()
+        assert kernel_calls(text) == (0, 1)
+        assert not [line for line in text.splitlines()
+                    if GATHER in line and " u8[" in line.split(GATHER)[0]]
+        mem = compiled.memory_analysis()
+        print(f"customers {kind} {rows}x{extent}: "
+              f"{mem.argument_size_in_bytes} B in, "
+              f"{mem.output_size_in_bytes} B out, "
+              f"{mem.temp_size_in_bytes} B of temporaries")

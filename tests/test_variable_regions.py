@@ -18,7 +18,8 @@ from cobrix_tpu import read_cobol
 from cobrix_tpu.copybook.copybook import parse_copybook
 from cobrix_tpu.encode import RecordEncoder
 from cobrix_tpu.ops import batch_np, expand
-from cobrix_tpu.plan.compiler import VariableRegion, compile_plan
+from cobrix_tpu.plan.compiler import (VariableRegion, array_of_arrays,
+                                      compile_plan)
 from cobrix_tpu.reader import columnar
 
 BATCHED = ("numpy", "jax", "pallas")
@@ -442,7 +443,8 @@ def cross_rows(rnd, n):
 
 
 ROW_PATH = {
-    "nested": (NESTED, nested_rows, {}, "inside another array"),
+    # the inner count in the record's prefix: element rows carry it
+    "nested": (NESTED, nested_rows, {}, "OUTER holds variable arrays"),
     "cross_redefine": (CROSS_REDEFINE, cross_rows,
                        dict(segment_field="SEG-ID",
                             redefine_segment_id_map="ORDERS => O",
@@ -454,6 +456,8 @@ ROW_PATH = {
 @pytest.mark.parametrize("backend", BATCHED)
 @pytest.mark.parametrize("layout", sorted(ROW_PATH))
 def test_layouts_left_to_the_record_walk(tmp_path, layout, backend):
+    """What the batched route leaves to the walk, counted; a variable
+    array of variable arrays ("nested") now goes by element rows."""
     copybook, make, options, why = ROW_PATH[layout]
     rows = make(random.Random(4), 25)
     path = write(tmp_path, encode(copybook, rows))
@@ -461,15 +465,24 @@ def test_layouts_left_to_the_record_walk(tmp_path, layout, backend):
     data = read(path, copybook, backend, **options)
     assert data.to_arrow().equals(ref)
     metrics = data.metrics.as_dict()
+    from cobrix_tpu.explain import explain
+    plan = explain(copybook_contents=copybook, is_record_sequence="true",
+                   variable_size_occurs="true", **options).plan
+    assert why in plan["variable_occurs_reason"]
+    if layout == "nested":
+        elements = sum(r[0][0] for r in rows)
+        assert plan["variable_occurs"] == "elements"
+        assert metrics["odo"]["odo_nested_records"] == 25
+        assert metrics["odo"]["odo_elements"] == elements
+        assert metrics["odo"]["odo_records"] == elements
+        assert metrics["odo"]["odo_nested_fallback_records"] == 0
+        assert ("device" in metrics) == (backend in columnar.DEVICE_BACKENDS)
+        return
     assert metrics["odo"] == {"odo_regions": 0, "odo_records": 0,
                               "odo_fallback_records": 25,
                               "odo_shifted_bytes": 0}
     assert "device" not in metrics      # no byte reached a device
-    from cobrix_tpu.explain import explain
-    plan = explain(copybook_contents=copybook, is_record_sequence="true",
-                   variable_size_occurs="true", **options).plan
     assert plan["variable_occurs"] == "rows"
-    assert why in plan["variable_occurs_reason"]
 
 
 @pytest.mark.parametrize("backend", BATCHED)
@@ -498,6 +511,335 @@ def test_explain_reports_the_route_and_the_regions():
     assert "variable_occurs_reason" not in plan
     off = explain(copybook_contents=TWO, is_record_sequence="true").plan
     assert "variable_occurs" not in off
+
+
+# -- a variable array of variable arrays: element rows ----------------------
+
+CUSTOMERS = """
+       01  CUST.
+           05  C-ID      PIC S9(9) COMP.
+           05  C-NAME    PIC X(6).
+           05  C-CNT     PIC 9(2).
+           05  C-ORDERS OCCURS 0 TO 40 TIMES DEPENDING ON C-CNT.
+               10  O-KEY     PIC S9(9) COMP.
+               10  O-AMT     PIC S9(7)V99 COMP-3.
+               10  O-CNT     PIC 9(1).
+               10  O-LINES OCCURS 1 TO 7 TIMES DEPENDING ON O-CNT.
+                   15  L-QTY PIC S9(4) COMP.
+                   15  L-TXT PIC X(3).
+               10  O-NOTE    PIC X(5).
+           05  C-COMMENT PIC X(8).
+"""
+# C-ID 0, C-CNT 10, C-ORDERS 12: an order 10 + 7 x 5 + 5 = 50 B at most
+ORDER_AT, ORDER_HEAD, LINE, ORDER_TAIL = 12, 10, 5, 5
+
+TWO_INNER = """
+       01  REC.
+           05  N         PIC 9(1).
+           05  GRP OCCURS 0 TO 3 TIMES DEPENDING ON N.
+               10  A-CNT PIC 9(1).
+               10  A OCCURS 0 TO 3 TIMES DEPENDING ON A-CNT.
+                   15  A-V PIC S9(4) COMP.
+               10  B-CNT PIC S9(3) COMP-3.
+               10  B OCCURS 1 TO 2 TIMES DEPENDING ON B-CNT.
+                   15  B-V PIC X(3).
+               10  G-END PIC 9(2).
+           05  TAIL      PIC X(4).
+"""
+
+
+def customer_rows(rnd, n, orders=None, lines=None):
+    rows = []
+    for i in range(n):
+        c = (rnd.choice([0, 1, 2, 5, 40]) if orders is None
+             else orders[i % len(orders)])
+        elements = []
+        for k in range(c):
+            m = rnd.randint(1, 7) if lines is None else lines[k % len(lines)]
+            elements.append((rnd.randint(-10 ** 8, 10 ** 8),
+                             D(rnd.randint(-10 ** 8, 10 ** 8)) / 100, m,
+                             [(rnd.randint(-999, 999), name(rnd, 3))
+                              for _ in range(m)], name(rnd, 5)))
+        rows.append([(i, name(rnd, 6), c, elements, "E%07d" % i)])
+    return rows
+
+
+def two_inner_rows(rnd, n):
+    rows = []
+    for i in range(n):
+        groups = []
+        for _ in range(rnd.randint(0, 3)):
+            a, b = rnd.randint(0, 3), rnd.randint(1, 2)
+            groups.append((a, [(rnd.randint(-999, 999),) for _ in range(a)],
+                           b, [(name(rnd, 3),) for _ in range(b)],
+                           rnd.randint(0, 99)))
+        rows.append([(len(groups), groups, name(rnd, 4))])
+    return rows
+
+
+ELEMENT_LAYOUTS = {
+    "customers": (CUSTOMERS, customer_rows),
+    "two_inner": (TWO_INNER, two_inner_rows),
+    "prefix_count": (NESTED, nested_rows),
+}
+
+
+def decoder_width(copybook, kind):
+    parsed = parse_copybook(copybook)
+    outer, _ = array_of_arrays(parsed)
+    return compile_plan(parsed, variable_size_occurs=True,
+                        rows_of=(kind, outer.name)).max_extent
+
+
+def assert_element_rows(path, copybook, backend, rows, elements,
+                        walked=0, **options):
+    """The read by element rows equals the record walk's, table for table
+    and row for row, with `walked` records left to the walk, counted."""
+    ref = read(path, copybook, "host", **options)
+    data = read(path, copybook, backend, **options)
+    table, expected = data.to_arrow(), ref.to_arrow()
+    assert table.schema.equals(expected.schema)
+    assert table.num_rows == rows
+    for column in table.column_names:
+        assert table[column].equals(expected[column]), column
+    assert data.to_rows() == ref.to_rows()
+    metrics = data.metrics.as_dict()
+    odo = metrics["odo"]
+    assert odo["odo_nested_records"] == rows - walked
+    assert odo["odo_elements"] == elements
+    assert odo["odo_nested_fallback_records"] == walked
+    if backend in columnar.DEVICE_BACKENDS:
+        device = metrics["device"]
+        # the two row kinds, each at its own width
+        assert {shape.split("x")[1] for shape in device["launches"]} == {
+            str(decoder_width(copybook, kind)) for kind in ("owner",
+                                                            "element")}
+        assert device["records"] == rows - walked + elements
+        assert device["odo_nested_records"] == rows - walked
+    return data, metrics
+
+
+@pytest.mark.parametrize("backend", BATCHED)
+@pytest.mark.parametrize("layout", sorted(ELEMENT_LAYOUTS))
+def test_element_rows_equal_the_record_walk(tmp_path, layout, backend):
+    """Customers of 0, 1, 2, 5 and 40 orders of 1 to 7 lines, the comment
+    behind them; two variable arrays in an element, the second's count
+    behind the first; the inner count in the record's prefix."""
+    copybook, make = ELEMENT_LAYOUTS[layout]
+    rows = make(random.Random(len(layout)), 37)
+    path = write(tmp_path, encode(copybook, rows))
+    assert_element_rows(path, copybook, backend, 37,
+                        sum(r[0][-3 if layout == "customers" else 0]
+                            for r in rows))
+
+
+def spoil(body: bytes, at: int, value: bytes) -> bytes:
+    return body[:at] + value + body[at + len(value):]
+
+
+@pytest.mark.parametrize("backend", BATCHED)
+def test_element_rows_at_the_bounds_and_outside_them(tmp_path, backend):
+    """Counts outside their bounds or not digits take the maximum, as the
+    walk does: a customer of 40 orders whose count reads 99 or spaces, an
+    order of 7 lines whose count reads 9, 0 or a space (the first element
+    of its record: nothing read before it). Such records stay whole and go
+    by element rows."""
+    rnd = random.Random(5)
+    bodies = encode(CUSTOMERS, customer_rows(rnd, 6, orders=[0, 1, 40],
+                                             lines=[1, 7]))
+    full = encode(CUSTOMERS, customer_rows(rnd, 2, orders=[40],
+                                           lines=[7]))
+    bodies += [spoil(full[0], 10, b"\xf9\xf9"), spoil(full[1], 10,
+                                                      b"\x40\x40")]
+    seven = encode(CUSTOMERS, customer_rows(rnd, 3, orders=[1], lines=[7]))
+    bodies += [spoil(body, ORDER_AT + ORDER_HEAD - 1, value)
+               for body, value in zip(seven, (b"\xf9", b"\xf0", b"\x40"))]
+    path = write(tmp_path, bodies)
+    data, _ = assert_element_rows(path, CUSTOMERS, backend, 11,
+                                  2 * 41 + 40 + 40 + 3)
+    orders = data.to_arrow()["C_ORDERS"].to_pylist()
+    assert [len(o) for o in orders] == [0, 1, 40, 0, 1, 40, 40, 40, 1, 1, 1]
+    assert [len(o[0]["O_LINES"]) for o in orders[8:]] == [7, 7, 7]
+
+
+@pytest.mark.parametrize("backend", BATCHED)
+def test_records_element_rows_cannot_take_are_walked(tmp_path, backend):
+    """A record cut inside an element it shows, one cut inside the field
+    behind the array, one cut inside its prefix, and one whose second
+    order's count does not decode (the walk keeps the first order's
+    count): each decoded by the record walk alone, counted; and one
+    with bytes to spare, which is whole."""
+    rnd = random.Random(9)
+    bodies = encode(CUSTOMERS, customer_rows(rnd, 5, orders=[2, 3]))
+    whole = encode(CUSTOMERS, customer_rows(rnd, 5, orders=[2], lines=[3]))
+    second = ORDER_AT + ORDER_HEAD + 3 * LINE + ORDER_TAIL
+    bodies += [whole[0][:ORDER_AT + 20], whole[1][:-3], whole[2][:8],
+               spoil(whole[3], second + ORDER_HEAD - 1, b"\x40"),
+               whole[4] + b"\x40" * 6]
+    path = write(tmp_path, bodies)
+    data, metrics = assert_element_rows(
+        path, CUSTOMERS, backend, 10,
+        sum(r[0][2] for r in customer_rows(random.Random(9), 5,
+                                           orders=[2, 3])) + 2, walked=4)
+    assert metrics["odo"]["odo_fallback_records"] == 0
+    assert data.to_arrow()["C_COMMENT"].to_pylist()[9] == "E0000004"
+
+
+@pytest.mark.parametrize("backend", BATCHED)
+@pytest.mark.parametrize("kind", ["cut", "padded", "spoiled"])
+def test_damaged_records_by_element_rows_equal_the_walk(tmp_path, kind,
+                                                         backend):
+    """Records cut at any byte, padded with noise, or with one byte
+    spoiled (counts included): whatever the walk makes of them."""
+    rnd = random.Random(kind)
+    bodies = []
+    for body in encode(CUSTOMERS, customer_rows(rnd, 40)):
+        if rnd.random() < 0.5:
+            if kind == "cut":
+                body = body[:rnd.randint(1, len(body))]
+            elif kind == "padded":
+                body += bytes(rnd.randint(0, 255)
+                              for _ in range(rnd.randint(1, 12)))
+            else:
+                body = spoil(body, rnd.randrange(len(body)),
+                             bytes([rnd.randint(0, 255)]))
+        bodies.append(body)
+    path = write(tmp_path, bodies)
+    expected = read(path, CUSTOMERS, "host").to_arrow()
+    table = read(path, CUSTOMERS, backend).to_arrow()
+    for column in table.column_names:
+        assert table[column].equals(expected[column]), column
+
+
+@pytest.mark.parametrize("backend", BATCHED)
+def test_element_rows_with_generated_columns_and_shards(tmp_path, backend):
+    """Record_Id and the file name beside the columns, the file cut into
+    index shards, the schema kept under its root, a walked record among
+    them: in file order, as the walk numbers them."""
+    rnd = random.Random(3)
+    bodies = encode(CUSTOMERS, customer_rows(rnd, 120))
+    bodies[50] = bodies[50][:ORDER_AT + 3]
+    path = write(tmp_path, bodies)
+    options = dict(generate_record_id="true", input_split_records="25",
+                   with_input_file_name_col="F",
+                   schema_retention_policy="keep_original")
+    _, metrics = assert_element_rows(
+        path, CUSTOMERS, backend, 120,
+        sum(r[0][2] for i, r in enumerate(
+            customer_rows(random.Random(3), 120)) if i != 50),
+        walked=1, **options)
+    assert metrics["shards"] >= 4
+
+
+@pytest.mark.parametrize("backend", BATCHED)
+def test_element_rows_counters_and_stages(tmp_path, backend):
+    rows = customer_rows(random.Random(17), 30)
+    path = write(tmp_path, encode(CUSTOMERS, rows))
+    data = read(path, CUSTOMERS, backend)
+    data.to_arrow()
+    metrics = data.metrics.as_dict()
+    assert set(metrics["odo"]) == {
+        "odo_regions", "odo_records", "odo_fallback_records",
+        "odo_shifted_bytes", "odo_nested_records", "odo_elements",
+        "odo_nested_fallback_records"}
+    elements = sum(r[0][2] for r in rows)
+    assert (metrics["odo"]["odo_nested_records"],
+            metrics["odo"]["odo_elements"],
+            metrics["odo"]["odo_nested_fallback_records"]) == (
+                30, elements, 0)
+    # the elements' own lines through the single-level expansion
+    assert metrics["odo"]["odo_records"] == elements
+    assert metrics["native_passes"]["struct_list"] >= 1
+    stats = data.metrics.device_stats
+    for stage in ("frame.elements", "assemble.list.nested", "expand"):
+        assert stats.stage_n[stage] >= 1, stage
+    assert "assemble.list.slots" not in stats.stage_n
+    if backend in columnar.DEVICE_BACKENDS:
+        device = metrics["device"]
+        for key, value in metrics["odo"].items():
+            assert device[key] == value
+        assert "frame.elements" in device["stage_s"]
+
+
+def test_explain_reports_element_rows():
+    from cobrix_tpu.explain import explain
+
+    plan = explain(copybook_contents=CUSTOMERS, is_record_sequence="true",
+                   variable_size_occurs="true").plan
+    assert plan["variable_occurs"] == "elements"
+    assert plan["variable_occurs_reason"] == (
+        "C_ORDERS holds variable arrays: an element is a row of its own")
+    assert plan["variable_regions"] == ["C_ORDERS[0..40]x50B@12",
+                                        "O_LINES[1..7]x5B@10 in C_ORDERS"]
+
+
+@pytest.mark.parametrize("copybook,why,options", [
+    # a third level
+    ("""
+       01  REC.
+           05  N1  PIC 9(1).
+           05  A OCCURS 0 TO 2 TIMES DEPENDING ON N1.
+               10  N2  PIC 9(1).
+               10  B OCCURS 0 TO 2 TIMES DEPENDING ON N2.
+                   15  N3  PIC 9(1).
+                   15  C OCCURS 0 TO 2 TIMES DEPENDING ON N3.
+                       20  V PIC X(1).
+           05  TAIL PIC X(2).
+     """, "C is a variable array inside another array", {}),
+    # the outer array under a REDEFINES
+    ("""
+       01  REC.
+           05  N1  PIC 9(1).
+           05  PLAIN PIC X(20).
+           05  OVER REDEFINES PLAIN.
+               10  A OCCURS 0 TO 2 TIMES DEPENDING ON N1.
+                   15  N2  PIC 9(1).
+                   15  B OCCURS 0 TO 3 TIMES DEPENDING ON N2.
+                       20  V PIC X(3).
+     """, "A is a variable array under a REDEFINES", {}),
+    # another variable array beside it
+    (CUSTOMERS.replace("           05  C-COMMENT PIC X(8).",
+                       "           05  T OCCURS 0 TO 2 TIMES "
+                       "DEPENDING ON C-CNT.\n"
+                       "               10  T-V PIC X(1)."),
+     "T is a variable array beside C_ORDERS", {}),
+    # no RDW
+    (CUSTOMERS, "without RDW headers", dict(is_record_sequence="false")),
+])
+def test_arrays_of_arrays_the_route_declines(copybook, why, options):
+    from cobrix_tpu.explain import explain
+
+    options.setdefault("is_record_sequence", "true")
+    plan = explain(copybook_contents=copybook, variable_size_occurs="true",
+                   **options).plan
+    assert plan["variable_occurs"] == "rows"
+    assert why in plan["variable_occurs_reason"]
+
+
+@pytest.mark.parametrize("kind,size,columns", [
+    ("owner", 12 + 8, ["C_ID", "C_NAME", "C_CNT", "C_COMMENT"]),
+    ("element", 50, ["O_KEY", "O_AMT", "O_CNT"] + ["L_QTY", "L_TXT"] * 7
+     + ["O_NOTE"]),
+])
+def test_plans_of_the_two_row_kinds(kind, size, columns):
+    plan = compile_plan(parse_copybook(CUSTOMERS), variable_size_occurs=True,
+                        rows_of=(kind, "C_ORDERS"))
+    assert plan.record_size == plan.max_extent == size
+    assert [c.name for c in plan.columns] == columns
+    assert plan.row_path_reason is None
+    if kind == "owner":
+        assert plan.regions == ()
+        assert plan.columns[-1].offset == 12      # moved behind the prefix
+        return
+    (region,) = plan.regions
+    assert (region.name, region.start, region.depend_offset,
+            region.element_size, region.max_size) == ("O_LINES", 10, 9, 5, 7)
+    # the inner count in the record's prefix: the element row carries it
+    nested = compile_plan(parse_copybook(NESTED), variable_size_occurs=True,
+                          rows_of=("element", "OUTER"))
+    assert [c.name for c in nested.columns[:1]] == ["INNER_CNT"]
+    assert nested.regions[0].depend_offset == 1
+    assert nested.regions[0].start == 2
 
 
 # -- the plan's regions ------------------------------------------------------
