@@ -293,9 +293,11 @@ class PassCounters:
 
 class DeviceStats:
     """What the device decode plane did for one read: program launches by
-    padded batch shape, the records they decoded (padding excluded),
-    bytes over the link each way, the link home by its own counts
-    (`d2h_*`, below), the seconds spent
+    padded batch shape, the records they decoded (padding excluded) and
+    the rows they were launched as (`launch_rows`, padding included:
+    `records` over it is the launches' fill), bytes over the link each
+    way, the link home by its own counts (`d2h_*`, below), the seconds
+    spent
     compiling (`compile_s`, of which `lower_s` tracing and lowering), the
     devices the outputs lived on, what kind of program ran (does it hold
     the fused kernel; was that kernel interpreted; how many kernel groups
@@ -349,6 +351,7 @@ class DeviceStats:
         self._lock = threading.Lock()
         self.launches: Dict[tuple, int] = {}
         self.records = 0
+        self.launch_rows = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         # the link home (LinkCopy, note_launch)
@@ -459,6 +462,7 @@ class DeviceStats:
                 self._program_groups[id(device_groups)] = device_groups
             self.launches[shape] = self.launches.get(shape, 0) + 1
             self.records += records
+            self.launch_rows += shape[0]
             self.h2d_bytes += h2d_bytes
             self.d2h_bytes += d2h_bytes
             self.devices.update(str(d) for d in devices)
@@ -631,6 +635,7 @@ class DeviceStats:
                 "launches": {f"{b}x{e}": n for (b, e), n
                              in sorted(self.launches.items())},
                 "records": self.records,
+                "launch_rows": self.launch_rows,
                 "h2d_bytes": self.h2d_bytes,
                 "d2h_bytes": self.d2h_bytes,
                 "d2h_copy_thread_s": round(self.d2h_copy_thread_s, 9),

@@ -1343,6 +1343,16 @@ DEVICE_BACKENDS = ("jax", "pallas")
 # bounded whatever the shard size and a big read compiles one shape
 DEVICE_BLOCK_BYTES = 128 * 1024 * 1024
 
+# a launch pads its rows to a bucket (ColumnarDecoder._bucket_size), and
+# the host's link work (the zeroed buffer, its linearization, the fetch of
+# every output) grows with the padded rows. Past BUCKET_OCTAVE_ROWS the
+# buckets are a quarter octave apart, where a power of two left up to half
+# a launch zeros: exp1's 64 MiB chunk of 44,949 records goes as 49,152
+# rows, not 65,536. Every bucket is a multiple of the rows-in-lanes
+# kernel's grid step (ops/pallas_tpu.LANE_TILE), so neither kernel pads
+LAUNCH_ROW_STEP = 4096
+BUCKET_OCTAVE_ROWS = 4 * LAUNCH_ROW_STEP
+
 # a program that lays variable-size OCCURS records to the static layout
 # (ops/expand.py) holds a launch's rows several times over on the chip:
 # as they came, with the bytes behind each array shifted, expanded, and
@@ -1375,7 +1385,8 @@ PARTITION_MIN_SAVED_BYTES = 256
 # beside the 0.19 s its transfer takes); whole rows of 128 arrive as
 # they lie, in the same 0.19 s, and so does a flat array, whose first
 # compile takes 27 s where this takes 6 (PERF.md section 6, PR 33).
-# Every launch bucket (a power of two from 256) divides into such rows
+# Every launch bucket (a power of two from 256, or a multiple of
+# LAUNCH_ROW_STEP) divides into such rows
 POINTS_LANES = 128
 
 
@@ -1939,8 +1950,15 @@ class ColumnarDecoder:
 
     @staticmethod
     def _bucket_size(n: int) -> int:
-        """Round the batch size up to a power-of-two bucket (>= 256) so the
-        jitted decode is traced a bounded number of times."""
+        """The rows a launch of `n` is padded to, so the jitted decode is
+        traced a bounded number of times: a power of two from 256 up to
+        BUCKET_OCTAVE_ROWS, above it a quarter of the octave's power of
+        two (LAUNCH_ROW_STEP at least), at most four shapes an octave and
+        each at least 80 % full."""
+        n = int(n)
+        if n > BUCKET_OCTAVE_ROWS:
+            step = max(LAUNCH_ROW_STEP, (1 << (n.bit_length() - 1)) // 4)
+            return -(-n // step) * step
         b = 256
         while b < n:
             b *= 2

@@ -256,9 +256,10 @@ def test_device_aggregator(topo, mosaic, n_chips, columns):
 @pytest.mark.parametrize("name", ["q6", "q1"])
 def test_tpch_query_programs(one_chip, mosaic, name):
     """The cell tpch_q6_q1's two programs at the shape a 64 MiB read chunk
-    of 149 B records launches: the kernel, the predicate and the grouped
-    integer reductions in one program, no gather, scatter or sort, far
-    inside the chip's memory, a few hundred bytes out."""
+    of 149 B records launches (450,395 rows in a bucket of 458,752): the
+    kernel, the predicate and the grouped integer reductions in one
+    program, no gather, scatter or sort, far inside the chip's memory, a
+    few hundred bytes out."""
     import json
 
     import jax
@@ -294,8 +295,9 @@ def test_tpch_query_programs(one_chip, mosaic, name):
     assert mem.output_size_in_bytes < 32768
     text = compiled.as_text()
     # two narrow groups a query (COMP-3 and the DISPLAY date): one
-    # rows-in-lanes kernel of 128 grid steps, where 32 rows a step made
-    # 16,384
+    # rows-in-lanes kernel of 112 grid steps over a bucket of 458,752
+    # rows, where 32 rows a step made 14,336
+    assert rows == 458_752
     assert program.device_groups["fused"] == 2
     assert program.device_groups["fused_rows_in_lanes"] == 2
     assert kernel_calls(text) == (0, 1) and GATHER not in text
@@ -411,6 +413,58 @@ def test_exp1_decode_pallas_whole_program(one_chip, mosaic):
     assert kernel_calls(text) == (0, 1)
     assert GATHER not in text
 
+
+def _exp1_decoder():
+    return ColumnarDecoder(parse_copybook(EXP1_COPYBOOK), backend="pallas")
+
+
+def _exp2_decoder():
+    return ColumnarDecoder(
+        parse_copybook(EXP2_COPYBOOK,
+                       segment_redefines=["STATIC_DETAILS", "CONTACTS"]),
+        backend="pallas")
+
+
+def _hier_decoder():
+    from benchmark.generators import hier_companies
+
+    return ColumnarDecoder(
+        parse_copybook(hier_companies.COPYBOOK,
+                       segment_redefines=list(hier_companies.SEGMENTS)),
+        backend="pallas")
+
+
+@pytest.mark.parametrize("cell, build, records, batch, extent, out_row", [
+    # a 64 MiB chunk of 44,949 records, 65,536 rows at the power of two;
+    # 2,240 B a row of outputs in the chip's layouts, 1,862 fetched
+    ("exp1", _exp1_decoder, 44_949, 49_152, 1493, 2304),
+    # a 20 MiB shard of about 313 k records, 524,288 at the power of two
+    ("hier", _hier_decoder, 313_000, 327_680, 108, 192),
+    ("exp2", _exp2_decoder, 316_000, 327_680, 64, 128),
+])
+def test_programs_at_a_quarter_octave_bucket(one_chip, mosaic, cell, build,
+                                             records, batch, extent,
+                                             out_row):
+    """A cell's whole program at the bucket its launch now pads to, a
+    quarter octave above its records where the power of two was up to
+    an octave: the one rows-in-lanes kernel call it makes at a power of
+    two, on grid steps that need no padding, no gather, and inside the
+    chip's memory."""
+    decoder = build()
+    assert decoder.plan.max_extent == extent
+    assert decoder._device_block(records, extent) == batch
+    assert batch % pallas_tpu.LANE_TILE == 0
+    fn = decoder.build_jax_decode_fn()
+    assert fn.device_groups["gathered"] == 0
+    compiled = compile_on(one_chip, fn, batch, extent)
+    text = compiled.as_text()
+    assert kernel_calls(text) == (0, 1)
+    assert GATHER not in text
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes < batch * out_row
+    print(f"{cell} {batch}x{extent}: {mem.argument_size_in_bytes} B in, "
+          f"{mem.output_size_in_bytes} B out, "
+          f"{mem.temp_size_in_bytes} B of temporaries")
 
 
 def test_tpch_orders_program_with_the_expansion(one_chip, mosaic):
