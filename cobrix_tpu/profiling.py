@@ -331,7 +331,9 @@ class DeviceStats:
     root rows `hierarchical_table` assembled (`hier_roots`), the records
     it assembled them from (`hier_records`), the child structs it put
     under a parent (`hier_children`), the child records that found none
-    (`hier_orphans`), the roots the record walk assembled because the
+    (`hier_orphans`), the leaves it built at a struct's rows
+    (`hier_leaves_at`) and those it built at full length and took
+    (`hier_leaves_taken`), the roots the record walk assembled because the
     columnar assembly declined (`hier_row_path_roots`) and why the last
     of them did (`hier_decline_reason`).
     The link home, noted where a launch is fetched (`ColumnarDecoder.
@@ -400,6 +402,8 @@ class DeviceStats:
         self.hier_records = 0
         self.hier_children = 0
         self.hier_orphans = 0
+        self.hier_leaves_at = 0
+        self.hier_leaves_taken = 0
         self.hier_row_path_roots = 0
         self.hier_decline_reason: Optional[str] = None
         # route counts of every decode program launched, by identity: a
@@ -543,17 +547,22 @@ class DeviceStats:
 
     def note_hier(self, roots: int = 0, records: int = 0,
                   children: int = 0, orphans: int = 0,
+                  leaves_at: int = 0, leaves_taken: int = 0,
                   row_path_roots: int = 0,
                   reason: Optional[str] = None) -> None:
         """One shard of a hierarchical read: `roots` rows assembled in
         columns from `records` records, `children` child structs put
-        under a parent and `orphans` child records left under none; or
-        `row_path_roots` roots left to the record walk, for `reason`."""
+        under a parent and `orphans` child records left under none, a
+        struct's leaves built at its rows (`leaves_at`) or at full
+        length and taken (`leaves_taken`); or `row_path_roots` roots
+        left to the record walk, for `reason`."""
         with self._lock:
             self.hier_roots += roots
             self.hier_records += records
             self.hier_children += children
             self.hier_orphans += orphans
+            self.hier_leaves_at += leaves_at
+            self.hier_leaves_taken += leaves_taken
             self.hier_row_path_roots += row_path_roots
             if reason is not None:
                 self.hier_decline_reason = reason
@@ -569,6 +578,8 @@ class DeviceStats:
                       "hier_records": self.hier_records,
                       "hier_children": self.hier_children,
                       "hier_orphans": self.hier_orphans,
+                      "hier_leaves_at": self.hier_leaves_at,
+                      "hier_leaves_taken": self.hier_leaves_taken,
                       "hier_row_path_roots": self.hier_row_path_roots}
             if self.hier_decline_reason is not None:
                 counts["hier_decline_reason"] = self.hier_decline_reason

@@ -34,6 +34,7 @@ from .columnar import (
     _NATIVE_TRIM_MODES,
     _STRING_CODECS,
     _dyn_scale,
+    _identity_lut,
     _is_wide,
     _resolve_occurs,
     DecodedBatch,
@@ -348,23 +349,27 @@ class ArrowBatchBuilder:
     # -- leaves ------------------------------------------------------------
 
     def leaf_strings_at(self, sts, positions: np.ndarray) -> dict:
-        """String-leaf values built AT `positions` straight from the raw
-        file image, for EVERY eligible statement of one struct in ONE
-        subset kernel call (per-column calls paid the wrapper/gather
-        overhead once per leaf). Returns {id(st): pa.StringArray} for the
-        leaves it could build; callers fall back to the full-length
-        build + take for the rest (non-EBCDIC codecs, no raw image,
-        truncated rows, native library unavailable)."""
-        from .. import native
-
+        """String-leaf values built AT `positions`, for EVERY eligible
+        statement of one struct in ONE subset kernel call (stage
+        `assemble.string`), from what the batch holds: the raw file
+        image, or else the matrix of 8-bit code points a device program
+        handed back (`char_plane`), read as an image of rows of its
+        width with a table that maps a code point to itself, since the
+        chip has done the lookup. Returns {id(st): pa.StringArray} for
+        the leaves it could build; callers fall back to the full-length
+        build + take for the rest (non-EBCDIC codecs, 16-bit code
+        points, no image or matrix, truncated rows, a column whose UTF-8
+        outgrew its buffer, native library unavailable)."""
         pa = _pa()
-        rs = self.batch.raw_source
+        batch = self.batch
+        rs = batch.raw_source
         trim = _NATIVE_TRIM_MODES.get(self.decoder.plan.trimming)
-        if rs is None or trim is None or not native.available():
+        if trim is None or not native.available():
             return {}
-        buf, offs, lens = rs
-        sub_offs = sub_lens = None
-        chosen, specs = [], []
+        lengths = batch.lengths if rs is None else rs[2]
+        sub_lens = None if lengths is None else lengths[positions]
+        matrix = None
+        chosen, specs, starts = [], [], []
         for st in sts:
             col = self.decoder.slot_map.get((id(st), ()))
             if col is None:
@@ -372,25 +377,39 @@ class ArrowBatchBuilder:
             spec = self.decoder.plan.columns[col]
             if spec.codec is not Codec.EBCDIC_STRING:
                 continue
-            if sub_lens is None:
-                sub_offs = offs[positions]
-                sub_lens = lens[positions]
-            if bool((sub_lens < spec.offset + spec.width).any()):
+            if sub_lens is not None and bool(
+                    (sub_lens < spec.offset + spec.width).any()):
                 continue  # truncated tails keep the scalar-owned path
+            if rs is None:
+                plane = batch._out[col].get("char_plane")
+                if (plane is None or plane[0].dtype != np.uint8
+                        or (matrix is not None and plane[0] is not matrix)):
+                    continue
+                matrix, start = plane
+            else:
+                start = spec.offset
             chosen.append(st)
             specs.append(spec)
+            starts.append(start)
         if not specs:
             return {}
-        res = native.string_cols_arrow_raw(
-            buf, sub_offs, sub_lens,
-            np.asarray([sp.offset for sp in specs], dtype=np.int64),
-            np.asarray([sp.width for sp in specs], dtype=np.int64),
-            self.decoder.lut, trim)
+        if rs is None:
+            matrix = np.ascontiguousarray(matrix)
+            width = matrix.shape[1]
+            buf, lut = matrix.reshape(-1), _identity_lut()
+            sub_offs = positions.astype(np.int64) * width
+            sub_lens = np.full(len(positions), width, dtype=np.int64)
+        else:
+            buf, lut = rs[0], self.decoder.lut
+            sub_offs = rs[1][positions]
+        with Stage("assemble.string", self.stats):
+            res = native.string_cols_arrow_raw(
+                buf, sub_offs, sub_lens, np.asarray(starts, dtype=np.int64),
+                np.asarray([sp.width for sp in specs], dtype=np.int64),
+                lut, trim)
         out = {}
-        if res:
-            for st, r in zip(chosen, res):
-                if r is None:
-                    continue
+        for st, r in zip(chosen, res or ()):
+            if r is not None:
                 offsets, data = r
                 out[id(st)] = pa.Array.from_buffers(
                     pa.string(), len(positions),

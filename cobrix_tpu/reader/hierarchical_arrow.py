@@ -27,7 +27,11 @@ inside `segment_struct`, once a struct of the tree and never a record,
 `assemble.hier.assign` (positions by segment, child-to-parent assignment,
 list offsets) and `assemble.hier.leaves` (the struct's leaf builds and
 `take`s, with `assemble.string` / `.scalar` / `.decimal` nested where
-they fire). Counts: `DeviceStats.note_hier`.
+they fire: `assemble.string` once a struct with string leaves where
+`ArrowBatchBuilder.leaf_strings_at` builds them at its rows). Counts:
+`DeviceStats.note_hier`, with the leaves built at a struct's rows
+(`hier_leaves_at`) and those built at full length and then taken
+(`hier_leaves_taken`).
 """
 from __future__ import annotations
 
@@ -269,7 +273,7 @@ def _assemble(batch, segment_names, copybook, output_schema, sid_map,
         offsets[-1] = offsets_own[-1]
         return offsets
 
-    kept_children = 0
+    kept_children = leaves_at = leaves_taken = 0
 
     def segment_struct(group: Group, positions: np.ndarray,
                        null_mask: Optional[np.ndarray] = None):
@@ -277,7 +281,7 @@ def _assemble(batch, segment_names, copybook, output_schema, sid_map,
         list<struct> fields, schema order). `null_mask`: True where the
         struct itself is null (rows of positions owned by a sibling
         redefine — their decoded bytes are garbage by design)."""
-        nonlocal kept_children
+        nonlocal kept_children, leaves_at, leaves_taken
         owned = None if null_mask is None else ~null_mask
         # this struct's own fields, schema order; child segments are
         # nested below them
@@ -297,13 +301,18 @@ def _assemble(batch, segment_names, copybook, output_schema, sid_map,
                 arr = None
                 if isinstance(child, Primitive) and not child.is_array:
                     # string/numeric leaves build straight at `positions`
-                    # (raw-image subset transcode / numpy gather) — no
-                    # full-length build, no take
+                    # (subset transcode of the file image or the fetched
+                    # code points / numpy gather) — no full-length
+                    # build, no take
                     arr = built_at.get(id(child))
                     if arr is None:
                         arr = builder.leaf_numeric_at(child, positions)
-                leaves[id(child)] = (arr if arr is not None
-                                     else full_array(child).take(idx))
+                if arr is None:
+                    arr = full_array(child).take(idx)
+                    leaves_taken += 1
+                else:
+                    leaves_at += 1
+                leaves[id(child)] = arr
         arrays, field_names = [], [c.name for c in fields]
         for child in fields:
             if id(child) in leaves:
@@ -374,5 +383,6 @@ def _assemble(batch, segment_names, copybook, output_schema, sid_map,
         # found no parent of its direct parent's type is under no row
         mapped = sum(len(p) for p in positions_of.values())
         stats.note_hier(roots=n_roots, records=n, children=kept_children,
-                        orphans=mapped - n_roots - kept_children)
+                        orphans=mapped - n_roots - kept_children,
+                        leaves_at=leaves_at, leaves_taken=leaves_taken)
     return pa.Table.from_arrays(arrays, schema=target)

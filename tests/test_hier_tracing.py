@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from benchmark.generators import hier_companies as hier
-from cobrix_tpu import read_cobol
+from cobrix_tpu import native, profiling, read_cobol
 from cobrix_tpu.explain import explain
 
 pytestmark = pytest.mark.jax
@@ -25,6 +25,9 @@ OPTIONS = dict(
 # row mask under ENTITY and the six child assignments
 HIER_STAGES = {"assemble.hier": 1, "assemble.hier.assign": 8,
                "assemble.hier.leaves": 8}
+# a shard's string leaves are built at their rows in one pass a struct
+# that has any (not ENTITY): COMPANY and the six child segments
+STRING_PASSES = 7
 
 
 def written(tmp_path, companies: int):
@@ -41,31 +44,47 @@ def small(tmp_path_factory):
 
 @pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
 def test_counts_equal_the_generators_and_stages_fire_by_struct_not_record(
-        small, backend):
+        small, backend, monkeypatch):
     path, facts = small
     records = int(facts["segment_records"].sum())
+    # the stages open round each call of the string leaves' kernel
+    subset_kernel, opened = native.string_cols_arrow_raw, []
+
+    def noted(*args, **kwargs):
+        opened.append(tuple(frame.name for frame in profiling._open.stack))
+        return subset_kernel(*args, **kwargs)
+
+    monkeypatch.setattr(native, "string_cols_arrow_raw", noted)
     data = read_cobol(path, backend=backend,
                       input_split_records=str(records // 4), **OPTIONS)
     table = data.to_arrow()
     metrics = data.metrics.as_dict()
     shards = metrics["shards"]
     assert shards >= 4 and table.num_rows == 600
+    # 18 string and 5 integer leaves a shard built at their rows, the
+    # AMOUNT decimal at full length and taken
     assert metrics["hier"] == {
         "hier_roots": 600, "hier_records": records,
         "hier_children": records - 600, "hier_orphans": 0,
+        "hier_leaves_at": 23 * shards, "hier_leaves_taken": shards,
         "hier_row_path_roots": 0}
     stats = data.metrics.device_stats
     assert {stage: stats.stage_n[stage] for stage in HIER_STAGES} == \
         {stage: entries * shards for stage, entries in HIER_STAGES.items()}
+    # the string leaves' pass, beneath the leaves' stage, once a struct
+    # of the tree and never a record; no full-length pass over the
+    # fetched code points
+    assert stats.stage_n["assemble.string"] == STRING_PASSES * shards
+    assert opened == [("assemble.hier", "assemble.hier.leaves",
+                       "assemble.string")] * (STRING_PASSES * shards)
+    assert "point_strings" not in metrics.get("native_passes", {})
     if backend == "numpy":
         # its leaves are built at positions, from the file image
         assert "device" not in metrics
-        assert "assemble.string" not in stats.stage_n
     else:
         device = metrics["device"]
-        # a device batch's leaves are built at full length and taken:
-        # the string stage fires where it did, beneath the leaves' stage
-        assert stats.stage_n["assemble.string"] >= shards
+        # a device batch's leaves are built at positions from the
+        # fetched matrix of code points
         assert {k: v for k, v in device.items()
                 if k.startswith("hier_")} == metrics["hier"]
         assert all(shape.endswith("x108") for shape in device["launches"])
